@@ -3,9 +3,11 @@
 Times a batch bucket applied through a row-sharded
 :class:`repro.dist.ShardedSequencePlan` (one planned launch per shard
 under ``shard_map``) against the replicated
-``SequencePlan.apply_batched`` path on a forced 8-device host mesh, in
-a subprocess (``XLA_FLAGS=--xla_force_host_platform_device_count``
-must be set before JAX initializes).  Alongside the measured speedup
+``SequencePlan.apply_batched`` path.  On TPU it runs in this process
+over every attached chip; elsewhere it runs on a forced 8-device CPU
+host mesh in a subprocess (``XLA_FLAGS=--xla_force_host_platform_
+device_count`` must be set before JAX initializes, and the child is
+pinned to ``JAX_PLATFORMS=cpu``).  Alongside the measured speedup
 the suite reports the comm-extended §6 cost model's view of the same
 problem — modeled inter-device bytes and the sharded-vs-replicated
 crossover ratio — as deterministic warn-only context rows, so model
@@ -23,74 +25,92 @@ Gating rows (``compare_baseline.SPEC``):
 import os
 import subprocess
 import sys
-import textwrap
 
 from benchmarks.common import emit
 
-DEVICES = 8
+HOST_DEVICES = 8
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-_CODE = """
-import numpy as np, jax, jax.numpy as jnp
-from repro import dist, obs
-from repro.obs import timing
-from repro.core.rotations import random_sequence
 
-D = {D}
-mesh = jax.make_mesh((D,), ("data",))
-b, m, n, k = {b}, {m}, {n}, {k}
-rng = np.random.default_rng(0)
-A = jnp.asarray(rng.standard_normal((b, m, n)), jnp.float32)
-seq = random_sequence(jax.random.key(0), n, k)
+def _measure(D, b, m, n, k, devices=None):
+    """``(sh, rep, launches, comm, parity, sh_s, rep_s)`` on a D-mesh."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
 
-plan_sh = dist.plan_sharded(seq, like=A, mesh=mesh, method="blocked")
-plan_rep = seq.plan(like=A, method="blocked", shared_sequence=True)
+    from repro import compat, dist, obs
+    from repro.core.rotations import random_sequence
+    from repro.obs import timing
 
-def timed(fn):
-    jax.block_until_ready(fn())
-    ts = []
-    for _ in range(5):
-        t0 = timing.now(); jax.block_until_ready(fn())
-        ts.append(timing.now() - t0)
-    return sorted(ts)[len(ts) // 2]
+    mesh = compat.make_mesh((D,), ("data",), devices=devices)
+    rng = np.random.default_rng(0)
+    A = jnp.asarray(rng.standard_normal((b, m, n)), jnp.float32)
+    seq = random_sequence(jax.random.key(0), n, k)
 
-sh = timed(lambda: plan_sh.apply_batched(A, direct=True))
-rep = timed(lambda: plan_rep.apply_batched(A, direct=True))
+    plan_sh = dist.plan_sharded(seq, like=A, mesh=mesh, method="blocked")
+    plan_rep = seq.plan(like=A, method="blocked", shared_sequence=True)
 
-obs.set_enabled(True)
-obs.reset()
-out = plan_sh.apply_batched(A)
-snap = obs.snapshot()
-obs.set_enabled(False)
-launches = snap["gauges"].get("dist.launches_per_shard", 0.0)
-comm = snap["counters"].get("dist.comm_bytes", 0)
-parity = int(bool(jnp.array_equal(out, plan_rep.apply_batched(A))))
-sh_s, rep_s = dist.modeled_crossover(m, n, k, devices=D, batch=b,
-                                     shared_sequence=True)
-print("RESULT %.6f %.6f %.0f %.0f %d %.6e %.6e"
-      % (sh, rep, launches, comm, parity, sh_s, rep_s))
-"""
+    def timed(fn):
+        jax.block_until_ready(fn())
+        ts = []
+        for _ in range(5):
+            t0 = timing.now()
+            jax.block_until_ready(fn())
+            ts.append(timing.now() - t0)
+        return sorted(ts)[len(ts) // 2]
+
+    sh = timed(lambda: plan_sh.apply_batched(A, direct=True))
+    rep = timed(lambda: plan_rep.apply_batched(A, direct=True))
+
+    obs.set_enabled(True)
+    obs.reset()
+    out = plan_sh.apply_batched(A)
+    snap = obs.snapshot()
+    obs.set_enabled(False)
+    launches = snap["gauges"].get("dist.launches_per_shard", 0.0)
+    comm = snap["counters"].get("dist.comm_bytes", 0)
+    parity = int(bool(jnp.array_equal(out, plan_rep.apply_batched(A))))
+    sh_s, rep_s = dist.modeled_crossover(m, n, k, devices=D, batch=b,
+                                         shared_sequence=True)
+    return sh, rep, launches, comm, parity, sh_s, rep_s
+
+
+def _child_main(argv) -> None:
+    print("RESULT", *_measure(*map(int, argv)))
+
+
+def _measure_on_host(D, b, m, n, k):
+    """:func:`_measure` in a child with ``D`` forced CPU host devices."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={D}")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(_ROOT, "src"), _ROOT, env.get("PYTHONPATH", "")])
+    r = subprocess.run(
+        [sys.executable, "-m", "benchmarks.bench_dist", "--child",
+         *map(str, (D, b, m, n, k))],
+        capture_output=True, text=True, timeout=900, env=env, cwd=_ROOT)
+    line = [ln for ln in r.stdout.splitlines() if ln.startswith("RESULT")]
+    if not line:
+        raise RuntimeError(f"dist child failed (rc {r.returncode}):\n"
+                           f"{r.stdout}\n{r.stderr}")
+    return tuple(map(float, line[0].split()[1:]))
 
 
 def run(quick: bool = False) -> None:
+    from repro import compat
+
     b, m, n, k = (8, 256, 64, 16) if quick else (64, 512, 128, 32)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = \
-        f"--xla_force_host_platform_device_count={DEVICES}"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
-                                     "src")
-    code = textwrap.dedent(_CODE.format(D=DEVICES, b=b, m=m, n=n, k=k))
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=900, env=env)
-    line = [ln for ln in r.stdout.splitlines() if ln.startswith("RESULT")]
-    if not line:
-        emit(f"dist/sharded_vs_replicated", 0.0, "FAILED")
-        print(r.stdout, r.stderr, file=sys.stderr)
-        return
-    sh, rep, launches, comm, parity, sh_s, rep_s = \
-        map(float, line[0].split()[1:])
+    if compat.is_tpu():
+        devs = compat.devices()
+        D = len(devs)
+        res = _measure(D, b, m, n, k, devices=devs)
+    else:
+        D = HOST_DEVICES
+        res = _measure_on_host(D, b, m, n, k)
+    sh, rep, launches, comm, parity, sh_s, rep_s = res
     speedup = rep / sh if sh > 0 else 0.0
     emit("dist/sharded_vs_replicated", sh,
-         f"speedup_{speedup:.2f}x_D{DEVICES}",
+         f"speedup_{speedup:.2f}x_D{D}",
          metrics={"speedup": speedup, "parity": parity,
                   "launches_per_shard": launches})
     # deterministic cost-model context: modeled wire traffic for the
@@ -118,9 +138,11 @@ def main() -> None:
     print("name,us_per_call,derived")
     run(quick=args.quick)
     if args.json:
-        common.write_json(args.json, meta={"quick": args.quick,
-                                           "devices": DEVICES})
+        common.write_json(args.json, meta={"quick": args.quick})
 
 
 if __name__ == "__main__":
-    main()
+    if sys.argv[1:2] == ["--child"]:
+        _child_main(sys.argv[2:])
+    else:
+        main()

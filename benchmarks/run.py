@@ -15,6 +15,9 @@ def main() -> None:
                          "(BENCH_*.json for the CI regression compare)")
     args = ap.parse_args()
 
+    from repro import compat
+    compat.enable_compile_cache()
+
     from benchmarks import (bench_eig, bench_fig5, bench_fig6, bench_fig7,
                             bench_fig8, bench_iolb, bench_memops,
                             bench_serve, bench_smoke, common)

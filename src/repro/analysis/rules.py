@@ -76,9 +76,12 @@ def _function_references(mi: ModuleInfo, fn: ast.AST) -> List[str]:
 class RA101VersionSensitiveAttr(Rule):
     """Version-sensitive JAX API used outside ``repro.compat``.
 
-    Incident: the repo supports jax 0.4.37 through 0.5.x, across which
-    ``shard_map``/``typeof``/``pcast``/``pvary`` and the pltpu
-    ``CompilerParams`` spelling all moved or changed name.  The old
+    Incident: between JAX 0.4 and 0.9 ``shard_map``/``typeof``/
+    ``pcast``/``pvary`` and the pltpu ``CompilerParams`` spelling all
+    moved or changed name, and ``jax.make_mesh`` began to default to
+    Explicit axes, which broke every sharded test at once.  The repo
+    runs one JAX line; these lookups stay in one module so that the
+    next upgrade edits one file.  The old
     ``compat-gate`` grepped for literal spellings and missed aliased
     imports (``from jax.experimental.shard_map import shard_map as
     smap``); this rule resolves every import alias first.
@@ -95,6 +98,7 @@ class RA101VersionSensitiveAttr(Rule):
         "jax.lax.pvary",
         "jax.experimental.pallas.tpu.CompilerParams",
         "jax.experimental.pallas.tpu.TPUCompilerParams",
+        "jax.make_mesh",
     )
 
     def _bad(self, dotted: str) -> Optional[str]:

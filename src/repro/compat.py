@@ -1,123 +1,81 @@
-"""JAX version-compatibility shim (single source of truth).
+"""The JAX surface this repo depends on, in one module.
 
-Every version-sensitive JAX attribute lookup in this repo lives here —
-the rule (enforced by ``make check``'s grep gate) is: **no raw
-``jax.shard_map`` / ``jax.typeof`` / ``jax.lax.pcast`` /
-``pltpu.CompilerParams`` outside this module**.
+The repo runs one JAX line: 0.9.0 (jaxlib 0.9.0, libtpu 0.0.34).  The
+lookups below moved or changed meaning between JAX lines, so they live
+here and nowhere else (analyzer rule RA101): the next upgrade edits
+this file only.
 
-Resolved surfaces, spanning JAX 0.4.x -> 0.5.x+ and nightlies:
-
-* :func:`shard_map` — ``jax.shard_map`` (0.5+) vs
-  ``jax.experimental.shard_map.shard_map`` (0.4.x, with ``check_rep``
-  disabled: the pipelined collectives in ``repro.dist`` are not
-  replication-inferable on the old checker).
-* :func:`varying_axes` / :func:`pvary` / :func:`pvary_like` — the
-  varying-manual-axes ("vma") type system.  Nightlies track which mesh
-  axes a value varies over and require explicit ``pcast``/``pvary`` to
-  make loop-carry types agree; 0.4.x has no such tracking, so the probe
-  returns ``()`` and the cast is the identity.
-* :func:`tpu_compiler_params` — ``pltpu.CompilerParams`` (new name) vs
-  ``pltpu.TPUCompilerParams`` (0.4.x) vs a raw ``mosaic`` params dict
-  (very old releases).
-* :func:`default_platform` / :func:`is_tpu` — backend detection used by
-  the dispatch registry to gate Pallas backends and interpret mode.
+* :func:`shard_map`, :func:`varying_axes` / :func:`pvary` /
+  :func:`pvary_like` — manual sharding and the varying-manual-axes
+  ("vma") types that loop carries inside ``shard_map`` must agree on.
+* :func:`make_mesh` — the one mesh constructor.  ``jax.make_mesh``
+  defaults to Explicit axes on 0.9, under which ``jit`` needs
+  ``jax.set_mesh`` and ``with_sharding_constraint`` rejects the axes;
+  every mesh here states Auto axes instead.
+* :func:`tpu_compiler_params` — ``pltpu.CompilerParams``.
+* :func:`default_platform` / :func:`is_tpu` / :func:`devices` /
+  :func:`device_kind` — what is attached, for the registry's
+  eligibility, interpret mode and peak rates.
+* :func:`enable_x64`, :func:`enable_compile_cache` — process config.
 """
 from __future__ import annotations
 
-from typing import Any, Iterable, Sequence
+import functools
+import os
+from typing import Any, Iterable, Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = [
     "JAX_VERSION",
-    "MIN_SUPPORTED",
     "shard_map",
+    "make_mesh",
     "varying_axes",
     "pvary",
     "pvary_like",
     "tpu_compiler_params",
     "default_platform",
+    "devices",
+    "device_kind",
     "is_tpu",
     "is_tracer",
     "pallas_interpret_default",
     "enable_x64",
     "x64_enabled",
+    "enable_compile_cache",
 ]
 
-
-def _parse_version(v: str) -> tuple:
-    parts = []
-    for tok in v.split(".")[:3]:
-        num = "".join(ch for ch in tok if ch.isdigit())
-        parts.append(int(num) if num else 0)
-    return tuple(parts)
-
-
-JAX_VERSION: tuple = _parse_version(jax.__version__)
-MIN_SUPPORTED: tuple = (0, 4, 37)
-
-if JAX_VERSION < MIN_SUPPORTED:  # pragma: no cover - old-env guard
-    import warnings
-
-    warnings.warn(
-        f"repro supports JAX >= {'.'.join(map(str, MIN_SUPPORTED))}; "
-        f"found {jax.__version__}. Expect breakage.",
-        stacklevel=2,
-    )
+JAX_VERSION: tuple = tuple(int(v) for v in jax.__version__.split(".")[:3])
 
 
 # --------------------------------------------------------------------------
-# shard_map
+# sharding
 # --------------------------------------------------------------------------
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with a 0.4.x experimental-namespace fallback."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # check_rep=False: the 0.4.x replication checker rejects the manual
-    # ppermute pipelines in repro.dist (same semantics either way).
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
+    """``jax.shard_map`` over ``mesh``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)
 
 
-# --------------------------------------------------------------------------
-# varying-manual-axes (vma) probing and casting
-# --------------------------------------------------------------------------
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Optional[Sequence[Any]] = None):
+    """A device mesh whose axes are all ``AxisType.Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
 
 def varying_axes(x: Any) -> tuple:
-    """Mesh axes ``x`` is device-varying over inside ``shard_map``.
-
-    On JAX versions without vma tracking (<= 0.4.x) this is always
-    ``()`` — those versions do not distinguish varying from replicated
-    values in the type system, so no cast is ever needed.
-    """
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return ()
-    try:
-        aval = typeof(x)
-    except Exception:
-        return ()
-    return tuple(getattr(aval, "vma", ()) or ())
+    """Mesh axes ``x`` is device-varying over inside ``shard_map``."""
+    return tuple(jax.typeof(x).vma)
 
 
 def pvary(x, axes: Sequence[str]):
-    """Cast ``x`` to be device-varying over ``axes`` (identity if n/a)."""
+    """Cast ``x`` to be device-varying over ``axes``."""
     axes = tuple(axes)
-    if not axes:
-        return x
-    lax = jax.lax
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axes)
-    return x  # no vma type system: replicated values are fine as carries
+    return jax.lax.pcast(x, axes, to="varying") if axes else x
 
 
 def pvary_like(x, ref: Any, extra: Iterable[str] = ()):
@@ -137,32 +95,10 @@ def pvary_like(x, ref: Any, extra: Iterable[str] = ()):
 # --------------------------------------------------------------------------
 
 def tpu_compiler_params(**kwargs):
-    """Construct TPU compiler params across the pltpu renames.
-
-    ``pltpu.CompilerParams`` (new) -> ``pltpu.TPUCompilerParams``
-    (0.4.x) -> ``{"mosaic": {...}}`` dict (ancient).  Unknown kwargs are
-    dropped with a warning rather than crashing, so newer tuning knobs
-    degrade gracefully on older compilers.
-    """
+    """``pltpu.CompilerParams(**kwargs)``."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:  # pragma: no cover - ancient JAX
-        return dict(mosaic=kwargs)
-    fields = getattr(cls, "__dataclass_fields__", None)
-    if fields is not None:
-        unknown = [k for k in kwargs if k not in fields]
-        if unknown:  # pragma: no cover - forward-compat path
-            import warnings
-
-            warnings.warn(
-                f"dropping TPU compiler params unsupported on "
-                f"jax {jax.__version__}: {unknown}", stacklevel=2,
-            )
-            kwargs = {k: v for k, v in kwargs.items() if k in fields}
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 # --------------------------------------------------------------------------
@@ -171,27 +107,22 @@ def tpu_compiler_params(**kwargs):
 
 def default_platform() -> str:
     """Lowercase default backend platform: ``cpu`` / ``gpu`` / ``tpu``."""
-    try:
-        return jax.default_backend().lower()
-    except Exception:  # pragma: no cover - uninitialized backends
-        return "cpu"
+    return jax.default_backend().lower()
+
+
+def devices() -> list:
+    """The attached devices of the default backend."""
+    return jax.devices()
+
+
+@functools.lru_cache(maxsize=None)
+def device_kind() -> str:
+    """``device_kind`` of the first attached device (``"TPU v5 lite"``)."""
+    return devices()[0].device_kind
 
 
 def is_tpu() -> bool:
     return default_platform() == "tpu"
-
-
-def _tracer_class():
-    """Resolve the abstract-tracer base across the jax.core shuffles."""
-    import warnings
-
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        core = getattr(jax, "core", None)
-        return getattr(core, "Tracer", None) if core is not None else None
-
-
-_TRACER_CLS = _tracer_class()
 
 
 def is_tracer(x: Any) -> bool:
@@ -202,7 +133,7 @@ def is_tracer(x: Any) -> bool:
     ``block_until_ready`` would be meaningless — so every instrumented
     seam guards with this.
     """
-    return _TRACER_CLS is not None and isinstance(x, _TRACER_CLS)
+    return isinstance(x, jax.core.Tracer)
 
 
 def pallas_interpret_default() -> bool:
@@ -211,7 +142,7 @@ def pallas_interpret_default() -> bool:
 
 
 # --------------------------------------------------------------------------
-# 64-bit mode
+# process configuration
 # --------------------------------------------------------------------------
 
 def x64_enabled() -> bool:
@@ -220,25 +151,30 @@ def x64_enabled() -> bool:
 
 
 def enable_x64(enabled: bool = True):
-    """Context manager scoping 64-bit mode (float64 eigen/SVD paths).
+    """Context manager scoping 64-bit mode (float64 eigen/SVD paths)."""
+    return jax.enable_x64(enabled)
 
-    ``jax.experimental.enable_x64`` where available (all supported
-    versions), else a manual ``jax.config`` toggle with restore.
+
+# <repo>/.jax_cache: one fixed path inside the checkout (git-ignored),
+# so a later process in the same checkout finds what this one compiled
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    no other directory is configured; otherwise the cache goes to
+    ``<repo>/.jax_cache``.  Every compile is cached: the kernels compile
+    in a second or two, under JAX's default one-second threshold.
+    Called by the entry points (``chip_smoke.py``, ``python -m
+    repro.launch.serve``, ``benchmarks/run.py``), never on import.
     """
-    cm = getattr(__import__("jax.experimental", fromlist=["enable_x64"]),
-                 "enable_x64", None)
-    if cm is not None:
-        return cm(enabled)
-
-    import contextlib  # pragma: no cover - future-proofing fallback
-
-    @contextlib.contextmanager
-    def _toggle():
-        prev = x64_enabled()
-        jax.config.update("jax_enable_x64", enabled)
-        try:
-            yield
-        finally:
-            jax.config.update("jax_enable_x64", prev)
-
-    return _toggle()
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = _DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path

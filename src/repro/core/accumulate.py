@@ -52,13 +52,17 @@ def accumulate_tile_factors(Ct, St, Gt, *, dtype=jnp.float32):
     eye = jnp.eye(w, dtype=dtype)
     # inside shard_map the tiles may be device-varying; the identity must
     # carry the same varying-manual-axes type to be a legal loop carry
-    # (no-op on JAX versions without vma tracking — see repro.compat)
     eye = compat.pvary_like(eye, Ct)
     return jax.vmap(lambda c, s, g: apply_tile(eye, c, s, g))(Ct, St, Gt)
 
 
-def apply_band_accumulated(A, Q, *, k_b: int, precision=None):
-    """Sweep one band using precomputed tile factors ``Q`` (T, w, w)."""
+def apply_band_accumulated(A, Q, *, k_b: int):
+    """Sweep one band using precomputed tile factors ``Q`` (T, w, w).
+
+    The GEMMs run at ``Precision.HIGHEST``: at the default precision a
+    TPU multiplies float32 operands in one bfloat16 pass, which falls
+    far short of the float32 accuracy this backend promises.
+    """
     T, w, _ = Q.shape
     n_b = w - k_b
     m, n = A.shape
@@ -68,7 +72,8 @@ def apply_band_accumulated(A, Q, *, k_b: int, precision=None):
     def step(carry, xs):
         q, ft = xs
         X = jnp.concatenate([carry, ft], axis=1)
-        X = jnp.dot(X, q.astype(X.dtype), precision=precision)
+        X = jnp.dot(X, q.astype(X.dtype),
+                    precision=jax.lax.Precision.HIGHEST)
         return X[:, n_b:], X[:, :n_b]
 
     _, out = jax.lax.scan(step, carry0, (Q, fresh_tiles))

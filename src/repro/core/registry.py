@@ -28,11 +28,11 @@ from repro import compat, obs
 from repro.obs import timing as _timing
 
 __all__ = [
-    "Hardware", "PLATFORMS", "Problem", "Plan", "Capability", "BackendSpec",
+    "Hardware", "Problem", "Plan", "Capability", "BackendSpec",
     "register", "get_backend", "registered_methods", "eligible_backends",
     "no_tiles", "blocked_tiles", "accumulated_tiles",
     "pallas_wave_tiles", "pallas_mxu_tiles", "rotseq_batched_tiles",
-    "select_plan", "plan_cache_stats", "clear_plan_cache",
+    "pinned_tiles", "select_plan", "plan_cache_stats", "clear_plan_cache",
     "plan_cache_path", "save_plan_cache", "load_plan_cache",
     "cost_components",
 ]
@@ -40,9 +40,9 @@ __all__ = [
 
 # hardware table lives in the jax-free repro.hw (shared with the
 # roofline report); re-exported here for registry users
-from repro.hw import Hardware, PLATFORMS  # noqa: E402
-from repro.kernels.limits import (SMEM_PANEL_BUDGET, VMEM_SLAB_BUDGET,
-                                  clamp_m_blk)
+from repro.hw import Hardware, peaks, planning_peaks  # noqa: E402
+from repro.kernels.limits import (LANES, SMEM_PANEL_BUDGET,  # noqa: E402
+                                  smem_panel_bytes)
 
 # Pallas interpret mode executes the kernel body op-by-op on the host —
 # orders of magnitude off compiled speed.  Off-TPU the pallas backends
@@ -131,7 +131,12 @@ class Problem:
 
     @property
     def hardware(self) -> Hardware:
-        return PLATFORMS.get(self.platform, PLATFORMS["cpu"])
+        """Peaks of the attached device when it is this platform's, else
+        of the device a plan for the platform assumes (``repro.hw``);
+        an unknown platform or device kind raises."""
+        if self.platform == compat.default_platform():
+            return peaks(compat.device_kind())
+        return planning_peaks(self.platform)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -494,7 +499,8 @@ def cost_rotseq_batched(p: Problem, plan: Plan) -> float:
     read once per batch element, and — unlike every other backend —
     the flop term scales with the *live* planes: identity padding from
     ``pad_to`` and ``seq.T`` staircases is skipped, not multiplied
-    through.
+    through.  A problem past the kernel's on-chip budgets gets no
+    candidate (:func:`rotseq_batched_tiles`) rather than a price.
     """
     hw = p.hardware
     c = _components_rotseq_batched(p, plan)
@@ -502,20 +508,6 @@ def cost_rotseq_batched(p: Problem, plan: Plan) -> float:
     secs = _roofline_seconds(
         c["stream_flops"] / hw.vpu_flops / D,
         (c["setup_bytes"] + c["stream_bytes"] / D) / hw.hbm_bw)
-    # On-chip residency bounds, priced out rather than hard-filtered:
-    # the (n, m_blk) slab must fit in VMEM for the single-pass
-    # assumption to hold, and the scalar-indexed C/S/G panels live in
-    # SMEM, whose capacity is far smaller — a (n-1, K) grid past the
-    # budget cannot compile on hardware (interpret mode hides this),
-    # so keep auto off the kernel there.  Budgets and the m_blk clamp
-    # come from repro.kernels.limits — the same definitions the kernel
-    # wrapper uses, so the kernel the model prices is the kernel that
-    # launches (enforced by RA403/RA404).
-    m_blk = clamp_m_blk(p.m, plan.m_blk or 256)
-    panel_bytes = 3 * p.planes_total * p.itemsize
-    if (p.n * m_blk * p.itemsize > VMEM_SLAB_BUDGET
-            or panel_bytes > SMEM_PANEL_BUDGET):
-        secs *= 1e3
     return max(secs * _interpret_factor(p), _LATENCY_FLOOR) + comm_s
 
 
@@ -547,8 +539,8 @@ def cost_components(method: str, problem: Problem,
     §6 memory-operation analysis of the named backend (zero for
     backends registered without a component entry); ``seconds`` is the
     registered cost model itself, so it always matches what
-    ``select_plan`` ranked by — including the interpret penalty and
-    residency guards.  The ``setup``/``stream`` sub-dicts carry the
+    ``select_plan`` ranked by — including the interpret penalty.  The
+    ``setup``/``stream`` sub-dicts carry the
     per-sequence vs per-row split with *additive, penalty-free*
     attribution seconds (pure traffic over peak rates), so the obs
     roofline ledger — and the bench row that watches the per-request
@@ -628,7 +620,21 @@ def _m_blk_for(p: Problem) -> int:
     return min(256, max(8, 1 << (max(1, p.m_total) - 1).bit_length()))
 
 
+# What Mosaic compiles.  Off the chip the interpreter runs any kernel;
+# on it, a kernel that cannot compile is never offered.  The VPU
+# kernels address one row of a slab at a dynamic offset, which a packed
+# 16-bit slab does not allow, so they are float32 only.
+_VPU_KERNEL_DTYPES = ("float32",)
+_MXU_KERNEL_DTYPES = ("float32", "bfloat16")
+
+
+def _compiles_for(p: Problem, dtypes: Tuple[str, ...]) -> bool:
+    return p.platform != "tpu" or p.dtype in dtypes
+
+
 def pallas_wave_tiles(p: Problem) -> List[Plan]:
+    if not _compiles_for(p, _VPU_KERNEL_DTYPES):
+        return []
     cap = get_backend("pallas_wave").capability
     pairs = _clip_pairs(p, [(64, 16), (32, 8), (8, 4)], cap)
     mb = _m_blk_for(p)
@@ -636,19 +642,53 @@ def pallas_wave_tiles(p: Problem) -> List[Plan]:
 
 
 def pallas_mxu_tiles(p: Problem) -> List[Plan]:
+    if not _compiles_for(p, _MXU_KERNEL_DTYPES):
+        return []
     cap = get_backend("pallas_mxu").capability
     pairs = _clip_pairs(p, [(128, 128), (64, 64), (8, 8)], cap)
+    if p.platform == "tpu":
+        # the (m_blk, n_b) fresh-column block is lane-major: Mosaic
+        # takes it only at a multiple of 128 lanes
+        pairs = [(a, b) for a, b in pairs if a % LANES == 0]
     mb = _m_blk_for(p)
     return [Plan("", n_b=a, k_b=b, m_blk=mb) for a, b in pairs]
 
 
 def rotseq_batched_tiles(p: Problem) -> List[Plan]:
-    """The fused kernel tiles only over lanes (whole n stays in VMEM)."""
+    """The fused kernel tiles only over lanes (whole n stays in VMEM).
+
+    On the chip the scalar-indexed C/S/G panels live in SMEM; a problem
+    whose panels exceed ``repro.kernels.limits.SMEM_PANEL_BUDGET`` gets
+    no candidate.
+    """
+    if not _compiles_for(p, _VPU_KERNEL_DTYPES):
+        return []
+    if p.platform == "tpu" \
+            and smem_panel_bytes(p.n - 1, p.k) > SMEM_PANEL_BUDGET:
+        return []
     mb = _m_blk_for(p)
-    cands = [Plan("", m_blk=mb)]
-    if mb != 128:
-        cands.append(Plan("", m_blk=128))
-    return cands
+    return [Plan("", m_blk=b) for b in dict.fromkeys((mb, 128))]
+
+
+def pinned_tiles(method: str, problem: Problem) -> dict:
+    """Tile kwargs for a backend the caller names instead of ``"auto"``.
+
+    Tiled backends keep the seed tiles ``n_b=64, k_b=16``, except a
+    Pallas kernel on the chip, which takes the cheapest tile it offers
+    for ``problem`` — Mosaic compiles only those.  Raises
+    ``ValueError`` where it offers none.
+    """
+    spec = get_backend(method)
+    if spec.candidates is no_tiles:
+        return {}
+    if not (spec.capability.needs_pallas and problem.platform == "tpu"):
+        return {"n_b": 64, "k_b": 16}
+    plans = [dataclasses.replace(c, method=method)
+             for c in spec.candidates(problem)]
+    if not plans:
+        raise ValueError(
+            f"method {method!r} cannot compile {problem} for the chip")
+    return min(plans, key=lambda pl: spec.cost(problem, pl)).kwargs()
 
 
 # --------------------------------------------------------------------------
@@ -1181,20 +1221,16 @@ def select_plan(m: int, n: int, k: int, *, dtype="float32",
                             cached.m_blk)
                         for pl in candidates):
                 candidates = candidates + [cached]
-            timed = []
-            for plan in candidates:
-                try:
-                    secs = _measure_plan(problem, plan)
-                except Exception:  # backend crashed at these tiles
-                    continue
-                timed.append(dataclasses.replace(
-                    plan, est_seconds=secs, source="measured"))
-            if timed:
-                best = min(timed, key=lambda pl: pl.est_seconds)
-                if cached is not None:
-                    # a cached (model/interpolated) entry was replaced
-                    # by a fresh measurement for the same key
-                    obs.inc("registry.plan_cache.autotune_upgrade")
+            # every candidate compiles (the tile generators offer no
+            # other), so a measurement that raises is a real failure
+            timed = [dataclasses.replace(
+                plan, est_seconds=_measure_plan(problem, plan),
+                source="measured") for plan in candidates]
+            best = min(timed, key=lambda pl: pl.est_seconds)
+            if cached is not None:
+                # a cached (model/interpolated) entry was replaced by a
+                # fresh measurement for the same key
+                obs.inc("registry.plan_cache.autotune_upgrade")
         _PLAN_CACHE[key] = best
         sp.set(method=best.method, source=best.source)
     if best.source == "measured":

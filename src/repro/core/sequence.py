@@ -405,7 +405,9 @@ class RotationSequence:
         SS6 cost model (or measured ``autotune``) through the per-shape
         plan cache — batch-aware, so a batch-64 bucket can plan onto a
         different backend than a single request; a named method keeps
-        the seed defaults (``n_b=64, k_b=16`` for tiled backends).
+        the seed defaults (``n_b=64, k_b=16`` for tiled backends; on the
+        chip a Pallas kernel takes its cheapest compilable tile, see
+        ``registry.pinned_tiles``).
         Explicit ``n_b``/``k_b`` always override the planned tiles.
         """
         _ensure_backends()
@@ -448,10 +450,16 @@ class RotationSequence:
             return SequencePlan(self, plan.method,
                                 tuple(sorted(planned.items())), plan)
 
-        planned = dict(kw)
-        if spec.candidates is not registry.no_tiles:  # tiled backend
-            planned["n_b"] = 64 if n_b is None else n_b  # seed defaults
-            planned["k_b"] = 16 if k_b is None else k_b
+        tiles = registry.pinned_tiles(method, registry.Problem(
+            m=m, n=n, k=k, dtype=str(jnp.dtype(dtype)),
+            platform=platform or compat.default_platform(),
+            signs=self.sign is not None, batch=batch,
+            shared_sequence=shared_sequence, live_planes=self.k_live))
+        if n_b is not None:
+            tiles["n_b"] = n_b
+        if k_b is not None:
+            tiles["k_b"] = k_b
+        planned = {**tiles, **kw}
         return SequencePlan(self, method, tuple(sorted(planned.items())),
                             None)
 

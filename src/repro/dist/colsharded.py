@@ -89,8 +89,8 @@ def _sweep(X0carry, fresh_tiles, Ct, St, Gt, use_mxu: bool):
         def step(carry, xs):
             q, ft = xs
             X = jnp.concatenate([carry, ft], axis=1)
-            X = jnp.dot(X, q,
-                        preferred_element_type=jnp.float32).astype(X.dtype)
+            X = jnp.dot(X, q, preferred_element_type=jnp.float32,
+                        precision=jax.lax.Precision.HIGHEST).astype(X.dtype)
             n_b = ft.shape[1]
             return X[:, n_b:], X[:, :n_b]
 

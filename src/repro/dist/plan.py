@@ -152,10 +152,16 @@ def plan_sharded(seq: RotationSequence, like=None, *, mesh,
         if not spec.capability.supports_sharding:
             raise ValueError(
                 f"method {method!r} cannot run inside shard_map")
-        planned = dict(kw)
-        if spec.candidates is not registry.no_tiles:
-            planned["n_b"] = 64 if n_b is None else n_b
-            planned["k_b"] = 16 if k_b is None else k_b
+        planned = registry.pinned_tiles(method, registry.Problem(
+            m=m, n=n, k=k, dtype=str(jnp.dtype(dtype)),
+            platform=platform or compat.default_platform(), signs=signs,
+            sharded=True, devices=devices, batch=batch,
+            shared_sequence=shared_sequence, live_planes=seq.k_live))
+        if n_b is not None:
+            planned["n_b"] = n_b
+        if k_b is not None:
+            planned["k_b"] = k_b
+        planned.update(kw)
         return ShardedSequencePlan(
             sequence=seq, mesh=mesh, row_axes=_as_tuple(row_axes),
             method=method, kwargs=tuple(sorted(planned.items())),

@@ -17,26 +17,28 @@ wrapper without ordering constraints.
 from __future__ import annotations
 
 __all__ = [
-    "SUBLANES", "SMEM_PANEL_BUDGET", "VMEM_SLAB_BUDGET",
-    "round_up", "clamp_m_blk",
+    "SUBLANES", "LANES", "SMEM_BYTES", "SMEM_PANEL_BUDGET",
+    "round_up", "clamp_m_blk", "smem_panel_bytes",
 ]
 
 # TPU sublane count: block shapes keep the second-minor dimension a
 # multiple of this so Mosaic never pads a tile internally.
 SUBLANES = 8
 
-# SMEM bytes one request's scalar-indexed C/S/G panels may occupy in the
-# fused rotseq_batched kernel.  Scalar memory is orders of magnitude
-# smaller than VMEM: serve-bucket grids are a few KB, while a (255, 263)
-# staircase panel set is ~800KB and would fail Mosaic compilation —
-# interpret mode would happily run it, which is why the cost model
-# prices the kernel out (rather than crashing) past this budget.
-SMEM_PANEL_BUDGET = 128 * 2**10
+# TPU lane count: Mosaic refuses a block whose minor dimension is neither
+# a multiple of this nor the whole array dimension.
+LANES = 128
 
-# VMEM bytes one (n, m_blk) target slab may occupy: the fused kernel's
-# single-HBM-pass argument assumes the whole slab stays resident for all
-# K waves.
-VMEM_SLAB_BUDGET = 8 * 2**20
+# Scalar memory of one TPU v5e core.  Compiling for a v5e chip refuses
+# SMEM windows past it ("would exceed memory (size=1048576)").
+SMEM_BYTES = 2**20
+
+# SMEM bytes the fused rotseq_batched kernel's C/S/G panel windows may
+# take: all of SMEM but a reserve for its (1, 1, K) wave windows and
+# plane counter.  Compiling for v5e admits panels of n=336, K<=128 at
+# exactly this bound and refuses n=344 by 12 KB.  It also bounds the
+# kernel's VMEM: n <= 337 keeps an (n, 256) float32 slab under 345 KB.
+SMEM_PANEL_BUDGET = SMEM_BYTES - 16 * 2**10
 
 
 def round_up(x: int, mult: int) -> int:
@@ -55,3 +57,13 @@ def clamp_m_blk(m: int, m_blk: int) -> int:
     is the kernel that actually launches.
     """
     return min(m_blk, round_up(max(1, m), SUBLANES))
+
+
+def smem_panel_bytes(J: int, K: int) -> int:
+    """SMEM the fused kernel's three ``(1, J, K)`` panel windows take.
+
+    SMEM holds 32-bit words and pads a window's last two dimensions to
+    ``(SUBLANES, LANES)``; each window is double-buffered.
+    """
+    return (3 * 2 * round_up(max(1, J), SUBLANES)
+            * round_up(max(1, K), LANES) * 4)

@@ -25,7 +25,6 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -36,33 +35,41 @@ __all__ = ["rotseq_wave_pallas"]
 
 
 def _wave_kernel(ct_ref, st_ref, gt_ref, init_ref, fresh_ref, out_ref,
-                 carry_ref, *, n_b: int, k_b: int):
-    """One parallelogram tile: k_b waves over X = [carry; fresh] (w, m_blk)."""
+                 x_ref, *, n_b: int, k_b: int):
+    """One parallelogram tile: k_b waves over X = [carry; fresh] (w, m_blk).
+
+    ``x_ref`` is the VMEM slab ``X``; its first ``k_b`` rows persist across
+    ``t`` as the carry.  Each plane loads and stores its two rows through
+    the ref one row at a time: Mosaic lowers a dynamic single-row slice
+    of a ref, but neither a ``dynamic_slice`` of a value nor a two-row
+    window at an offset it cannot prove sublane-aligned.
+    """
     t = pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
-        carry_ref[...] = init_ref[...]
+        x_ref[:k_b, :] = init_ref[...]
 
-    x = jnp.concatenate([carry_ref[...], fresh_ref[...]], axis=0)
+    x_ref[k_b:, :] = fresh_ref[...]
+    dtype = x_ref.dtype
 
-    def wave(p, x):
-        def rot(jj, x):
+    def wave(p, _):
+        def rot(jj, _):
             jl = k_b - 1 - p + jj
-            c = ct_ref[0, jj, p].astype(x.dtype)
-            s = st_ref[0, jj, p].astype(x.dtype)
-            g = gt_ref[0, jj, p].astype(x.dtype)
-            pair = jax.lax.dynamic_slice_in_dim(x, jl, 2, axis=0)
-            xn, yn = plane_update(pair[0], pair[1], c, s, g)
-            return jax.lax.dynamic_update_slice_in_dim(
-                x, jnp.stack([xn, yn], axis=0), jl, axis=0
-            )
+            c = ct_ref[0, jj, p].astype(dtype)
+            s = st_ref[0, jj, p].astype(dtype)
+            g = gt_ref[0, jj, p].astype(dtype)
+            xn, yn = plane_update(x_ref[pl.ds(jl, 1), :],
+                                  x_ref[pl.ds(jl + 1, 1), :], c, s, g)
+            x_ref[pl.ds(jl, 1), :] = xn
+            x_ref[pl.ds(jl + 1, 1), :] = yn
+            return 0
 
-        return jax.lax.fori_loop(0, n_b, rot, x)
+        return jax.lax.fori_loop(0, n_b, rot, 0)
 
-    x = jax.lax.fori_loop(0, k_b, wave, x)
-    out_ref[...] = x[:n_b]
-    carry_ref[...] = x[n_b:]
+    jax.lax.fori_loop(0, k_b, wave, 0)
+    out_ref[...] = x_ref[:n_b, :]
+    x_ref[:k_b, :] = x_ref[n_b:, :]
 
 
 @functools.partial(
@@ -110,7 +117,7 @@ def rotseq_wave_pallas(ATfresh, Ct, St, Gt, init, *, n_b: int, k_b: int,
         ],
         out_specs=pl.BlockSpec((n_b, m_blk), lambda i, t: (t, i)),
         out_shape=jax.ShapeDtypeStruct((T * n_b, m), ATfresh.dtype),
-        scratch_shapes=[pltpu.VMEM((k_b, m_blk), ATfresh.dtype)],
+        scratch_shapes=[pltpu.VMEM((k_b + n_b, m_blk), ATfresh.dtype)],
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),

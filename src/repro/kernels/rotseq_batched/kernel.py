@@ -27,11 +27,11 @@ row dimension ``m`` lies along TPU lanes; every plane update is a dense
 against every jnp backend).
 
 Residency: the whole ``(n, m_blk)`` slab stays in VMEM for all ``K``
-waves, and the scalar-indexed C/S/G panels stay in SMEM — the cost
-model (``registry.cost_rotseq_batched``) prices the kernel out of
-``method="auto"`` when either exceeds its on-chip budget
-(``repro.kernels.limits.SMEM_PANEL_BUDGET`` for the panels), since
-interpret mode would happily run grids Mosaic could never compile.
+waves, and the scalar-indexed C/S/G panels stay in SMEM — the registry
+offers the kernel no tile on TPU when the panels exceed
+``repro.kernels.limits.SMEM_PANEL_BUDGET``
+(``registry.rotseq_batched_tiles``), since interpret mode would happily
+run grids Mosaic could never compile.
 """
 from __future__ import annotations
 
@@ -50,31 +50,34 @@ __all__ = ["rotseq_batched_pallas"]
 
 def _batched_kernel(starts_ref, counts_ref, c_ref, s_ref, g_ref, a_ref,
                     out_ref, planes_ref, *, K: int):
-    """Apply all K waves to one (n, m_blk) slab, skipping dead planes."""
-    x0 = a_ref[0]  # (n, m_blk)
+    """Apply all K waves to one (n, m_blk) slab, skipping dead planes.
 
-    def wave(p, carry):
-        x, total = carry
-        start = starts_ref[0, p]
-        count = counts_ref[0, p]
+    The slab is rotated in place in the output block, one row load and
+    store per plane side (see the VPU wavefront kernel for why rows go
+    through the ref).
+    """
+    out_ref[...] = a_ref[...]
+    dtype = out_ref.dtype
 
-        def rot(jj, x):
+    def wave(p, total):
+        start = starts_ref[0, 0, p]
+        count = counts_ref[0, 0, p]
+
+        def rot(jj, _):
             j = start + jj
-            c = c_ref[0, j, p].astype(x.dtype)
-            s = s_ref[0, j, p].astype(x.dtype)
-            g = g_ref[0, j, p].astype(x.dtype)
-            pair = jax.lax.dynamic_slice_in_dim(x, j, 2, axis=0)
-            xn, yn = plane_update(pair[0], pair[1], c, s, g)
-            return jax.lax.dynamic_update_slice_in_dim(
-                x, jnp.stack([xn, yn], axis=0), j, axis=0
-            )
+            c = c_ref[0, j, p].astype(dtype)
+            s = s_ref[0, j, p].astype(dtype)
+            g = g_ref[0, j, p].astype(dtype)
+            xn, yn = plane_update(out_ref[0, pl.ds(j, 1), :],
+                                  out_ref[0, pl.ds(j + 1, 1), :], c, s, g)
+            out_ref[0, pl.ds(j, 1), :] = xn
+            out_ref[0, pl.ds(j + 1, 1), :] = yn
+            return 0
 
-        x = jax.lax.fori_loop(0, count, rot, x)
-        return x, total + count
+        jax.lax.fori_loop(0, count, rot, 0)
+        return total + count
 
-    x, total = jax.lax.fori_loop(0, K, wave, (x0, jnp.int32(0)))
-    out_ref[0] = x
-    planes_ref[0, 0] = total
+    planes_ref[0, 0, 0, 0] = jax.lax.fori_loop(0, K, wave, jnp.int32(0))
 
 
 @functools.partial(
@@ -110,17 +113,22 @@ def rotseq_batched_pallas(AT, C, S, G, starts, counts, *, m_blk: int,
 
     if bs == 1:
         panel_ix = lambda ib, i: (0, 0, 0)
-        window_ix = lambda ib, i: (0, 0)
     else:
         panel_ix = lambda ib, i: (ib, 0, 0)
-        window_ix = lambda ib, i: (ib, 0)
 
+    # Every block's last two dimensions equal the array's own (Mosaic's
+    # block rule): the (bs, K) windows ride as (bs, 1, K) and the plane
+    # counter as (b, R, 1, 1).
     panel_spec = pl.BlockSpec((1, J, K), panel_ix,
                               memory_space=pltpu.SMEM)
-    window_spec = pl.BlockSpec((1, K), window_ix,
+    window_spec = pl.BlockSpec((1, 1, K), panel_ix,
                                memory_space=pltpu.SMEM)
+    # inside shard_map the outputs vary over every mesh axis an input
+    # does (shard_map checks it); outside, the set is empty
+    vma = frozenset().union(
+        *(compat.varying_axes(x) for x in (AT, C, S, G, starts, counts)))
     kernel = functools.partial(_batched_kernel, K=K)
-    return pl.pallas_call(
+    out, planes = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -133,15 +141,16 @@ def rotseq_batched_pallas(AT, C, S, G, starts, counts, *, m_blk: int,
         ],
         out_specs=[
             pl.BlockSpec((1, n, m_blk), lambda ib, i: (ib, 0, i)),
-            pl.BlockSpec((1, 1), lambda ib, i: (ib, i),
+            pl.BlockSpec((1, 1, 1, 1), lambda ib, i: (ib, i, 0, 0),
                          memory_space=pltpu.SMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, n, m_pad), AT.dtype),
-            jax.ShapeDtypeStruct((b, R), jnp.int32),
+            jax.ShapeDtypeStruct((b, n, m_pad), AT.dtype, vma=vma),
+            jax.ShapeDtypeStruct((b, R, 1, 1), jnp.int32, vma=vma),
         ],
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(starts, counts, C, S, G, AT)
+    )(starts[:, None], counts[:, None], C, S, G, AT)
+    return out, planes.reshape(b, R)

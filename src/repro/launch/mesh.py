@@ -6,7 +6,7 @@ importing this module never touches jax device state — the dry-run sets
 """
 from __future__ import annotations
 
-import jax
+from repro import compat
 
 __all__ = ["make_production_mesh", "make_rules_for_mesh"]
 
@@ -14,7 +14,7 @@ __all__ = ["make_production_mesh", "make_rules_for_mesh"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return compat.make_mesh(shape, axes)
 
 
 def make_rules_for_mesh(mesh, *, seq_parallel: bool = False):

@@ -30,11 +30,11 @@ import glob
 import json
 import os
 
-from repro.hw import PLATFORMS
+from repro.hw import TPU_V5E, peaks
 
 # per-chip peaks come from the shared hardware table (repro.hw) —
 # the single source of truth shared with the registry's cost model
-_HW = PLATFORMS["tpu"]
+_HW = peaks(TPU_V5E)
 PEAK_FLOPS = _HW.mxu_flops   # bf16 MXU / chip (v5e)
 VPU_FLOPS = _HW.vpu_flops    # ~elementwise ops/s / chip (8x128 VPU, est.)
 HBM_BW = _HW.hbm_bw          # B/s / chip

@@ -36,7 +36,7 @@ import argparse
 
 import jax
 
-from repro import obs
+from repro import compat, obs
 from repro.configs import get_config
 from repro.models import build_model
 from repro.serve import ServeEngine
@@ -191,6 +191,7 @@ def main():
                          "JSON here (view in ui.perfetto.dev)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compat.enable_compile_cache()
 
     if args.metrics_json or args.trace:
         obs.set_enabled(True)

@@ -1,8 +1,8 @@
 # repro-lint: fixture-as=src/repro/parallel/bad_shims.py
 """RA101 fixture: version-sensitive JAX API outside compat.py.
 
-Every spelling here moved or was renamed between jax 0.4.37 and 0.5.x;
-all must route through repro.compat.  The aliased forms are the ones
+Every spelling here moved, was renamed or changed its defaults between
+jax 0.4 and 0.9; all must route through repro.compat.  The aliased forms are the ones
 the old compat-gate grep could not see.
 """
 import jax
@@ -28,3 +28,7 @@ def bad_pvary(x):
 
 def bad_params():
     return pltpu.CompilerParams(dimension_semantics=())  # expect: RA101
+
+
+def bad_mesh():
+    return jax.make_mesh((2,), ("data",))  # expect: RA101

@@ -1,0 +1,124 @@
+"""Compile the rotation-sequence kernels for a TPU v5e chip.
+
+The chip is described, not attached: JAX's TPU compiler builds each
+kernel at the widths ``chip_smoke.py`` runs (the paper's k=180 waves on
+m=n=3840 and 960, serving buckets of 64 requests, the eigenvector
+flush), for every tile the TPU registry offers.  Interpret-mode tests
+cannot see what Mosaic refuses; these can.  Nothing runs.
+
+The topology is described inside a module fixture, never at import:
+only one process may load the TPU library at a time, and every test
+worker imports every test module.
+"""
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.core import registry
+from repro.kernels.limits import SMEM_PANEL_BUDGET, smem_panel_bytes
+from repro.kernels.rotseq.ops import rot_sequence_wave
+from repro.kernels.rotseq_batched.ops import rot_sequence_batched
+from repro.kernels.rotseq_mxu.ops import rot_sequence_mxu
+
+# (m, n, k) of single-target applications: the paper experiment at the
+# smoke's two sizes and one eigenvector flush (n=1024, k_delay=32)
+SINGLE = [(3840, 3840, 180), (960, 960, 180), (1024, 1024, 32)]
+# (b, m, n, k) serving buckets: 64 slots, per-request waves
+BUCKETS = [(64, 256, 128, 32), (64, 512, 128, 16), (64, 1024, 256, 32),
+           (64, 256, 128, 4)]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without the chip; keep it out of the cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile(fn, sharding, *shapes, dtype=jnp.float32):
+    compiled = jax.jit(fn).lower(*(
+        jax.ShapeDtypeStruct(s, dtype, sharding=sharding)
+        for s in shapes)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return compiled
+
+
+@pytest.mark.parametrize("m,n,k", SINGLE)
+@pytest.mark.parametrize("method,dtype", [("pallas_wave", "float32"),
+                                          ("pallas_mxu", "float32"),
+                                          ("pallas_mxu", "bfloat16")])
+def test_offered_tiles_compile(one_chip, method, dtype, m, n, k):
+    """Every tile the TPU registry offers for the shape compiles."""
+    problem = registry.Problem(m=m, n=n, k=k, dtype=dtype, platform="tpu")
+    plans = registry.get_backend(method).candidates(problem)
+    assert plans, f"{method} offers no tile at {(m, n, k)}"
+    run = rot_sequence_wave if method == "pallas_wave" else rot_sequence_mxu
+    for plan in plans:
+        kw = plan.kwargs()
+        _compile(lambda A, C, S: run(A, C, S, interpret=False, **kw),
+                 one_chip, (m, n), (n - 1, k), (n - 1, k),
+                 dtype=jnp.dtype(dtype))
+
+
+@pytest.mark.parametrize("b,m,n,k", BUCKETS)
+@pytest.mark.parametrize("waves", ["shared", "per_request"])
+def test_fused_bucket_compiles(one_chip, waves, b, m, n, k):
+    """The fused kernel compiles for a serving bucket at every offered
+    lane tile, with one shared or 64 per-request wave stacks."""
+    shared = waves == "shared"
+    problem = registry.Problem(m=m, n=n, k=k, platform="tpu", batch=b,
+                               shared_sequence=shared)
+    plans = registry.rotseq_batched_tiles(problem)
+    assert plans
+    cs = (n - 1, k) if shared else (b, n - 1, k)
+    for plan in plans:
+        _compile(lambda A, C, S: rot_sequence_batched(
+                     A, C, S, interpret=False, m_blk=plan.m_blk),
+                 one_chip, (b, m, n), cs, cs)
+
+
+def test_filtered_tiles_are_refused(one_chip):
+    """What the TPU registry filters out, the chip's compiler refuses:
+    a 64-lane MXU fresh-column block, fused-kernel panels past the SMEM
+    budget, and a bfloat16 slab addressed one row at a time.  If Mosaic
+    ever accepts them, the filters can go."""
+    m = n = 960
+    k = 180
+    bf16 = registry.Problem(m=m, n=n, k=k, dtype="bfloat16", platform="tpu")
+    assert registry.pallas_wave_tiles(bf16) == []
+    with pytest.raises(Exception, match="multiple of 8"):
+        _compile(lambda A, C, S: rot_sequence_wave(
+                     A, C, S, interpret=False, n_b=64, k_b=16, m_blk=256),
+                 one_chip, (m, n), (n - 1, k), (n - 1, k),
+                 dtype=jnp.bfloat16)
+
+    offered = registry.pallas_mxu_tiles(
+        registry.Problem(m=m, n=n, k=k, platform="tpu"))
+    assert all(p.n_b % 128 == 0 for p in offered)
+    with pytest.raises(Exception, match="divisible by 8 and 128"):
+        _compile(lambda A, C, S: rot_sequence_mxu(
+                     A, C, S, interpret=False, n_b=64, k_b=64, m_blk=256),
+                 one_chip, (m, n), (n - 1, k), (n - 1, k))
+
+    b, m, n, k = 4, 256, 344, 32
+    assert smem_panel_bytes(n - 1, k) > SMEM_PANEL_BUDGET
+    assert registry.rotseq_batched_tiles(registry.Problem(
+        m=m, n=n, k=k, platform="tpu", batch=b,
+        shared_sequence=False)) == []
+    with pytest.raises(Exception, match="smem"):
+        _compile(lambda A, C, S: rot_sequence_batched(
+                     A, C, S, interpret=False, m_blk=256),
+                 one_chip, (b, m, n), (b, n - 1, k), (b, n - 1, k))

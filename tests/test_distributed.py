@@ -25,11 +25,12 @@ def _run(code: str):
 def test_row_and_column_sharded_rotseq():
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro import compat
         from repro.core.rotations import random_sequence
         from repro.core.ref import rot_sequence_numpy
         from repro.dist import (rot_sequence_row_sharded,
             rot_sequence_column_sharded_padded)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = compat.make_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(5)
         for (m, n, k, n_b, k_b, method) in [
                 (8, 32, 5, 4, 2, "blocked"), (16, 64, 7, 8, 4, "blocked"),
@@ -85,9 +86,10 @@ def test_core_distributed_compat_wrapper():
     out = _run("""
         import warnings
         import numpy as np, jax, jax.numpy as jnp
+        from repro import compat
         from repro.core.rotations import random_sequence
         from repro import dist
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = compat.make_mesh((8,), ("data",))
         rng = np.random.default_rng(7)
         A = jnp.asarray(rng.standard_normal((16, 32)), jnp.float32)
         seq = random_sequence(jax.random.key(3), 32, 5)
@@ -112,10 +114,11 @@ def test_sharded_fused_parity_and_obs():
     signed, and reflector sequences."""
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro import compat
         from repro import dist, obs
         from repro.core.rotations import random_sequence
         from repro.core.sequence import RotationSequence
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = compat.make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         b, m, n, k = 8, 64, 32, 6
         A = jnp.asarray(rng.standard_normal((b, m, n)), jnp.float32)
@@ -160,10 +163,11 @@ def test_sharded_plan_grad_and_roundtrip():
     to_dict/from_dict round-trip (mesh re-supplied at load)."""
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro import compat
         from repro import dist
         from repro.dist import ShardedSequencePlan
         from repro.core.rotations import random_sequence
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = compat.make_mesh((8,), ("data",))
         rng = np.random.default_rng(2)
         A = jnp.asarray(rng.standard_normal((64, 32)), jnp.float32)
         seq = random_sequence(jax.random.key(4), 32, 6)
@@ -192,9 +196,10 @@ def test_auto_crossover_small_and_large():
     comm-extended ``cost_components`` arbitration)."""
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro import compat
         from repro import dist
         from repro.core.rotations import random_sequence
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = compat.make_mesh((8,), ("data",))
         rng = np.random.default_rng(3)
         for (m, n, k), expect_sharded in [((64, 32, 8), False),
                                           ((2048, 512, 64), True)]:
@@ -290,6 +295,7 @@ def test_mini_dryrun_multipod_mesh():
     the same code path as the production dry-run."""
     out = _run("""
         import jax, jax.numpy as jnp
+        from repro import compat
         from repro.configs import get_config
         from repro.launch.mesh import make_rules_for_mesh
         from repro.launch.specs import (abstract_opt_state, input_specs,
@@ -300,7 +306,7 @@ def test_mini_dryrun_multipod_mesh():
         from repro.train import make_train_step
         from repro.configs.base import ShapeConfig
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = get_config("smollm-135m").reduced()
         shape = ShapeConfig("mini", 64, 8, "train")
         rules = make_rules_for_mesh(mesh)
@@ -332,9 +338,10 @@ def test_hlo_collectives_accounting():
     a scan of length L must count L times."""
     out = _run("""
         import jax, jax.numpy as jnp
+        from repro import compat
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.launch.hlo_analysis import analyze_hlo
-        mesh = jax.make_mesh((8,), ("d",))
+        mesh = compat.make_mesh((8,), ("d",))
         L, M = 5, 64
 
         def f(x, ws):

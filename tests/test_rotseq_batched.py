@@ -189,8 +189,8 @@ def test_registry_routes_staircase_to_fused_on_tpu():
     """seq.T planning: the live-plane-aware cost model sends thin
     staircases to the plane-skipping kernel on TPU while dense grids of
     the same padded shape stay on the GEMM family — and staircases
-    whose C/S/G panels exceed the kernel's SMEM budget are priced off
-    it (interpret mode would run them; Mosaic could not compile them)."""
+    whose C/S/G panels exceed the kernel's SMEM budget are kept off it
+    (interpret mode would run them; Mosaic could not compile them)."""
     clear_plan_cache()
     thin = select_plan(4096, 96, 102, platform="tpu",
                        live_planes=95 * 8)
@@ -290,7 +290,9 @@ def test_fused_capability_record():
                               live_planes=95 * 8)
     plan = registry.Plan("rotseq_batched", m_blk=256)
     assert spec.cost(p_live, plan) < spec.cost(p_dense, plan)
-    # and prices out panels beyond the SMEM budget
+    # and offers no tile where the panels exceed the chip's SMEM (the
+    # kernel could not compile there)
     p_big = registry.Problem(m=4096, n=256, k=263, platform="tpu",
                              live_planes=255 * 8)
-    assert spec.cost(p_big, plan) > 100 * spec.cost(p_live, plan)
+    assert registry.rotseq_batched_tiles(p_live)
+    assert registry.rotseq_batched_tiles(p_big) == []
