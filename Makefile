@@ -71,7 +71,7 @@ bench-compare:
 		BENCH_smoke.json BENCH_eig.json BENCH_serve.json BENCH_dist.json
 
 # Observability report: obs-enabled rotation-serving runs writing the
-# metrics + roofline snapshot (OBS_metrics.json) and a Perfetto-loadable
+# metrics snapshot (OBS_metrics.json) and a Perfetto-loadable
 # Chrome trace (trace.jsonl — load at ui.perfetto.dev), once through the
 # synchronous service and once through the streaming engine
 # (OBS_stream_metrics.json / trace_stream.jsonl, bit-checked against the

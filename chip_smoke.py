@@ -291,8 +291,10 @@ def eig_phase(seed: int) -> None:
     snap = obs.snapshot()
     obs.set_enabled(False)
     flushes = snap["counters"].get("eig.flushes", 0)
-    backends = sorted({(r["backend"], tuple(sorted(r["tile"].items())))
-                       for r in snap["roofline"]["dispatches"]})
+    prefix = "sequence.applies."
+    backends = sorted((name[len(prefix):], int(count))
+                      for name, count in snap["counters"].items()
+                      if name.startswith(prefix))
     log(phase, f"eigh_givens(method='qr'): {wall:.2f} s wall, host "
                f"reduction and compiles included; {flushes:.0f} flushes "
                f"through {backends}")

@@ -324,8 +324,8 @@ class RA204StreamConcurrencyDiscipline(Rule):
       ``concurrent.futures``, ``_thread``, ``multiprocessing``) live
       only in ``repro.serve.stream`` — the serving stack's one
       concurrent component.  Carve-outs for the pre-existing
-      infrastructure users: ``repro.obs.*`` (metric/trace/roofline
-      locks), ``repro.ckpt.manager`` (async checkpoint writer), and
+      infrastructure users: ``repro.obs.*`` (metric/trace locks),
+      ``repro.ckpt.manager`` (async checkpoint writer), and
       ``repro.parallel.sharding`` (a ``threading.local`` for axis-rule
       scoping).
     * ``repro.serve.stream`` itself executes only through

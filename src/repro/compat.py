@@ -128,10 +128,9 @@ def is_tpu() -> bool:
 def is_tracer(x: Any) -> bool:
     """Whether ``x`` is an abstract tracer (inside jit/vmap/grad).
 
-    Host-side instrumentation (repro.obs spans, roofline timing) must
-    be a no-op under tracing — there is no concrete value to time and
-    ``block_until_ready`` would be meaningless — so every instrumented
-    seam guards with this.
+    Host-side instrumentation (repro.obs spans and counters) must be a
+    no-op under tracing — a traced call dispatches nothing — so every
+    instrumented seam guards with this.
     """
     return isinstance(x, jax.core.Tracer)
 

@@ -22,6 +22,12 @@ path's per-request buckets — while the GEMM sweep is *stream*, scaling
 with the rows of ``A``.  That asymmetry is why this backend wins
 batched accumulator flushes yet loses serving buckets of the same
 shape to the fused kernel.
+
+The phases carry ``jax.named_scope`` names that reach the device trace
+(the ``tf_op`` of each XLA op): ``rotseq.pack`` and
+``rotseq.accumulate`` are the setup, ``rotseq.sweep`` the stream.  Every
+backend names its phases at their call sites, so the backends that
+share these helpers (``accumulated``, ``pallas_mxu``) read alike.
 """
 from __future__ import annotations
 
@@ -32,7 +38,8 @@ import jax.numpy as jnp
 
 from repro import compat
 
-from .blocked import _band_inputs, apply_tile, num_tiles, pack_sheared
+from .blocked import (ACCUMULATE, PACK, SWEEP, _band_inputs, apply_tile,
+                      num_tiles, pack_sheared)
 
 __all__ = [
     "accumulate_tile_factors",
@@ -91,8 +98,11 @@ def rot_sequence_accumulated(A, C, S, *, n_b: int = 128, k_b: int = 128,
     n_b = min(n_b, max(8, n))
     T = num_tiles(n, n_b, k_b)
     for p0 in range(0, k, k_b):
-        Ct, St, Gt = pack_sheared(C, S, p0, k_b, n_b, T, reflect=reflect,
-                                  G=G)
-        Q = accumulate_tile_factors(Ct, St, Gt, dtype=A.dtype)
-        A = apply_band_accumulated(A, Q, k_b=k_b)
+        with jax.named_scope(PACK):
+            Ct, St, Gt = pack_sheared(C, S, p0, k_b, n_b, T,
+                                      reflect=reflect, G=G)
+        with jax.named_scope(ACCUMULATE):
+            Q = accumulate_tile_factors(Ct, St, Gt, dtype=A.dtype)
+        with jax.named_scope(SWEEP):
+            A = apply_band_accumulated(A, Q, k_b=k_b)
     return A

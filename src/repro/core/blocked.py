@@ -33,12 +33,24 @@ import jax.numpy as jnp
 from repro.core.rotations import plane_update
 
 __all__ = [
+    "PACK",
+    "ACCUMULATE",
+    "SWEEP",
     "pack_sheared",
     "apply_tile",
     "apply_band",
     "rot_sequence_blocked",
     "num_tiles",
 ]
+
+
+# Names of an application's phases on the device (``jax.named_scope``,
+# read back from the profiler trace): the per-sequence setup (packing
+# the waves into tiles, accumulating tile factors) and the stream over
+# the target's rows (docs/cost-model.md).
+PACK = "rotseq.pack"
+ACCUMULATE = "rotseq.accumulate"
+SWEEP = "rotseq.sweep"
 
 
 def num_tiles(n: int, n_b: int, k_b: int) -> int:
@@ -155,7 +167,9 @@ def rot_sequence_blocked(A, C, S, *, n_b: int = 64, k_b: int = 16,
     n_b = min(n_b, max(8, n))  # don't tile wider than the matrix
     T = num_tiles(n, n_b, k_b)
     for p0 in range(0, k, k_b):  # bands, sequential (python loop: k/k_b small)
-        Ct, St, Gt = pack_sheared(C, S, p0, k_b, n_b, T, reflect=reflect,
-                                  G=G)
-        A = apply_band(A, Ct, St, Gt)
+        with jax.named_scope(PACK):
+            Ct, St, Gt = pack_sheared(C, S, p0, k_b, n_b, T,
+                                      reflect=reflect, G=G)
+        with jax.named_scope(SWEEP):
+            A = apply_band(A, Ct, St, Gt)
     return A

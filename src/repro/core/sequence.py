@@ -515,17 +515,14 @@ class SequencePlan:
         if self.method == _IDENTITY:
             return A
         seq = self.sequence
-        if not obs.enabled() or compat.is_tracer(A):
+        if compat.is_tracer(A):
             return _apply_planned(self.method, self.kwargs, seq.reflect,
                                   A, seq.cos, seq.sin, seq.sign)
         with obs.span("apply", method=self.method, m=int(A.shape[0]),
                       n=int(A.shape[1])):
-            t0 = obs.timing.now()
             out = _apply_planned(self.method, self.kwargs, seq.reflect,
                                  A, seq.cos, seq.sin, seq.sign)
-            out = jax.block_until_ready(out)
-            dt = obs.timing.now() - t0
-        self._record_dispatch(A, dt)
+        self._count_apply()
         return out
 
     __call__ = apply
@@ -543,16 +540,13 @@ class SequencePlan:
         if self.method == _IDENTITY:
             return A
         seq = self.sequence
-        if not obs.enabled() or compat.is_tracer(A):
+        if compat.is_tracer(A):
             return _run_backend(self.method, self.kwargs, seq.reflect,
                                 A, seq.cos, seq.sin, seq.sign)
         with obs.span("apply", method=self.method, direct=True):
-            t0 = obs.timing.now()
             out = _run_backend(self.method, self.kwargs, seq.reflect,
                                A, seq.cos, seq.sin, seq.sign)
-            out = jax.block_until_ready(out)
-            dt = obs.timing.now() - t0
-        self._record_dispatch(A, dt)
+        self._count_apply()
         return out
 
     def apply_batched(self, A, sequences=None, *, direct: bool = False):
@@ -597,16 +591,13 @@ class SequencePlan:
                 f"got {A.shape} — use apply() for a single target")
         if self.method == _IDENTITY:
             return A
-        if not obs.enabled() or compat.is_tracer(A):
+        if compat.is_tracer(A):
             return self._apply_batched_impl(A, sequences, direct)
         with obs.span("apply_batched", method=self.method,
                       batch=int(A.shape[0]), m=int(A.shape[1]),
                       n=int(A.shape[2])):
-            t0 = obs.timing.now()
             out = self._apply_batched_impl(A, sequences, direct)
-            out = jax.block_until_ready(out)
-            dt = obs.timing.now() - t0
-        self._record_dispatch(A, dt, shared=sequences is None)
+        self._count_apply()
         return out
 
     def _apply_batched_impl(self, A, sequences, direct: bool):
@@ -672,51 +663,11 @@ class SequencePlan:
                 f"plan built for n={self.sequence.n} targets; "
                 f"got A.shape={A.shape}")
 
-    def _record_dispatch(self, A, measured_s: float,
-                         shared: bool = True) -> None:
-        """Roofline-attribute one completed host-side dispatch.
-
-        Called only on the obs-enabled, non-traced path, *after* the
-        result is device-complete: pairs the §6 cost model's predicted
-        flops/bytes/seconds — including the per-sequence setup vs
-        per-row stream split, priced per-request when the batch carried
-        distinct sequences (``shared=False``) — for this exact
-        (problem, backend, tile) with the measured wall time (see
-        :mod:`repro.obs.roofline`).
-        """
-        seq = self.sequence
-        if A.ndim == 3:
-            b, m = int(A.shape[0]), int(A.shape[1])
-        else:
-            b, m = 1, int(A.shape[0])
-        kw = dict(self.kwargs)
-        problem = registry.Problem(
-            m=m, n=seq.n, k=seq.k, dtype=str(A.dtype),
-            platform=compat.default_platform(),
-            signs=seq.sign is not None, batch=b, shared_sequence=shared,
-            live_planes=seq.k_live)
-        rplan = self.plan if self.plan is not None else registry.Plan(
-            method=self.method, n_b=kw.get("n_b"), k_b=kw.get("k_b"),
-            m_blk=kw.get("m_blk"))
-        try:
-            comp = registry.cost_components(self.method, problem, rplan)
-        except ValueError:  # unregistered/identity method: no model
-            comp = {"flops": 0.0, "bytes": 0.0, "seconds": 0.0,
-                    "setup": {"seconds": 0.0}, "stream": {"seconds": 0.0}}
-        obs.roofline.record_dispatch(
-            backend=self.method, m_total=problem.m_total, n=seq.n,
-            k=seq.k, batch=b, dtype=str(A.dtype),
-            tile={key: val for key, val in kw.items()
-                  if key in ("n_b", "k_b", "m_blk")},
-            planes_live=problem.planes_live,
-            planes_total=problem.planes_total,
-            predicted_flops=comp["flops"], predicted_bytes=comp["bytes"],
-            predicted_s=comp["seconds"], measured_s=measured_s,
-            predicted_setup_s=comp["setup"]["seconds"],
-            predicted_stream_s=comp["stream"]["seconds"],
-            shared_sequence=shared)
-        obs.inc("sequence.applies")
-        obs.observe("sequence.apply_seconds", measured_s)
+    def _count_apply(self) -> None:
+        """Count one concrete application, in all and per backend."""
+        if obs.enabled():
+            obs.inc("sequence.applies")
+            obs.inc(f"sequence.applies.{self.method}")
 
     def rebind(self, sequence: RotationSequence) -> "SequencePlan":
         """Bind this (method, tiles) decision to a new same-shape sequence."""

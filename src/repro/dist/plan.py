@@ -32,9 +32,8 @@ transposed-sequence VJP shard-locally with zero extra collectives.
 
 Observability: kernel-side launch accounting is tracer-guarded and
 cannot fire under ``shard_map`` tracing, so the plan self-accounts
-host-side — ``dist.launches_per_shard``, ``dist.comm_bytes``, and
-roofline rows attributed with the same comm-extended components the
-planner ranked by.
+host-side — ``dist.launches_per_shard`` and ``dist.comm_bytes``,
+from the same comm-extended components the planner ranked by.
 """
 from __future__ import annotations
 
@@ -299,15 +298,13 @@ class ShardedSequencePlan:
             inner = self._inner()
             return inner.apply_direct(A) if direct else inner.apply(A)
         self._check_rows(A.shape[-2])
-        if not obs.enabled() or compat.is_tracer(A):
+        if compat.is_tracer(A):
             return self._row_sharded_2d(A, direct)
         with obs.span("dist.apply", method=self.method,
                       devices=self.devices, m=int(A.shape[0]),
                       n=int(A.shape[1])):
-            t0 = obs.timing.now()
-            out = jax.block_until_ready(self._row_sharded_2d(A, direct))
-            dt = obs.timing.now() - t0
-        self._record_dispatch(A, dt, launches=1)
+            out = self._row_sharded_2d(A, direct)
+        self._count_dispatch(A, launches=1)
         return out
 
     __call__ = apply
@@ -339,17 +336,14 @@ class ShardedSequencePlan:
                                                direct=direct)
         self._check_rows(A.shape[1])
         launches = self._launches_per_shard(int(A.shape[0]))
-        if not obs.enabled() or compat.is_tracer(A):
+        if compat.is_tracer(A):
             return self._row_sharded_batched(A, sequences, direct)
         with obs.span("dist.apply_batched", method=self.method,
                       devices=self.devices, batch=int(A.shape[0]),
                       m=int(A.shape[1]), n=int(A.shape[2])):
-            t0 = obs.timing.now()
-            out = jax.block_until_ready(
-                self._row_sharded_batched(A, sequences, direct))
-            dt = obs.timing.now() - t0
-        self._record_dispatch(A, dt, launches=launches,
-                              shared=sequences is None)
+            out = self._row_sharded_batched(A, sequences, direct)
+        self._count_dispatch(A, launches=launches,
+                             shared=sequences is None)
         return out
 
     # -- sharded executors ------------------------------------------------
@@ -503,56 +497,33 @@ class ShardedSequencePlan:
             devices=self.devices)
         return registry._comm_components(problem)
 
-    def _record_dispatch(self, A, measured_s: float, *, launches: int,
-                         shared: bool = True) -> None:
-        """Host-side obs attribution of one completed sharded dispatch.
+    def _count_dispatch(self, A, *, launches: int,
+                        shared: bool = True) -> None:
+        """Host-side obs accounting of one sharded dispatch.
 
         The fused kernel's own launch accounting is tracer-guarded and
         never fires under ``shard_map`` tracing, so the dist layer is
-        the accounting authority for its dispatches: comm bytes and
-        launches-per-shard come from the same comm-extended model the
-        planner ranked with.
+        the accounting authority for its dispatches: comm bytes come
+        from the same comm-extended model the planner ranked with.
         """
+        if not obs.enabled():
+            return
         seq = self.sequence
         if A.ndim == 3:
             b, m = int(A.shape[0]), int(A.shape[1])
         else:
             b, m = 1, int(A.shape[0])
-        kw = dict(self.kwargs)
         problem = registry.Problem(
             m=m, n=seq.n, k=seq.k, dtype=str(A.dtype),
             platform=compat.default_platform(),
             signs=seq.sign is not None, sharded=True, batch=b,
             shared_sequence=shared, live_planes=seq.k_live,
             devices=self.devices)
-        rplan = self.plan if self.plan is not None else registry.Plan(
-            method=self.method, n_b=kw.get("n_b"), k_b=kw.get("k_b"),
-            m_blk=kw.get("m_blk"))
-        try:
-            comp = registry.cost_components(self.method, problem, rplan)
-        except ValueError:
-            comp = {"flops": 0.0, "bytes": 0.0, "seconds": 0.0,
-                    "setup": {"seconds": 0.0}, "stream": {"seconds": 0.0},
-                    "comm": {"bytes": 0.0, "seconds": 0.0}}
-        comm_bytes = comp.get("comm", {}).get("bytes", 0.0)
-        obs.roofline.record_dispatch(
-            backend=self.method, m_total=problem.m_total, n=seq.n,
-            k=seq.k, batch=b, dtype=str(A.dtype),
-            tile={key: val for key, val in kw.items()
-                  if key in ("n_b", "k_b", "m_blk")},
-            planes_live=problem.planes_live,
-            planes_total=problem.planes_total,
-            predicted_flops=comp["flops"], predicted_bytes=comp["bytes"],
-            predicted_s=comp["seconds"], measured_s=measured_s,
-            predicted_setup_s=comp["setup"]["seconds"],
-            predicted_stream_s=comp["stream"]["seconds"],
-            shared_sequence=shared, comm_bytes=comm_bytes,
-            launches_per_shard=launches)
         obs.inc("dist.applies")
-        obs.inc("dist.comm_bytes", comm_bytes)
+        comm = registry._comm_components(problem)
+        obs.inc("dist.comm_bytes", comm["bytes"])
         obs.gauge("dist.devices", self.devices)
         obs.gauge("dist.launches_per_shard", launches)
-        obs.observe("dist.apply_seconds", measured_s)
 
     # -- rebinding / serialization ----------------------------------------
     def rebind(self, sequence: RotationSequence) -> "ShardedSequencePlan":

@@ -60,6 +60,7 @@ def rope_pallas(q, k, cos, sin, *, heads_q: int, heads_k: int,
     )
     return pl.pallas_call(
         kernel,
+        name="rope",
         grid=grid,
         in_specs=[
             pl.BlockSpec((blk, half), lambda i: (i, 0)),

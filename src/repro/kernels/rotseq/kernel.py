@@ -107,6 +107,7 @@ def rotseq_wave_pallas(ATfresh, Ct, St, Gt, init, *, n_b: int, k_b: int,
                            memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kernel,
+        name="rotseq_wave",
         grid=grid,
         in_specs=[
             cs_spec,

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import compat, obs
-from repro.core.blocked import num_tiles, pack_sheared
+from repro.core.blocked import PACK, SWEEP, num_tiles, pack_sheared
 from repro.kernels.limits import round_up
 
 from .kernel import rotseq_wave_pallas
@@ -65,20 +65,24 @@ def _rot_sequence_wave_jit(A, C, S, *, n_b: int = 64, k_b: int = 16,
     T = num_tiles(n, n_b, k_b)
 
     m_pad = round_up(m, m_blk)
-    AT = jnp.pad(A.T, ((0, 0), (0, m_pad - m)))  # packed layout (n, m_pad)
+    with jax.named_scope(SWEEP):  # packed layout (n, m_pad)
+        AT = jnp.pad(A.T, ((0, 0), (0, m_pad - m)))
 
     for p0 in range(0, k, k_b):
-        Ct, St, Gt = pack_sheared(C, S, p0, k_b, n_b, T, reflect=reflect,
-                                  G=G)
-        init = jnp.concatenate(
-            [jnp.zeros((k_b - 1, m_pad), AT.dtype), AT[:1]], axis=0
-        )
-        fresh = jnp.pad(AT[1:], ((0, T * n_b - (n - 1)), (0, 0)))
-        O = rotseq_wave_pallas(
-            fresh, Ct, St, Gt, init,
-            n_b=n_b, k_b=k_b, m_blk=min(m_blk, m_pad),
-            interpret=interpret,
-        )
-        AT = jax.lax.slice_in_dim(O, k_b - 1, k_b - 1 + n, axis=0)
+        with jax.named_scope(PACK):
+            Ct, St, Gt = pack_sheared(C, S, p0, k_b, n_b, T,
+                                      reflect=reflect, G=G)
+        with jax.named_scope(SWEEP):
+            init = jnp.concatenate(
+                [jnp.zeros((k_b - 1, m_pad), AT.dtype), AT[:1]], axis=0
+            )
+            fresh = jnp.pad(AT[1:], ((0, T * n_b - (n - 1)), (0, 0)))
+            O = rotseq_wave_pallas(
+                fresh, Ct, St, Gt, init,
+                n_b=n_b, k_b=k_b, m_blk=min(m_blk, m_pad),
+                interpret=interpret,
+            )
+            AT = jax.lax.slice_in_dim(O, k_b - 1, k_b - 1 + n, axis=0)
 
-    return AT[:, :m].T  # unpack
+    with jax.named_scope(SWEEP):
+        return AT[:, :m].T  # unpack

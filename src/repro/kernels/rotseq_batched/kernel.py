@@ -130,6 +130,7 @@ def rotseq_batched_pallas(AT, C, S, G, starts, counts, *, m_blk: int,
     kernel = functools.partial(_batched_kernel, K=K)
     out, planes = pl.pallas_call(
         kernel,
+        name="rotseq_batched",
         grid=grid,
         in_specs=[
             window_spec,

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import compat, obs
+from repro.core.blocked import SWEEP
 from repro.kernels.limits import clamp_m_blk, round_up
 
 from .kernel import rotseq_batched_pallas
@@ -148,35 +149,38 @@ def _rot_sequence_batched_jit(A, C, S, *, reflect: bool = False, G=None,
                               return_planes: bool = False):
     if interpret is None:
         interpret = compat.pallas_interpret_default()
-    single = A.ndim == 2
-    if single:
-        A = A[None]
-    b, m, n = A.shape
-    if C.ndim == 2:
-        C = C[None]
-        S = S[None]
-        if G is not None:
-            G = G[None]
-    bs, J, K = C.shape
-    assert J == n - 1, (C.shape, A.shape)
-    assert bs in (1, b), (C.shape, A.shape)
-    if G is None:
-        G = jnp.full(C.shape, 1.0 if reflect else -1.0, C.dtype)
-    starts, counts = wave_windows(C, S, G)
+    # the whole launch, packing included, is the stream over the rows
+    with jax.named_scope(SWEEP):
+        single = A.ndim == 2
+        if single:
+            A = A[None]
+        b, m, n = A.shape
+        if C.ndim == 2:
+            C = C[None]
+            S = S[None]
+            if G is not None:
+                G = G[None]
+        bs, J, K = C.shape
+        assert J == n - 1, (C.shape, A.shape)
+        assert bs in (1, b), (C.shape, A.shape)
+        if G is None:
+            G = jnp.full(C.shape, 1.0 if reflect else -1.0, C.dtype)
+        starts, counts = wave_windows(C, S, G)
 
-    # never tile (and pad) wider than the target: small serve-bucket
-    # rows would otherwise pay m_blk lanes of identity work per plane
-    # (multiples of 8 keep sublane alignment; use 128+ on hardware)
-    m_blk = clamp_m_blk(m, m_blk)
-    m_pad = round_up(m, m_blk)
-    AT = jnp.pad(jnp.swapaxes(A, 1, 2), ((0, 0), (0, 0), (0, m_pad - m)))
-    out, planes = rotseq_batched_pallas(
-        AT, C, S, G, starts, counts,
-        m_blk=m_blk, interpret=interpret,
-    )
-    out = jnp.swapaxes(out[:, :, :m], 1, 2)
-    if single:
-        out = out[0]
-    if return_planes:
-        return out, planes
-    return out
+        # never tile (and pad) wider than the target: small serve-bucket
+        # rows would otherwise pay m_blk lanes of identity work per plane
+        # (multiples of 8 keep sublane alignment; use 128+ on hardware)
+        m_blk = clamp_m_blk(m, m_blk)
+        m_pad = round_up(m, m_blk)
+        AT = jnp.pad(jnp.swapaxes(A, 1, 2),
+                     ((0, 0), (0, 0), (0, m_pad - m)))
+        out, planes = rotseq_batched_pallas(
+            AT, C, S, G, starts, counts,
+            m_blk=m_blk, interpret=interpret,
+        )
+        out = jnp.swapaxes(out[:, :, :m], 1, 2)
+        if single:
+            out = out[0]
+        if return_planes:
+            return out, planes
+        return out

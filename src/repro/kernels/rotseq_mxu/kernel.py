@@ -78,6 +78,7 @@ def rotseq_mxu_pallas(fresh, Q, init, *, n_b: int, k_b: int, m_blk: int,
     kernel = functools.partial(_mxu_kernel, n_b=n_b, k_b=k_b)
     return pl.pallas_call(
         kernel,
+        name="rotseq_mxu",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, w, w), lambda i, t: (t, 0, 0)),

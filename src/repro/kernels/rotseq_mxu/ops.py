@@ -9,7 +9,8 @@ import jax.numpy as jnp
 
 from repro import compat, obs
 from repro.core.accumulate import accumulate_tile_factors
-from repro.core.blocked import num_tiles, pack_sheared
+from repro.core.blocked import (ACCUMULATE, PACK, SWEEP, num_tiles,
+                                pack_sheared)
 from repro.kernels.limits import round_up
 
 from .kernel import rotseq_mxu_pallas
@@ -58,20 +59,25 @@ def _rot_sequence_mxu_jit(A, C, S, *, n_b: int = 128, k_b: int = 128,
     T = num_tiles(n, n_b, k_b)
 
     m_pad = round_up(m, m_blk)
-    Ap = jnp.pad(A, ((0, m_pad - m), (0, 0)))
+    with jax.named_scope(SWEEP):
+        Ap = jnp.pad(A, ((0, m_pad - m), (0, 0)))
 
     for p0 in range(0, k, k_b):
-        Ct, St, Gt = pack_sheared(C, S, p0, k_b, n_b, T, reflect=reflect,
-                                  G=G)
-        Q = accumulate_tile_factors(Ct, St, Gt, dtype=Ap.dtype)
-        init = jnp.concatenate(
-            [jnp.zeros((m_pad, k_b - 1), Ap.dtype), Ap[:, :1]], axis=1
-        )
-        fresh = jnp.pad(Ap[:, 1:], ((0, 0), (0, T * n_b - (n - 1))))
-        O = rotseq_mxu_pallas(
-            fresh, Q, init, n_b=n_b, k_b=k_b,
-            m_blk=min(m_blk, m_pad), interpret=interpret,
-        )
-        Ap = jax.lax.slice_in_dim(O, k_b - 1, k_b - 1 + n, axis=1)
+        with jax.named_scope(PACK):
+            Ct, St, Gt = pack_sheared(C, S, p0, k_b, n_b, T,
+                                      reflect=reflect, G=G)
+        with jax.named_scope(ACCUMULATE):
+            Q = accumulate_tile_factors(Ct, St, Gt, dtype=Ap.dtype)
+        with jax.named_scope(SWEEP):
+            init = jnp.concatenate(
+                [jnp.zeros((m_pad, k_b - 1), Ap.dtype), Ap[:, :1]], axis=1
+            )
+            fresh = jnp.pad(Ap[:, 1:], ((0, 0), (0, T * n_b - (n - 1))))
+            O = rotseq_mxu_pallas(
+                fresh, Q, init, n_b=n_b, k_b=k_b,
+                m_blk=min(m_blk, m_pad), interpret=interpret,
+            )
+            Ap = jax.lax.slice_in_dim(O, k_b - 1, k_b - 1 + n, axis=1)
 
-    return Ap[:m]
+    with jax.named_scope(SWEEP):
+        return Ap[:m]

@@ -23,11 +23,12 @@ service::
       --requests 64 --slots 8
 
 With ``--metrics-json PATH`` the run executes with ``repro.obs``
-enabled and writes the full metrics + roofline snapshot (plan-cache
-counters, admit→drain latency histogram p50/p99, per-backend
-model-vs-measured fractions) to ``PATH``; ``--trace PATH`` additionally
-exports a Perfetto-loadable Chrome trace of the plan / admit / drain /
-apply spans.  ``make obs-report`` packages the canonical invocations
+enabled and writes the metrics snapshot (plan-cache counters, apply
+counts per backend, admit→drain latency histogram p50/p99) to
+``PATH``; ``--trace PATH`` additionally exports a Perfetto-loadable
+Chrome trace of the plan / admit / drain / apply spans, stamped in
+Unix-epoch microseconds (the clock of a ``jax.profiler`` trace's host
+plane, where the same spans appear as ``repro.<name>``).  ``make obs-report`` packages the canonical invocations
 (synchronous and streaming, each with its own artifact pair).
 """
 from __future__ import annotations
@@ -184,11 +185,12 @@ def main():
     ap.add_argument("--check", action="store_true",
                     help="rotation mode: verify against per-request apply")
     ap.add_argument("--metrics-json", default=None,
-                    help="enable repro.obs and write the metrics + "
-                         "roofline snapshot here")
+                    help="enable repro.obs and write the metrics "
+                         "snapshot here")
     ap.add_argument("--trace", default=None,
                     help="enable span tracing and write Chrome trace "
-                         "JSON here (view in ui.perfetto.dev)")
+                         "JSON here, timestamps in Unix-epoch "
+                         "microseconds (view in ui.perfetto.dev)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     compat.enable_compile_cache()

@@ -1,21 +1,25 @@
 """repro.obs — zero-overhead-when-disabled observability.
 
-Three pieces (tentpole of PR 7):
+Two pieces:
 
 - **Metrics** (:mod:`repro.obs.metrics`): counters, gauges, and
   deterministic log-spaced-bucket histograms, exported as a plain-JSON
   snapshot.  No wall-clock or RNG in any metrics path (RA5).
 - **Tracing** (:mod:`repro.obs.trace`): host-side spans around
-  plan / resolve / rebind / apply / flush / admit / drain, exported as
-  Chrome trace-event JSON viewable in Perfetto.
-- **Roofline attribution** (:mod:`repro.obs.roofline`): per-dispatch
-  predicted-vs-measured records driven by the registry's §6 cost
-  model.
+  plan / resolve / rebind / apply / flush / admit / drain.  Under an
+  active ``jax.profiler`` session each span is a ``repro.<name>``
+  annotation in the trace's host plane, on the clock of the device
+  ops; with a trace path the spans are also buffered as Chrome
+  trace-event JSON viewable in Perfetto.  Spans never wait for the
+  device: what an application costs on the chip is read from the
+  profiler trace, where the backends name their phases
+  (``rotseq.pack`` / ``rotseq.accumulate`` / ``rotseq.sweep``).
 
 Configuration is environment-first: ``REPRO_OBS=on`` enables
 recording (default off — the tier-1 suite runs with every hook on the
 shared null fast path), ``REPRO_OBS_TRACE=PATH`` additionally buffers
-spans for trace export.  Tests and launchers flip the switch
+spans for trace export.  Profiler annotations need neither: they
+follow the profiler session.  Tests and launchers flip the switch
 programmatically via :func:`set_enabled` / :func:`override`.
 
 This package is also the **single sanctioned home for timing**
@@ -29,7 +33,7 @@ from __future__ import annotations
 import json
 from typing import Any
 
-from repro.obs import metrics, roofline, runtime, timing, trace
+from repro.obs import metrics, runtime, timing, trace
 from repro.obs.metrics import zeroed_timings
 from repro.obs.runtime import enabled, override, set_enabled
 from repro.obs.trace import span
@@ -37,8 +41,7 @@ from repro.obs.trace import span
 __all__ = [
     "enabled", "set_enabled", "override", "span", "inc", "gauge",
     "observe", "snapshot", "reset", "write_metrics_json", "write_trace",
-    "zeroed_timings", "timing", "metrics", "roofline", "runtime",
-    "trace",
+    "zeroed_timings", "timing", "metrics", "runtime", "trace",
 ]
 
 
@@ -61,16 +64,13 @@ def observe(name: str, value: float, unit: str = "seconds") -> None:
 
 
 def snapshot() -> dict:
-    """Full metrics + roofline snapshot as a JSON-clean dict."""
-    snap = metrics.GLOBAL.snapshot()
-    snap["roofline"] = roofline.snapshot()
-    return snap
+    """Full metrics snapshot as a JSON-clean dict."""
+    return metrics.GLOBAL.snapshot()
 
 
 def reset() -> None:
-    """Clear all recorded metrics, spans, and roofline records."""
+    """Clear all recorded metrics and buffered spans."""
     metrics.GLOBAL.reset()
-    roofline.reset()
     trace.reset()
 
 

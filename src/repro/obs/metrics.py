@@ -208,9 +208,8 @@ def zeroed_timings(snap: dict) -> dict:
     distributions of count-valued histograms (``unit != "seconds"``)
     are kept verbatim — they are deterministic for a fixed workload.
     For seconds-valued histograms the value-derived fields
-    (sum/min/max/p50/p99/buckets) are zeroed; roofline records (if
-    present) lose ``measured_s`` / ``model_fraction``.  Two runs of the
-    same request stream must produce bit-identical zeroed snapshots.
+    (sum/min/max/p50/p99/buckets) are zeroed.  Two runs of the same
+    request stream must produce bit-identical zeroed snapshots.
     """
     out = json.loads(json.dumps(snap))  # cheap deep copy, JSON-clean
     for h in out.get("histograms", {}).values():
@@ -221,14 +220,6 @@ def zeroed_timings(snap: dict) -> dict:
             h["p50"] = 0.0
             h["p99"] = 0.0
             h["buckets"] = {}
-    roof = out.get("roofline")
-    if roof:
-        for rec in roof.get("dispatches", []):
-            rec["measured_s"] = 0.0
-            rec["model_fraction"] = 0.0
-        for agg in roof.get("by_backend", {}).values():
-            agg["measured_s"] = 0.0
-            agg["model_fraction"] = 0.0
     return out
 
 

@@ -1,15 +1,27 @@
-"""Host-side tracing spans → Chrome trace-event JSON (Perfetto).
+"""Host-side spans on the profiler's clock.
 
-Spans are recorded as complete events (``"ph": "X"``) with
-microsecond timestamps relative to the first span in the buffer, one
-thread lane per Python thread.  :func:`write_trace` emits the
-``{"traceEvents": [...]}`` wrapper with one event per line — the file
-loads directly in https://ui.perfetto.dev or ``chrome://tracing``.
+:func:`span` opens a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>`` whenever a profiler session is active, whatever
+``REPRO_OBS`` says: the span then lands in the trace's host plane,
+beside the device operations it launched and on the same clock.  The
+annotation carries the name alone, so it stays stable across calls.
 
-Spans must only ever wrap *host* code (plan resolution, bucket drains,
-flushes, blocking apply calls).  Nothing here is safe or meaningful
-inside jit/traced code, which is why the instrumented seams guard with
-``repro.compat.is_tracer(x)`` before opening a span.
+With obs on and a trace path (``REPRO_OBS_TRACE``) each span is also
+buffered as a Chrome complete event (``"ph": "X"``) with its arguments,
+one thread lane per Python thread.  Its ``ts`` is Unix-epoch
+microseconds, the clock of the profiler's host plane, so a span in this
+export and the same span in a profiler trace agree to the microsecond.
+:func:`write_trace` emits the ``{"traceEvents": [...]}`` wrapper with
+one event per line — the file loads directly in https://ui.perfetto.dev
+or ``chrome://tracing``.
+
+Spans wrap *host* code only and never wait for the device: a span
+around an application covers its dispatch, and the device time it
+launched is read from the profiler trace.  Nothing here is safe or
+meaningful inside jit/traced code, which is why the instrumented seams
+guard with ``repro.compat.is_tracer(x)`` before opening a span.  With
+no profiler session and no trace path, a span costs one TraceMe check
+and returns a shared null object.
 """
 from __future__ import annotations
 
@@ -17,25 +29,35 @@ import json
 import threading
 from typing import Any, Dict, List
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs import runtime, timing
+
+PREFIX = "repro."
 
 _lock = threading.Lock()
 _events: List[Dict[str, Any]] = []
-_origin: float | None = None
 
 
 class _Span:
-    """Context manager recording one complete ("X") trace event."""
+    """One span: a profiler annotation, a buffered Chrome event, or both."""
 
-    __slots__ = ("name", "args", "_t0")
+    __slots__ = ("name", "args", "_annotation", "_record", "_t0")
 
-    def __init__(self, name: str, args: Dict[str, Any]):
+    def __init__(self, name: str, args: Dict[str, Any], annotate: bool,
+                 record: bool):
         self.name = name
         self.args = args
-        self._t0 = 0.0
+        self._annotation = TraceAnnotation(PREFIX + name) \
+            if annotate else None
+        self._record = record
+        self._t0 = 0
 
     def __enter__(self) -> "_Span":
-        self._t0 = timing.now()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        if self._record:
+            self._t0 = timing.wall_ns()
         return self
 
     def set(self, **kw: Any) -> None:
@@ -43,20 +65,20 @@ class _Span:
         self.args.update(kw)
 
     def __exit__(self, *exc) -> None:
-        t1 = timing.now()
-        global _origin
-        with _lock:
-            if _origin is None:
-                _origin = self._t0
-            _events.append({
-                "name": self.name,
-                "ph": "X",
-                "ts": round((self._t0 - _origin) * 1e6, 3),
-                "dur": round((t1 - self._t0) * 1e6, 3),
-                "pid": 1,
-                "tid": threading.get_ident() & 0xFFFF,
-                "args": self.args,
-            })
+        if self._record:
+            t1 = timing.wall_ns()
+            with _lock:
+                _events.append({
+                    "name": self.name,
+                    "ph": "X",
+                    "ts": self._t0 / 1e3,
+                    "dur": (t1 - self._t0) / 1e3,
+                    "pid": 1,
+                    "tid": threading.get_ident() & 0xFFFF,
+                    "args": self.args,
+                })
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
 
 
 class _NullSpan:
@@ -78,10 +100,13 @@ NULL_SPAN = _NullSpan()
 
 
 def span(name: str, **args: Any):
-    """Open a span when tracing is live; shared null object otherwise."""
-    if not runtime.trace_enabled():
+    """Open a span when a profiler session or the trace buffer is live;
+    the shared null object otherwise."""
+    annotate = TraceAnnotation.is_enabled()
+    record = runtime.trace_enabled()
+    if not (annotate or record):
         return NULL_SPAN
-    return _Span(name, args)
+    return _Span(name, args, annotate, record)
 
 
 def events() -> List[Dict[str, Any]]:
@@ -90,10 +115,8 @@ def events() -> List[Dict[str, Any]]:
 
 
 def reset() -> None:
-    global _origin
     with _lock:
         _events.clear()
-        _origin = None
 
 
 def write_trace(path: str) -> int:

@@ -137,22 +137,37 @@ def test_sharded_fused_parity_and_obs():
                            shared_sequence=True).apply_batched(A)
             out = plan.apply_batched(A)
             assert jnp.array_equal(out, rep), name
-        # obs attribution: exactly one planned launch per shard, a
-        # modeled comm-bytes counter, and the mesh size as a gauge
+        # obs accounting: exactly one planned launch per shard, a
+        # modeled comm-bytes counter, the mesh size as a gauge, and a
+        # span around each dispatch that never waits for the device
         obs.set_enabled(True)
         obs.reset()
+        obs.runtime.set_trace_path("unused.json")
         plan = dist.plan_sharded(variants["plain"], like=A, mesh=mesh,
                                  method="blocked")
-        plan.apply_batched(A)
+        want = plan.apply_batched(A)   # compiled outside the check
+        obs.reset()
+        wait = jax.block_until_ready
+        def refuse(*a, **k):
+            raise AssertionError("a sharded apply waited for the device")
+        jax.block_until_ready = refuse
+        out2 = plan.apply(A[0])
+        out3 = plan.apply_batched(A)
+        jax.block_until_ready = wait
         snap = obs.snapshot()
+        spans = obs.trace.events()
         obs.set_enabled(False)
+        assert jnp.array_equal(out3, want), "obs changed the output"
+        assert out2.shape == A[0].shape
         assert snap["gauges"]["dist.launches_per_shard"] == 1.0, snap
         assert snap["gauges"]["dist.devices"] == 8.0
         assert snap["counters"]["dist.comm_bytes"] > 0
-        assert snap["counters"]["dist.applies"] == 1
-        rows = [r for r in snap["roofline"]["dispatches"]
-                if r.get("comm_bytes")]
-        assert rows and rows[0]["launches_per_shard"] == 1, rows
+        assert snap["counters"]["dist.applies"] == 2
+        assert "roofline" not in snap
+        assert "dist.apply_seconds" not in snap["histograms"]
+        assert [e["name"] for e in spans] == ["dist.apply",
+                                              "dist.apply_batched"], spans
+        assert spans[1]["args"]["devices"] == 8, spans
         print("PARITY OK")
     """)
     assert "PARITY OK" in out

@@ -1,5 +1,5 @@
-"""repro.obs: metrics primitives, exact dispatch counters, tracing,
-roofline attribution, and the disabled-path bit-identity contract.
+"""repro.obs: metrics primitives, exact dispatch counters, tracing
+on the profiler's clock, and the disabled-path bit-identity contract.
 
 The exact-count tests pin the plan-cache counter semantics across the
 cold -> warm -> interpolated -> autotune-upgrade lifecycle; the
@@ -8,6 +8,7 @@ runs produce bit-identical snapshots once timing-derived fields are
 zeroed, and (b) disabling obs changes neither outputs nor plan-cache
 contents.
 """
+import contextlib
 import json
 
 import jax
@@ -86,6 +87,15 @@ def test_disabled_hooks_record_nothing():
 
 # ------------------------------------------------------------ tracing ----
 
+@contextlib.contextmanager
+def _trace_path(path):
+    prev = obs.runtime.set_trace_path(path)
+    try:
+        yield
+    finally:
+        obs.runtime.set_trace_path(prev)
+
+
 def test_span_is_null_without_a_trace_path():
     with obs.override(True):
         with obs.span("apply", m=4) as sp:
@@ -109,6 +119,87 @@ def test_trace_exports_perfetto_loadable_chrome_events(tmp_path):
     assert ev["ph"] == "X" and ev["name"] == "apply"
     assert ev["args"] == {"m": 4, "method": "blocked"}
     assert ev["dur"] >= 0 and ev["ts"] >= 0
+
+
+def test_span_costs_one_check_with_no_session_and_no_buffer():
+    with obs.override(True):
+        assert obs.span("apply", m=4) is obs.trace.NULL_SPAN
+    with obs.override(False), _trace_path("unused.json"):
+        assert obs.span("apply", m=4) is obs.trace.NULL_SPAN
+
+
+def _host_events(log_dir, name):
+    """``(absolute start ns, duration ns)`` of the host-plane events
+    called ``name`` in the one trace under ``log_dir``."""
+    import glob
+    import os
+    import warnings
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    pd = ProfileData.from_file(path)
+    start = None
+    found = []
+    for plane in pd.planes:
+        if plane.name == "Task Environment":
+            with warnings.catch_warnings():   # the binding type warns
+                warnings.simplefilter("ignore", DeprecationWarning)
+                start = dict(plane.stats)["profile_start_time"]
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                found += [(e.start_ns, e.duration_ns) for e in line.events
+                          if e.name == name]
+    assert start is not None
+    return [(start + a, d) for a, d in found]
+
+
+@pytest.mark.parametrize("obs_on", [False, True])
+def test_span_is_a_profiler_annotation_on_the_exported_clock(
+        tmp_path, obs_on):
+    # under a profiler session the span reaches the trace's host plane
+    # as repro.<name> whatever REPRO_OBS says; the Chrome export, when
+    # on, is stamped on the same (Unix-epoch) clock
+    log_dir = str(tmp_path / "xplane")
+    with obs.override(obs_on), _trace_path(str(tmp_path / "t.json")):
+        with jax.profiler.trace(log_dir):
+            with obs.span("probe", m=4):
+                jnp.ones(8).block_until_ready()
+    (start_ns, dur_ns), = _host_events(log_dir, "repro.probe")
+    assert dur_ns > 0
+    chrome = [ev for ev in obs.trace.events() if ev["name"] == "probe"]
+    if not obs_on:
+        assert chrome == []
+        return
+    (ev,) = chrome
+    assert ev["args"] == {"m": 4}
+    assert abs(ev["ts"] * 1e3 - start_ns) < 1e6       # within 1 ms
+    assert abs(ev["dur"] * 1e3 - dur_ns) < 1e6
+
+
+def test_obs_on_apply_paths_never_wait_for_the_device(tmp_path,
+                                                      monkeypatch):
+    rng = np.random.default_rng(0)
+    A = jnp.asarray(rng.standard_normal((12, 24)), jnp.float32)
+    Ab = jnp.asarray(rng.standard_normal((3, 12, 24)), jnp.float32)
+    seq = random_sequence(jax.random.key(1), 24, 6)
+    plan = seq.plan(like=A, method="blocked")
+    want = [plan.apply(A), plan.apply_direct(A), plan.apply_batched(Ab)]
+    jax.block_until_ready(want)
+
+    def refuse(*_a, **_k):
+        raise AssertionError("an apply path waited for the device")
+
+    monkeypatch.setattr(jax, "block_until_ready", refuse)
+    with obs.override(True), _trace_path(str(tmp_path / "t.json")):
+        got = [plan.apply(A), plan.apply_direct(A), plan.apply_batched(Ab)]
+    monkeypatch.undo()
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
+    names = [ev["name"] for ev in obs.trace.events()]
+    assert names == ["apply", "apply", "apply_batched"]
+    assert obs.snapshot()["counters"]["sequence.applies.blocked"] == 3
 
 
 # ------------------------------------- plan-cache counters, exactly ----
@@ -152,27 +243,30 @@ def test_plan_cache_counters_cold_warm_interpolated_upgrade():
         assert c["registry.plan_cache.interpolated"] == 1
 
 
-# ------------------------------------------------- dispatch + roofline ----
+# ------------------------------------------------ dispatch + spans ----
 
-def test_sequence_dispatch_records_roofline_and_counters():
+def test_sequence_dispatch_records_roofline_and_counters(tmp_path):
+    # the blocking roofline records are gone: a dispatch is a span (its
+    # device time is read from the profiler trace) and exact counters
     rng = np.random.default_rng(0)
     A = jnp.asarray(rng.standard_normal((12, 24)), jnp.float32)
     Ab = jnp.asarray(rng.standard_normal((3, 12, 24)), jnp.float32)
     seq = random_sequence(jax.random.key(1), 24, 6)
     plan = seq.plan(like=A)
-    with obs.override(True):
+    with obs.override(True), _trace_path(str(tmp_path / "t.json")):
         jax.block_until_ready(plan.apply(A))
         jax.block_until_ready(plan.apply_batched(Ab))
         snap = obs.snapshot()
     assert snap["counters"]["sequence.applies"] == 2
-    assert snap["histograms"]["sequence.apply_seconds"]["count"] == 2
-    roof = snap["roofline"]
-    assert len(roof["dispatches"]) == 2
-    for agg in roof["by_backend"].values():
-        assert agg["predicted_flops"] > 0
-        assert agg["predicted_bytes"] > 0
-        assert agg["measured_s"] > 0
-        assert agg["model_fraction"] > 0
+    assert snap["counters"][f"sequence.applies.{plan.method}"] == 2
+    assert "roofline" not in snap
+    assert "sequence.apply_seconds" not in snap["histograms"]
+    spans = [ev for ev in obs.trace.events()
+             if ev["name"] in ("apply", "apply_batched")]
+    assert [ev["name"] for ev in spans] == ["apply", "apply_batched"]
+    assert spans[0]["args"] == {"method": plan.method, "m": 12, "n": 24}
+    assert spans[1]["args"]["batch"] == 3
+    assert all(ev["dur"] >= 0 for ev in spans)
 
 
 def test_disabled_obs_outputs_bit_identical_and_no_new_cache_keys():
@@ -197,19 +291,20 @@ def test_disabled_obs_outputs_bit_identical_and_no_new_cache_keys():
                                   np.asarray(on_batched))
 
 
-def test_instrumented_apply_stays_differentiable():
+def test_instrumented_apply_stays_differentiable(tmp_path):
     # the tracer guard: jax.grad drives apply with abstract values, and
     # the host-side instrumentation must stand aside rather than crash
     rng = np.random.default_rng(2)
     A = jnp.asarray(rng.standard_normal((8, 16)), jnp.float32)
     seq = random_sequence(jax.random.key(0), 16, 4)
     plan = seq.plan(like=A, method="blocked")
-    with obs.override(True):
+    with obs.override(True), _trace_path(str(tmp_path / "t.json")):
         g = jax.grad(lambda a: (plan.apply(a) ** 2).sum())(A)
         snap = obs.snapshot()
     assert g.shape == A.shape
-    # the traced inner call records nothing (no concrete wall time)
-    assert snap["roofline"]["dispatches"] == []
+    # the traced inner call records nothing: no span, no count
+    assert obs.trace.events() == []
+    assert "sequence.applies" not in snap["counters"]
 
 
 # --------------------------------------------------- serving + kernels ----
@@ -295,7 +390,7 @@ def test_write_metrics_json_roundtrip(tmp_path):
     assert on_disk == json.loads(json.dumps(snap))
     assert on_disk["counters"]["x"] == 2
     assert on_disk["meta"] == {"mode": "test"}
-    assert "roofline" in on_disk
+    assert set(on_disk) == {"counters", "gauges", "histograms", "meta"}
 
 
 # ------------------------------------------------------ thread safety ----
