@@ -2,14 +2,27 @@
 
 Each parallelogram tile of ``n_b`` waves x ``k_b`` rotations is accumulated
 into a dense orthogonal factor ``Q_t`` of size ``w x w`` (``w = k_b + n_b``)
-by applying the tile to an identity matrix with the wavefront kernel; the
-sweep over ``A`` then becomes a scan of ``(m, w) @ (w, w)`` matmuls.
+by applying the tile to an identity matrix; the sweep over ``A`` then
+becomes a scan of ``(m, w) @ (w, w)`` matmuls.
+
+``Q_t`` is built one anti-diagonal ``jj + p`` of the tile at a time: the
+``k_b`` rotations of an anti-diagonal pair disjoint planes, and all they
+depend on lies on earlier anti-diagonals, so ``n_b + k_b - 1`` steps of
+``k_b`` rotations give what ``n_b * k_b`` steps of one plane give, value
+for value.  The per-step rotations come from a pad-and-reshape shear of
+the tile, not a gather.  On a TPU a Pallas kernel keeps each tile's
+factor in VMEM for all its steps (float32); elsewhere an XLA loop runs
+the same schedule over all tiles at once.  The loop's step is a few XLA
+ops over the whole ``T x 2k_b x w`` window, which costs ~73 µs on a TPU
+v5e at 96/96 tiles against ~0.2 µs per tile and anti-diagonal in the
+kernel (PERF.md, section 5).
 
 On CPU (the paper) this trades ~4/3 more flops for MKL GEMM throughput and
 only wins for large matrices.  On TPU it is the *natural* formulation: the
 MXU delivers ~50x the VPU flop rate, so paying ``2 m w^2`` MXU flops instead
 of ``6 m n_b k_b`` VPU flops per tile inverts the paper's CPU conclusion.
-Accumulation cost is amortized by ``m / w``.
+Accumulation costs a step per anti-diagonal, whatever ``m``; the
+sweep's flops grow with ``m``.
 
 ``Q_t`` is banded (columns of ``Q_t`` mix at most ``k_b`` neighbours below),
 but we apply it densely: for ``n_b ~ k_b`` the band covers most of ``Q`` and
@@ -37,9 +50,12 @@ import jax
 import jax.numpy as jnp
 
 from repro import compat
+from repro.kernels.factors import tile_factors_pallas
+from repro.kernels.limits import LANES, SUBLANES, round_up
 
-from .blocked import (ACCUMULATE, PACK, SWEEP, _band_inputs, apply_tile,
-                      num_tiles, pack_sheared)
+from .blocked import (ACCUMULATE, PACK, SWEEP, _band_inputs, num_tiles,
+                      pack_sheared)
+from .rotations import plane_update
 
 __all__ = [
     "accumulate_tile_factors",
@@ -48,19 +64,105 @@ __all__ = [
 ]
 
 
-def accumulate_tile_factors(Ct, St, Gt, *, dtype=jnp.float32):
-    """Accumulate sheared tiles ``(T, n_b, k_b)`` into factors ``(T, w, w)``.
+def _diagonals(X, fill, steps: int, lanes: int):
+    """Tiles ``(T, n_b, k_b)`` by anti-diagonal: ``(T, steps, lanes)``.
 
-    ``X_out = X_in @ Q_t`` for each tile, so ``Q_t = apply_tile(I)``
-    (application is linear and acts identically on every row).
+    Row ``d`` holds the rotations with ``jj + p = d`` in descending wave
+    order: ``D[t, d, q] = X[t, d - p, p]`` with ``p = k_b - 1 - q``, and
+    the no-op value ``fill`` where ``d - p`` falls outside the tile, at
+    ``d`` past the last anti-diagonal and at ``q >= k_b``.  A pad and a
+    reshape shear the grid: no gather.
+    """
+    T, n_b, k_b = X.shape
+    L = n_b + k_b - 1
+    Y = jnp.pad(jnp.swapaxes(X, 1, 2), ((0, 0), (0, 0), (0, k_b)),
+                constant_values=fill)
+    Y = Y.reshape(T, k_b * (n_b + k_b))[:, :k_b * L].reshape(T, k_b, L)
+    D = jnp.swapaxes(Y, 1, 2)[:, :, ::-1]
+    return jnp.pad(D, ((0, 0), (0, steps - L), (0, lanes - k_b)),
+                   constant_values=fill)
+
+
+def _factors_loop(Ct, St, Gt, dtype):
+    """The schedule as an XLA loop over the rows of ``Q_tᵀ``.
+
+    Rotation ``(jj, p)`` of anti-diagonal ``d`` pairs rows ``k_b - 1 + d
+    - 2p`` and the next of ``Q_tᵀ``, padded by ``k_b - 1`` rows above.
+    Its ``x`` row has the parity of ``d``, so the rows are kept split by
+    parity (``E`` even, ``O`` odd) and an anti-diagonal updates two
+    contiguous ``k_b``-row slices in place: ``E[r:]`` and ``O[r:]`` for
+    ``d = 2r``, ``O[r:]`` and ``E[r + 1:]`` for ``d = 2r + 1``.  One loop
+    step does both.
     """
     T, n_b, k_b = Ct.shape
     w = k_b + n_b
-    eye = jnp.eye(w, dtype=dtype)
+    half = -(-(n_b + k_b - 1) // 2)
+    rows = half + k_b  # per parity: the last step reads E to row rows - 1
+    C, S, G = (_diagonals(X.astype(dtype), fill, 2 * half, k_b)
+               for X, fill in ((Ct, 1), (St, 0), (Gt, -1)))
+    eye = jnp.pad(jnp.eye(w, dtype=dtype),
+                  ((k_b - 1, 2 * rows - (k_b - 1) - w), (0, 0)))
     # inside shard_map the tiles may be device-varying; the identity must
     # carry the same varying-manual-axes type to be a legal loop carry
     eye = compat.pvary_like(eye, Ct)
-    return jax.vmap(lambda c, s, g: apply_tile(eye, c, s, g))(Ct, St, Gt)
+    EO = jnp.broadcast_to(eye.reshape(rows, 2, w), (T, rows, 2, w))
+
+    def diagonal(X, Y, x0, y0, d):
+        x = jax.lax.dynamic_slice_in_dim(X, x0, k_b, axis=1)
+        y = jax.lax.dynamic_slice_in_dim(Y, y0, k_b, axis=1)
+        c, s, g = (jax.lax.dynamic_index_in_dim(V, d, axis=1,
+                                                keepdims=False)[..., None]
+                   for V in (C, S, G))
+        x, y = plane_update(x, y, c, s, g)
+        return (jax.lax.dynamic_update_slice_in_dim(X, x, x0, axis=1),
+                jax.lax.dynamic_update_slice_in_dim(Y, y, y0, axis=1))
+
+    def step(r, EO):
+        E, O = diagonal(*EO, r, r, 2 * r)
+        O, E = diagonal(O, E, r, r + 1, 2 * r + 1)
+        return E, O
+
+    E, O = jax.lax.fori_loop(0, half, step, (EO[:, :, 0], EO[:, :, 1]))
+    Qt = jnp.stack([E, O], axis=2).reshape(T, 2 * rows, w)
+    return jnp.swapaxes(Qt[:, k_b - 1:k_b - 1 + w], 1, 2)
+
+
+def _factors_kernel(Ct, St, Gt, *, interpret: bool = False):
+    """The schedule as a Pallas kernel over float32 factors: the waves
+    sheared by anti-diagonal, each tile's columns built split by parity
+    (``kernels/factors.py``), then interleaved."""
+    T, n_b, k_b = Ct.shape
+    steps = round_up(n_b + k_b - 1, SUBLANES)
+    lanes = round_up(k_b, LANES)
+    C, S, G = (_diagonals(X.astype(jnp.float32), fill, steps, lanes)
+               for X, fill in ((Ct, 1), (St, 0), (Gt, -1)))
+    E, O = tile_factors_pallas(C, S, G, n_b=n_b, k_b=k_b,
+                               interpret=interpret)
+    Q = jnp.stack([E, O], axis=3).reshape(T, n_b + k_b, -1)
+    return Q[:, :, k_b - 1:k_b - 1 + n_b + k_b]
+
+
+def accumulate_tile_factors(Ct, St, Gt, *, dtype=jnp.float32):
+    """Accumulate sheared tiles ``(T, n_b, k_b)`` into factors ``(T, w, w)``.
+
+    ``X_out = X_in @ Q_t`` for each tile, so ``Q_t`` is the tile applied
+    to an identity, as ``apply_tile(I)`` gives it, value for value (a
+    zero's sign aside).  The rotations run one anti-diagonal ``d = jj +
+    p`` of the tile at a time: every rotation that ``(jj, p)`` depends
+    on lies on an earlier anti-diagonal, and those of one anti-diagonal
+    pair disjoint planes, ``(k_b - 1 + d - 2p, k_b + d - 2p)`` in a
+    padding of ``k_b - 1``.  That is ``n_b + k_b - 1`` steps of ``k_b``
+    rotations each, where applying the tile plane by plane takes ``n_b
+    * k_b`` steps of one.
+
+    On a TPU, float32 factors come from a Pallas kernel that keeps each
+    tile's factor in VMEM for all its steps; elsewhere, and for other
+    dtypes, from an XLA loop of the same schedule (two anti-diagonals per
+    step over all tiles at once).
+    """
+    if compat.is_tpu() and jnp.dtype(dtype) == jnp.float32:
+        return _factors_kernel(Ct, St, Gt)
+    return _factors_loop(Ct, St, Gt, dtype)
 
 
 def apply_band_accumulated(A, Q, *, k_b: int):

@@ -4,7 +4,9 @@ The chip is described, not attached: JAX's TPU compiler builds each
 kernel at the widths ``chip_smoke.py`` runs (the paper's k=180 waves on
 m=n=3840 and 960, serving buckets of 64 requests, the eigenvector
 flush), for every tile the TPU registry offers.  Interpret-mode tests
-cannot see what Mosaic refuses; these can.  Nothing runs.
+cannot see what Mosaic refuses; these can.  Nothing runs.  The program
+asks ``compat.is_tpu()`` where it takes a kernel only a TPU runs (the
+factor accumulation's); the ``on_tpu`` fixture answers as a chip would.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and every test
@@ -15,7 +17,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro import compat
 from repro.core import registry
+from repro.core.accumulate import _factors_kernel, rot_sequence_accumulated
+from repro.core.blocked import num_tiles
+from repro.core.sequence import RotationSequence
+from repro.dist import rot_sequence_column_sharded_padded
 from repro.kernels.limits import SMEM_PANEL_BUDGET, smem_panel_bytes
 from repro.kernels.rotseq.ops import rot_sequence_wave
 from repro.kernels.rotseq_batched.ops import rot_sequence_batched
@@ -30,9 +37,8 @@ BUCKETS = [(64, 256, 128, 32), (64, 512, 128, 16), (64, 1024, 256, 32),
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -43,8 +49,20 @@ def one_chip():
     # persistent cache without the chip; keep it out of the cache
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    monkeypatch.setattr(compat, "is_tpu", lambda: True)
 
 
 def _compile(fn, sharding, *shapes, dtype=jnp.float32):
@@ -60,7 +78,7 @@ def _compile(fn, sharding, *shapes, dtype=jnp.float32):
 @pytest.mark.parametrize("method,dtype", [("pallas_wave", "float32"),
                                           ("pallas_mxu", "float32"),
                                           ("pallas_mxu", "bfloat16")])
-def test_offered_tiles_compile(one_chip, method, dtype, m, n, k):
+def test_offered_tiles_compile(one_chip, on_tpu, method, dtype, m, n, k):
     """Every tile the TPU registry offers for the shape compiles."""
     problem = registry.Problem(m=m, n=n, k=k, dtype=dtype, platform="tpu")
     plans = registry.get_backend(method).candidates(problem)
@@ -71,6 +89,45 @@ def test_offered_tiles_compile(one_chip, method, dtype, m, n, k):
         _compile(lambda A, C, S: run(A, C, S, interpret=False, **kw),
                  one_chip, (m, n), (n - 1, k), (n - 1, k),
                  dtype=jnp.dtype(dtype))
+
+
+@pytest.mark.parametrize("m,n,k", SINGLE)
+def test_factor_kernel_compiles(one_chip, on_tpu, m, n, k):
+    """The factor kernel compiles for every tile the TPU registry offers
+    the ``accumulated`` backend, and the application the benchmark's
+    plan runs (96/96 at 3840, 64/64 at 960) holds it."""
+    problem = registry.Problem(m=m, n=n, k=k, platform="tpu")
+    plans = registry.accumulated_tiles(problem)
+    assert plans
+    for plan in plans:
+        tile = (num_tiles(n, plan.n_b, plan.k_b), plan.n_b, plan.k_b)
+        _compile(_factors_kernel, one_chip, tile, tile, tile)
+    if (m, n) in ((3840, 3840), (960, 960)):
+        b = 96 if n == 3840 else 64
+        text = _compile(lambda A, C, S: rot_sequence_accumulated(
+                            A, C, S, n_b=b, k_b=b),
+                        one_chip, (m, n), (n - 1, k), (n - 1, k)).as_text()
+        assert text.count("rotseq_factors") >= -(-k // b)
+
+
+def test_column_sharded_factor_kernel_compiles(topo, on_tpu):
+    """Inside ``shard_map`` on a 2x2 mesh, the column-sharded pipeline's
+    GEMM sweep accumulates its factors with the kernel."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    mesh = compat.make_mesh((2, 2), ("data", "model"),
+                            devices=topo.devices)
+    m, n, k = 256, 1024, 64
+    shapes = [((m, n), P("data", None)), ((n - 1, k), P(None, None)),
+              ((n - 1, k), P(None, None))]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32,
+                                 sharding=NamedSharding(mesh, spec))
+            for s, spec in shapes]
+    text = jax.jit(lambda A, C, S: rot_sequence_column_sharded_padded(
+        A, RotationSequence(C, S), mesh, col_axis="model", n_b=64, k_b=32,
+        row_axes=("data",), method="accumulated")).lower(
+            *args).compile().as_text()
+    assert "rotseq_factors" in text
 
 
 @pytest.mark.parametrize("b,m,n,k", BUCKETS)
