@@ -33,7 +33,10 @@ transposed-sequence VJP shard-locally with zero extra collectives.
 Observability: kernel-side launch accounting is tracer-guarded and
 cannot fire under ``shard_map`` tracing, so the plan self-accounts
 host-side — ``dist.launches_per_shard`` and ``dist.comm_bytes``,
-from the same comm-extended components the planner ranked by.
+from the same comm-extended components the planner ranked by, and
+``dist.donated_applies`` for the applications made in place.  The
+``dist.plan`` span carries the bytes of one device's rows of the target
+(``shard_bytes``), also set as the ``dist.shard_bytes`` gauge.
 """
 from __future__ import annotations
 
@@ -184,7 +187,10 @@ def plan_sharded(seq: RotationSequence, like=None, *, mesh,
             replicated_plan=rep_plan)
         execute_sharded = sh_s < rep_s
         chosen = sh_plan if execute_sharded else rep_plan
-        sp.set(method=chosen.method, sharded=execute_sharded)
+        shard_bytes = -(-m // devices) * n * jnp.dtype(dtype).itemsize
+        obs.gauge("dist.shard_bytes", shard_bytes)
+        sp.set(method=chosen.method, sharded=execute_sharded,
+               shard_bytes=shard_bytes)
     planned = chosen.kwargs()
     if n_b is not None:
         planned["n_b"] = n_b
@@ -281,7 +287,7 @@ class ShardedSequencePlan:
                             self.plan)
 
     # -- execution --------------------------------------------------------
-    def apply(self, A, *, direct: bool = False):
+    def apply(self, A, *, direct: bool = False, donate: bool = False):
         """Apply the planned sequence to a ``(m, n)`` target.
 
         Sharded execution shards rows over ``row_axes`` (``m`` must
@@ -289,6 +295,12 @@ class ShardedSequencePlan:
         backend call per shard; ``direct=True`` keeps the backend's
         native autodiff instead of the transposed-sequence
         ``custom_vjp`` (the ``apply_direct`` analogue).
+
+        ``donate=True`` gives ``A``'s buffers to the application, which
+        writes each shard's result in its own buffer, as ``xLASR``
+        updates its rows of Z in place: ``A`` is deleted, and a chain of
+        applications holds one copy of the target.  It applies to the
+        row-sharded path; the default keeps ``A``.
         """
         if self.method == _IDENTITY:
             return A
@@ -299,12 +311,14 @@ class ShardedSequencePlan:
             return inner.apply_direct(A) if direct else inner.apply(A)
         self._check_rows(A.shape[-2])
         if compat.is_tracer(A):
-            return self._row_sharded_2d(A, direct)
+            return self._row_sharded_2d(A, direct, False)
         with obs.span("dist.apply", method=self.method,
                       devices=self.devices, m=int(A.shape[0]),
                       n=int(A.shape[1])):
-            out = self._row_sharded_2d(A, direct)
+            out = self._row_sharded_2d(A, direct, donate)
         self._count_dispatch(A, launches=1)
+        if donate:
+            obs.inc("dist.donated_applies")
         return out
 
     __call__ = apply
@@ -352,22 +366,27 @@ class ShardedSequencePlan:
     # plan-once/apply-many must not pay a re-trace per application.
     # ``rebind`` carries the cache across same-structure rebinds (the
     # closures see waves only as call arguments).
-    def _cached_fn(self, key, builder):
+    def _fns(self) -> dict:
+        """The compiled fns of this plan and its same-structure rebinds."""
         cache = self.__dict__.get("_fn_cache")
         if cache is None:
             cache = {}
             object.__setattr__(self, "_fn_cache", cache)
+        return cache
+
+    def _cached_fn(self, key, builder):
+        cache = self._fns()
         fn = cache.get(key)
         if fn is None:
             fn = cache[key] = builder()
         return fn
 
-    def _row_sharded_2d(self, A, direct: bool):
-        fn = self._cached_fn(("2d", direct),
-                             lambda: self._build_2d(direct))
+    def _row_sharded_2d(self, A, direct: bool, donate: bool):
+        fn = self._cached_fn(("2d", direct, donate),
+                             lambda: self._build_2d(direct, donate))
         return fn(A, self.sequence)
 
-    def _build_2d(self, direct: bool):
+    def _build_2d(self, direct: bool, donate: bool):
         run = planned_run if direct else planned_apply
         method, kwargs = self.method, self.kwargs
         reflect = self.sequence.reflect
@@ -380,7 +399,8 @@ class ShardedSequencePlan:
         return jax.jit(compat.shard_map(
             local_fn, mesh=self.mesh,
             in_specs=(P(self.row_axes, None), seq_specs),
-            out_specs=P(self.row_axes, None)))
+            out_specs=P(self.row_axes, None)),
+            donate_argnums=(0,) if donate else ())
 
     def _row_sharded_batched(self, A, sequences, direct: bool):
         seq = self.sequence
@@ -543,12 +563,11 @@ class ShardedSequencePlan:
                     f"signs; re-plan the sign-carrying sequence")
         new = dataclasses.replace(self, sequence=sequence)
         # the jitted shard_map closures see waves only as call
-        # arguments, so a same-structure rebind reuses the compiled fns
-        cache = self.__dict__.get("_fn_cache")
-        if cache is not None \
-                and sequence.reflect == old.reflect \
+        # arguments, so a same-structure rebind shares the compiled fns,
+        # also those compiled after the rebind (by either plan)
+        if sequence.reflect == old.reflect \
                 and (sequence.sign is None) == (old.sign is None):
-            object.__setattr__(new, "_fn_cache", cache)
+            object.__setattr__(new, "_fn_cache", self._fns())
         return new
 
     def to_dict(self) -> dict:

@@ -3,8 +3,10 @@
 The chip is described, not attached: JAX's TPU compiler builds each
 kernel at the widths ``chip_smoke.py`` runs (the paper's k=180 waves on
 m=n=3840 and 960, serving buckets of 64 requests, the eigenvector
-flush), for every tile the TPU registry offers.  Interpret-mode tests
-cannot see what Mosaic refuses; these can.  Nothing runs.  The program
+flush), for every tile the TPU registry offers, and the four-chip
+eigenvector cell's flush (k=32 on 16384 x 65536 shards of V).
+Interpret-mode tests cannot see what Mosaic refuses; these can.
+Nothing runs.  The program
 asks ``compat.is_tpu()`` where it takes a kernel only a TPU runs (the
 factor accumulation's); the ``on_tpu`` fixture answers as a chip would.
 
@@ -17,7 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro import compat
+from repro import compat, dist
 from repro.core import registry
 from repro.core.accumulate import _factors_kernel, rot_sequence_accumulated
 from repro.core.blocked import num_tiles
@@ -179,3 +181,64 @@ def test_filtered_tiles_are_refused(one_chip):
         _compile(lambda A, C, S: rot_sequence_batched(
                      A, C, S, interpret=False, m_blk=256),
                  one_chip, (b, m, n), (b, n - 1, k), (b, n - 1, k))
+
+
+# the four-chip eigenvector cell: V is 65536 x 65536, k = 32 per flush
+EVACC = (65536, 65536, 32)
+
+
+def _evacc_plan():
+    m, n, k = EVACC
+    registry.clear_plan_cache()
+    plan = registry.select_plan(m, n, k, platform="tpu", sharded=True,
+                                devices=4)
+    registry.clear_plan_cache()
+    return plan
+
+
+def test_eigenvector_shard_compiles(one_chip, on_tpu):
+    """One chip's part of a flush, as the planner tiles it: the
+    16384 x 65536 shard swept whole, the factors from the kernel."""
+    plan = _evacc_plan()
+    assert (plan.method, plan.n_b, plan.k_b) == ("accumulated", 64, 32)
+    m, n, k = EVACC
+    kw = plan.kwargs()
+    assert kw == {"n_b": 64, "k_b": 32}
+    text = _compile(lambda A, C, S: rot_sequence_accumulated(A, C, S, **kw),
+                    one_chip, (m // 4, n), (n - 1, k), (n - 1, k)).as_text()
+    assert "rotseq_factors" in text
+
+
+def test_eigenvector_flush_fits_a_chip_in_place(topo, on_tpu):
+    """The whole flush on a 2x2 mesh, as the cell runs it: the donated
+    shard is the output's buffer, and what a chip holds (its shard and
+    the sweep's temporaries, about two shards) fits its 16 GB.  Without
+    donation the output is a fifth shard-sized buffer, and no chip holds
+    the flush."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    m, n, k = EVACC
+    mesh = compat.make_mesh((4,), ("data",), devices=topo.devices)
+    V = jax.ShapeDtypeStruct((m, n), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data", None)))
+    W = jax.ShapeDtypeStruct((n - 1, k), jnp.float32,
+                             sharding=NamedSharding(mesh, P(None, None)))
+    seq = RotationSequence(W, W)
+    plan = dist.plan_sharded(seq, like=V, mesh=mesh, method="auto",
+                             platform="tpu")
+    assert plan.execute_sharded and plan.method == "accumulated"
+
+    def memory(donate):
+        return plan._build_2d(False, donate).lower(V, seq).compile() \
+            .memory_analysis()
+
+    shard = m // 4 * n * 4
+    ma = memory(True)
+    assert ma.alias_size_in_bytes == ma.output_size_in_bytes == shard
+    held = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert ma.temp_size_in_bytes > 1.9 * shard
+    assert held < 16e9, held
+    ma = memory(False)
+    assert ma.alias_size_in_bytes == 0
+    assert ma.argument_size_in_bytes + ma.output_size_in_bytes + \
+        ma.temp_size_in_bytes > 16e9

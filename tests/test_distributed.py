@@ -380,3 +380,74 @@ def test_hlo_collectives_accounting():
         print("HLO COLL OK", hc.collective_counts)
     """)
     assert "HLO COLL OK" in out
+
+
+def test_donated_sharded_application_matches_the_reference():
+    """Eigenvector accumulation's update on four shards, made in place:
+    ``apply(V, donate=True)`` through a plan rebound to fresh waves, at
+    m = n = 256, k = 8, against ``core/ref`` on seeded random waves.
+    Float32 through the GEMM sweep reads ~1e-6 against float64; the
+    tolerance is 1e-4 absolute on entries of order one.  The donated
+    input reads as deleted afterwards, and the default keeps it."""
+    out = _run("""
+        import numpy as np, jax, jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro import compat, dist
+        from repro.core.ref import rot_sequence_numpy
+        from repro.core.rotations import random_sequence
+        mesh = compat.make_mesh((4,), ("data",), devices=jax.devices()[:4])
+        rows = NamedSharding(mesh, P("data", None))
+        rng = np.random.default_rng(11)
+        m = n = 256
+        k = 8
+        V0 = rng.standard_normal((m, n)).astype(np.float32)
+        seqs = [random_sequence(jax.random.key(20 + i), n, k)
+                for i in range(2)]
+        plan = dist.plan_sharded(seqs[0], like=V0, mesh=mesh,
+                                 method="accumulated")
+        assert plan.execute_sharded
+        # rebound before any application, as a flush loop does: the
+        # rebinds share one compiled program
+        plans = [plan.rebind(s) for s in seqs]
+        V = jax.device_put(V0, rows)
+        want = V0.astype(np.float64)
+        for p, s in zip(plans, seqs):
+            prev = V
+            V = p.apply(V, donate=True)
+            assert prev.is_deleted()
+            want = rot_sequence_numpy(want, s.cos, s.sin)
+        assert plans[0]._fns() is plans[1]._fns() is plan._fns()
+        assert list(plan._fns()) == [("2d", False, True)]
+        err = np.abs(np.asarray(V, np.float64) - want).max()
+        assert err < 1e-4, err
+        kept = jax.device_put(V0, rows)
+        plan.apply(kept)
+        assert not kept.is_deleted()
+        print("DONATE OK", err)
+    """)
+    assert "DONATE OK" in out
+
+
+@pytest.mark.parametrize("m,n,k,devices,tiles", [
+    (3840, 3840, 180, 1, (96, 96)),
+    (960, 960, 180, 1, (64, 64)),
+    (65536, 65536, 32, 4, (64, 32)),
+])
+def test_auto_plans_at_the_benchmark_sizes(m, n, k, devices, tiles):
+    """The plans the benchmark's cells run, for a described v5e: the
+    paper's squares take ``accumulated`` with their tiles and nothing
+    else, and the 65536-wide eigenvector matrix on four chips is priced
+    cheaper sharded than replicated, so ``auto`` shards it."""
+    from repro.core import registry
+    from repro.dist import modeled_crossover
+
+    registry.clear_plan_cache()
+    plan = registry.select_plan(m, n, k, platform="tpu",
+                                sharded=devices > 1, devices=devices)
+    if devices > 1:
+        sh_s, rep_s = modeled_crossover(m, n, k, devices=devices,
+                                        platform="tpu", sharded_plan=plan)
+        assert sh_s < rep_s, (sh_s, rep_s)
+    registry.clear_plan_cache()
+    assert (plan.method, plan.n_b, plan.k_b) == ("accumulated", *tiles)
+    assert plan.kwargs() == {"n_b": tiles[0], "k_b": tiles[1]}

@@ -202,6 +202,32 @@ def test_obs_on_apply_paths_never_wait_for_the_device(tmp_path,
     assert obs.snapshot()["counters"]["sequence.applies.blocked"] == 3
 
 
+def test_sharded_plan_and_in_place_apply_record_memory_and_donation(
+        tmp_path):
+    """``dist.plan`` carries the bytes of one device's rows of the
+    target and sets the ``dist.shard_bytes`` gauge; an application made
+    in place counts in ``dist.donated_applies`` and gives the result of
+    one that keeps its input."""
+    from repro import compat, dist
+
+    mesh = compat.make_mesh((1,), ("data",))
+    A = jnp.asarray(np.random.default_rng(2).standard_normal((256, 128)),
+                    jnp.float32)
+    seq = random_sequence(jax.random.key(3), 128, 8)
+    with obs.override(True), _trace_path(str(tmp_path / "t.json")):
+        dist.plan_sharded(seq, like=A, mesh=mesh, method="auto")
+        plan = dist.plan_sharded(seq, like=A, mesh=mesh, method="blocked")
+        out = plan.apply(A + 0, donate=True)
+        plan.apply(A)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(plan.apply(A)))
+    spans = {ev["name"]: ev["args"] for ev in obs.trace.events()}
+    assert spans["dist.plan"]["shard_bytes"] == 256 * 128 * 4
+    snap = obs.snapshot()
+    assert snap["counters"]["dist.donated_applies"] == 1
+    assert snap["counters"]["dist.applies"] == 2
+    assert snap["gauges"]["dist.shard_bytes"] == 256 * 128 * 4
+
+
 # ------------------------------------- plan-cache counters, exactly ----
 
 def test_plan_cache_counters_cold_warm_interpolated_upgrade():
