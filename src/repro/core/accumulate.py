@@ -53,8 +53,8 @@ from repro import compat
 from repro.kernels.factors import tile_factors_pallas
 from repro.kernels.limits import LANES, SUBLANES, round_up
 
-from .blocked import (ACCUMULATE, PACK, SWEEP, _band_inputs, num_tiles,
-                      pack_sheared)
+from .blocked import (ACCUMULATE, PACK, SWEEP, _band_inputs, _shear,
+                      num_tiles, pack_sheared)
 from .rotations import plane_update
 
 __all__ = [
@@ -70,15 +70,12 @@ def _diagonals(X, fill, steps: int, lanes: int):
     Row ``d`` holds the rotations with ``jj + p = d`` in descending wave
     order: ``D[t, d, q] = X[t, d - p, p]`` with ``p = k_b - 1 - q``, and
     the no-op value ``fill`` where ``d - p`` falls outside the tile, at
-    ``d`` past the last anti-diagonal and at ``q >= k_b``.  A pad and a
-    reshape shear the grid: no gather.
+    ``d`` past the last anti-diagonal and at ``q >= k_b``: the tiles
+    sheared (:func:`repro.core.blocked._shear`), no gather.
     """
     T, n_b, k_b = X.shape
     L = n_b + k_b - 1
-    Y = jnp.pad(jnp.swapaxes(X, 1, 2), ((0, 0), (0, 0), (0, k_b)),
-                constant_values=fill)
-    Y = Y.reshape(T, k_b * (n_b + k_b))[:, :k_b * L].reshape(T, k_b, L)
-    D = jnp.swapaxes(Y, 1, 2)[:, :, ::-1]
+    D = _shear(X, fill, L, k_b)[:, :, ::-1]
     return jnp.pad(D, ((0, 0), (0, steps - L), (0, lanes - k_b)),
                    constant_values=fill)
 

@@ -19,8 +19,8 @@ Coordinate bookkeeping (derived once, reused by the Pallas kernels):
   ``(j_l, j_l + 1)`` for ``j_l = k_b - 1 - p + jj``, ``jj in [0, n_b)`` —
   exactly Algorithm 2.1 of the paper.
 * the rotation value for ``(t, jj, p)`` is ``C[t*n_b + jj - p, p0 + p]`` —
-  a *sheared* ("packed", paper SS4) view of ``C``/``S`` built host-side so
-  kernels read aligned tiles.
+  a *sheared* ("packed", paper SS4) view of ``C``/``S`` built on the
+  device (:func:`pack_sheared`) so kernels read aligned tiles.
 """
 from __future__ import annotations
 
@@ -58,6 +58,26 @@ def num_tiles(n: int, n_b: int, k_b: int) -> int:
     return math.ceil((n + k_b - 1) / n_b)
 
 
+def _shear(X, fill, L: int, w: int):
+    """``X`` ``(..., J, w0)`` by diagonal: ``(..., L, w)``.
+
+    ``out[..., u, p] = X[..., u - p, p]``, and ``fill`` where ``u - p``
+    falls outside ``[0, J)`` or ``p >= w0``.  Each column of ``X``, padded
+    with ``fill`` to length ``R + 1``, is read back in rows of ``R``:
+    column ``p`` then starts ``p`` entries later, and its first ``p``
+    entries come from the fill at the end of column ``p - 1``, which is
+    long enough once ``R >= J + w - 2``.  A pad and a reshape shear the
+    grid: no gather.
+    """
+    *lead, J, w0 = X.shape
+    R = max(L, J + w - 2)
+    Y = jnp.pad(jnp.swapaxes(X, -1, -2),
+                [(0, 0)] * (X.ndim - 2) + [(0, w - w0), (0, R + 1 - J)],
+                constant_values=fill)
+    Y = Y.reshape(*lead, w * (R + 1))[..., :w * R].reshape(*lead, w, R)
+    return jnp.swapaxes(Y[..., :L], -1, -2)
+
+
 def pack_sheared(C, S, p0: int, k_b: int, n_b: int, T: int,
                  reflect: bool = False, G=None):
     """Shear-pack waves ``[p0, p0 + k_b)`` into aligned ``(T, n_b, k_b)`` tiles.
@@ -72,28 +92,24 @@ def pack_sheared(C, S, p0: int, k_b: int, n_b: int, T: int,
     ``G``: optional per-entry sign array ``(n-1, k)`` for *mixed*
     rotation/reflector sequences (e.g. the Jacobi solver's pivots-with-
     swaps interleaved with no-op rotations); overrides ``reflect``.
+
+    Each array's band is sheared by a pad and two reshapes
+    (:func:`_shear`, which :func:`repro.core.accumulate._diagonals` also
+    uses for the tiles' anti-diagonals): no element is gathered.
     """
-    J, k = C.shape
-    u = jnp.arange(T * n_b)
-    p = jnp.arange(k_b)
-    jg = u[:, None] - p[None, :]  # global j for each (u, p)
-    pg = p0 + p  # global wave index
-    valid = (jg >= 0) & (jg < J) & (pg < k)[None, :]
-    jc = jnp.clip(jg, 0, J - 1)
-    pc = jnp.minimum(pg, k - 1)
-    Ct = jnp.where(valid, C[jc, pc], jnp.ones((), C.dtype))
-    St = jnp.where(valid, S[jc, pc], jnp.zeros((), S.dtype))
+    def pack(X, fill):
+        return _shear(X[:, p0:p0 + k_b], fill, T * n_b, k_b).reshape(
+            T, n_b, k_b)
+
+    Ct = pack(C, 1)
+    St = pack(S, 0)
     if G is not None:
-        Gt = jnp.where(valid, G[jc, pc], -jnp.ones((), C.dtype))
+        Gt = pack(G.astype(jnp.result_type(G, C)), -1)
     elif reflect:
-        Gt = jnp.where(valid, jnp.ones((), C.dtype), -jnp.ones((), C.dtype))
+        Gt = pack(jnp.ones_like(C), -1)
     else:
         Gt = jnp.full_like(Ct, -1.0)
-    return (
-        Ct.reshape(T, n_b, k_b),
-        St.reshape(T, n_b, k_b),
-        Gt.reshape(T, n_b, k_b),
-    )
+    return Ct, St, Gt
 
 
 def apply_tile(X, Ct, St, Gt):

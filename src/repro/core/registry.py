@@ -372,7 +372,7 @@ def _tile_grid(p: Problem, n_b: int, k_b: int) -> Tuple[int, int, int]:
 def _pack_bytes(p: Problem, n_b: int, k_b: int) -> float:
     """Per-sequence sheared-tile packing traffic (blocked/accumulated).
 
-    Each band's ``k_b`` waves are gathered into ``tiles`` sheared
+    Each band's ``k_b`` waves are packed into ``tiles`` sheared
     ``(w, k_b)`` tiles per wave array before any row of A moves: the
     raw ``(n-1, k)`` panels are read once and the padded tile buffers
     written once.  Signs add a third array.

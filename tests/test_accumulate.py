@@ -1,7 +1,9 @@
 """Tile factors accumulated one anti-diagonal per step equal the tile
 applied plane by plane to an identity, both as the XLA loop and as the
 Pallas kernel (interpreted here), and each runs one loop of
-``ceil((n_b + k_b - 1) / 2)`` steps, two anti-diagonals each."""
+``ceil((n_b + k_b - 1) / 2)`` steps, two anti-diagonals each.  The
+sheared tiles they start from are packed without a gather, and equal
+the gathered ones bit for bit."""
 from functools import partial
 
 import jax
@@ -12,7 +14,7 @@ import pytest
 from repro import compat
 from repro.core import accumulate
 from repro.core.accumulate import accumulate_tile_factors
-from repro.core.blocked import apply_tile, pack_sheared
+from repro.core.blocked import apply_tile, num_tiles, pack_sheared
 
 SHAPES = [(3, 16, 16), (2, 8, 4), (4, 24, 8), (2, 8, 1), (3, 5, 9),
           (2, 64, 64)]
@@ -105,3 +107,77 @@ def test_tpu_float32_takes_the_kernel(monkeypatch, on_tpu, dtype, path):
                if e.primitive.name == "pallas_call"]
     assert len(kernels) == (path == "kernel")
     assert jaxpr.out_avals[0].dtype == dtype
+
+
+def _gathered(C, S, p0, k_b, n_b, T, reflect=False, G=None):
+    """``pack_sheared`` as one gather per element: the oracle."""
+    J, k = C.shape
+    u = jnp.arange(T * n_b)
+    p = jnp.arange(k_b)
+    jg = u[:, None] - p[None, :]
+    pg = p0 + p
+    valid = (jg >= 0) & (jg < J) & (pg < k)[None, :]
+    jc = jnp.clip(jg, 0, J - 1)
+    pc = jnp.minimum(pg, k - 1)
+    Ct = jnp.where(valid, C[jc, pc], jnp.ones((), C.dtype))
+    St = jnp.where(valid, S[jc, pc], jnp.zeros((), S.dtype))
+    if G is not None:
+        Gt = jnp.where(valid, G[jc, pc], -jnp.ones((), C.dtype))
+    elif reflect:
+        Gt = jnp.where(valid, jnp.ones((), C.dtype), -jnp.ones((), C.dtype))
+    else:
+        Gt = jnp.full_like(Ct, -1.0)
+    return tuple(X.reshape(T, n_b, k_b) for X in (Ct, St, Gt))
+
+
+def _waves(n, k, dtype, signs):
+    rng = np.random.default_rng(n * 1000 + k)
+    C, S, G = (jnp.asarray(X, dtype) for X in (
+        rng.standard_normal((n - 1, k)), rng.standard_normal((n - 1, k)),
+        rng.choice([-1.0, 1.0], (n - 1, k))))
+    kw = {"rotations": {}, "reflectors": {"reflect": True},
+          "mixed": {"G": G}}[signs]
+    return C, S, kw
+
+
+PACK_SIGNS = ["rotations", "reflectors", "mixed"]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("signs", PACK_SIGNS)
+@pytest.mark.parametrize("n,k,n_b,k_b", [
+    (300, 180, 96, 96),  # n3840's tiles; the second band is partial
+    (200, 180, 64, 64),  # n960's: three bands, the last partial
+    (150, 32, 64, 32),   # evacc-4chip's: one band
+    (50, 40, 8, 16),     # a partial last band of narrow tiles
+    (50, 10, 16, 16),    # k < k_b
+    (5, 4, 8, 4),        # n < n_b
+    (2, 3, 8, 16),       # one plane
+])
+def test_pack_equals_the_gather(n, k, n_b, k_b, signs, dtype):
+    C, S, kw = _waves(n, k, dtype, signs)
+    T = num_tiles(n, n_b, k_b)
+    for p0 in range(0, k, k_b):
+        got = pack_sheared(C, S, p0, k_b, n_b, T, **kw)
+        want = _gathered(C, S, p0, k_b, n_b, T, **kw)
+        for g, w in zip(got, want):
+            assert g.shape == w.shape == (T, n_b, k_b)
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("signs", PACK_SIGNS)
+def test_pack_gathers_nothing(signs):
+    """At n3840's shape, in every sign mode: no ``gather`` anywhere in
+    the packing program (the oracle's has them, so the walk sees one)."""
+    n, k, n_b, k_b = 3840, 180, 96, 96
+    C, S, kw = _waves(n, k, jnp.float32, signs)
+    T = num_tiles(n, n_b, k_b)
+
+    def prims(fn):
+        pack = partial(fn, p0=k_b, k_b=k_b, n_b=n_b, T=T, **kw)
+        return {e.primitive.name
+                for _, e in _eqns(jax.make_jaxpr(pack)(C, S).jaxpr)}
+
+    assert "gather" not in prims(pack_sheared)
+    assert "gather" in prims(_gathered)
