@@ -9,8 +9,7 @@
 # kernel bodies, and suppresses via `# repro-lint: disable=RAx` — see
 # `python -m repro.analysis --list-rules`.
 
-.PHONY: check lint analyze ruff docs-check test smoke bench \
-	bench-artifacts bench-compare obs-report
+.PHONY: check lint analyze ruff docs-check test obs-report
 
 check: lint test
 
@@ -46,29 +45,6 @@ ruff:
 PYTEST_PAR := $(shell python -c 'import xdist' 2>/dev/null && echo '-n auto')
 test:
 	PYTHONPATH=src python -m pytest -q --maxfail=1 $(PYTEST_PAR)
-
-smoke:
-	PYTHONPATH=src:. python benchmarks/run.py --only smoke
-
-bench:
-	PYTHONPATH=src:. python benchmarks/run.py
-
-# CI perf artifacts: JSON rows for the regression compare + upload.
-bench-artifacts:
-	PYTHONPATH=src:. python benchmarks/run.py --only smoke --json BENCH_smoke.json
-	PYTHONPATH=src:. python benchmarks/bench_eig.py --quick --json BENCH_eig.json
-	PYTHONPATH=src:. python benchmarks/run.py --only serve --json BENCH_serve.json
-	PYTHONPATH=src:. python benchmarks/bench_dist.py --quick --json BENCH_dist.json
-
-# Fails when a tracked metric (counts exactly; interpret-mode rates by
-# >30%) regresses vs benchmarks/baselines/bench_baseline.json.
-# Regenerate the baseline with:
-#   python benchmarks/compare_baseline.py --update --baseline \
-#     benchmarks/baselines/bench_baseline.json BENCH_*.json
-bench-compare:
-	PYTHONPATH=src:. python benchmarks/compare_baseline.py \
-		--baseline benchmarks/baselines/bench_baseline.json \
-		BENCH_smoke.json BENCH_eig.json BENCH_serve.json BENCH_dist.json
 
 # Observability report: obs-enabled rotation-serving runs writing the
 # metrics snapshot (OBS_metrics.json) and a Perfetto-loadable

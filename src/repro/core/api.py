@@ -13,10 +13,11 @@ back-compat wrapper over that machinery for callers still holding loose
   ``accumulated``   rs_gemm analogue: tile factors + GEMM sweeps
   ``pallas_wave``   Pallas VPU wavefront kernel (packed layout)
   ``pallas_mxu``    Pallas MXU accumulated kernel
+  ``rotseq_batched`` fused multi-request Pallas kernel (one launch per
+                    batch, identity planes skipped)
   ``auto``          registry cost model picks backend + (n_b, k_b, m_blk)
-                    from problem shape/dtype/platform; pass
-                    ``autotune=True`` to measure the top candidates and
-                    cache the fastest plan per (shape, dtype, platform).
+                    from problem shape/dtype/platform, memoized in
+                    process per problem.
 
 Each backend is registered below with a capability record (dtypes,
 platforms, per-entry-sign support, shard_map compatibility, Pallas
@@ -176,11 +177,6 @@ registry.register(BackendSpec(
 
 METHODS = registry.registered_methods()
 
-# persisted (autotuned) plans can only be validated against the registry
-# once every backend above is registered — hence load-here, not on
-# registry import
-registry.load_plan_cache()
-
 
 # --------------------------------------------------------------------------
 # dispatch
@@ -188,15 +184,14 @@ registry.load_plan_cache()
 
 def apply_rotation_sequence(A, C, S, *, method: str = "accumulated",
                             n_b: int | None = None, k_b: int | None = None,
-                            reflect: bool = False, G=None,
-                            autotune: bool = False, **kw):
+                            reflect: bool = False, G=None, **kw):
     """Apply the rotation sequence ``(C, S)`` to ``A`` from the right.
 
     Back-compat wrapper: wraps the loose arrays in a
     :class:`~repro.core.sequence.RotationSequence` and executes one
     freshly resolved :class:`~repro.core.sequence.SequencePlan`.
     ``method="auto"`` consults the registry (capability filter, SS6 cost
-    model / autotune, per-(shape, dtype, platform) plan cache); a named
+    model, per-problem plan memo); a named
     ``method`` keeps the seed behaviour (tiled backends default to
     ``n_b=64, k_b=16``).  Empty sequences (``n < 2`` or ``k < 1``) are
     the identity under *every* method.
@@ -231,7 +226,7 @@ def apply_rotation_sequence(A, C, S, *, method: str = "accumulated",
     seq = RotationSequence(C, S, G, reflect)
     platform = kw.pop("platform", None)
     sharded = kw.pop("sharded", False)
-    plan = seq.plan(like=A, method=method, autotune=autotune,
+    plan = seq.plan(like=A, method=method,
                     platform=platform, sharded=sharded,
                     n_b=n_b, k_b=k_b, **kw)
     return plan.apply_direct(A)

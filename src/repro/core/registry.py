@@ -3,12 +3,12 @@
 Every backend registers a :class:`BackendSpec` (capability record, §6
 memory-operation cost model split into per-sequence *setup* and per-row
 *stream* terms, tile-candidate generator); ``select_plan`` ranks the
-eligible (backend, tile) candidates by modeled cost — optionally
-re-ranked by measured wall time with ``autotune=True`` — and caches the
-winning :class:`Plan` per problem key, write-through to an on-disk store
-for measured plans.  :class:`Problem.shared_sequence` distinguishes one
-sequence amortized over a batch from the serving path's
-one-sequence-per-request batches, which pay setup × b.
+eligible (backend, tile) candidates by modeled cost and memoizes the
+winning :class:`Plan` per problem key in this process.  The choice is a
+pure function of the :class:`Problem`: nothing is timed, read from disk
+or borrowed from another shape.  :class:`Problem.shared_sequence`
+distinguishes one sequence amortized over a batch from the serving
+path's one-sequence-per-request batches, which pay setup × b.
 
 The full pricing derivation (every backend's flop/memop/setup formula,
 the per-request correction, and a worked batch-64 example) lives in
@@ -18,14 +18,10 @@ the registry → sequence → serve → stream layer diagram.
 from __future__ import annotations
 
 import dataclasses
-import json
 import math
-import os
-import tempfile
 from typing import Callable, Dict, List, Optional, Tuple
 
 from repro import compat, obs
-from repro.obs import timing as _timing
 
 __all__ = [
     "Hardware", "Problem", "Plan", "Capability", "BackendSpec",
@@ -33,7 +29,6 @@ __all__ = [
     "no_tiles", "blocked_tiles", "accumulated_tiles",
     "pallas_wave_tiles", "pallas_mxu_tiles", "rotseq_batched_tiles",
     "pinned_tiles", "select_plan", "plan_cache_stats", "clear_plan_cache",
-    "plan_cache_path", "save_plan_cache", "load_plan_cache",
     "cost_components",
 ]
 
@@ -147,10 +142,7 @@ class Plan:
     k_b: Optional[int] = None
     m_blk: Optional[int] = None
     est_seconds: float = float("inf")
-    # "model" (cost-model ranked) | "measured" (autotuned this process) |
-    # "persisted" (measured, loaded from disk) | "interpolated" (borrowed
-    # from the nearest measured shape)
-    source: str = "model"
+    source: str = "model"  # "model": ranked by select_plan's cost model
 
     def kwargs(self) -> dict:
         kw = {}
@@ -542,9 +534,7 @@ def cost_components(method: str, problem: Problem,
     ``select_plan`` ranked by — including the interpret penalty.  The
     ``setup``/``stream`` sub-dicts carry the
     per-sequence vs per-row split with *additive, penalty-free*
-    attribution seconds (pure traffic over peak rates), so the obs
-    roofline ledger — and the bench row that watches the per-request
-    accumulated cliff — can attribute ``model_fraction`` per term.
+    attribution seconds (pure traffic over peak rates).
     Sharded problems (``devices > 1``) additionally carry a ``comm``
     sub-dict — the wave-panel broadcast bytes and their link-priced
     seconds (``docs/cost-model.md``, "the communication term"); the
@@ -704,177 +694,27 @@ def plan_cache_stats() -> dict:
 
 
 def clear_plan_cache() -> None:
-    """Drop the *in-memory* plan cache (the on-disk file is untouched)."""
+    """Drop the in-process plan memo and its hit/miss counts."""
     _PLAN_CACHE.clear()
     _CACHE_STATS["hits"] = _CACHE_STATS["misses"] = 0
-
-
-# --------------------------------------------------------------------------
-# persisted plan cache (measured/autotuned plans survive the process)
-# --------------------------------------------------------------------------
-#
-# Autotuned plans are expensive (each one compiles and times real backends)
-# but keyed by pure host facts — (m, n, k, dtype, platform, signs, sharded)
-# plus the JAX version — so they are safe to reuse across processes.  Every
-# measured plan is written through to a JSON file (atomic tmp+rename) and
-# loaded back on import.  Model-ranked plans are cheap to recompute and are
-# never persisted.  ``REPRO_PLAN_CACHE`` overrides the path; setting it to
-# the empty string or ``off`` disables persistence entirely.
-
-_PLAN_CACHE_ENV = "REPRO_PLAN_CACHE"
-_PLAN_CACHE_FORMAT = 1
-_PERSISTED_SOURCES = ("measured", "persisted")
-
-
-def plan_cache_path() -> Optional[str]:
-    """Resolved on-disk cache path, or ``None`` when persistence is off."""
-    override = os.environ.get(_PLAN_CACHE_ENV)
-    if override is not None:
-        if override.strip().lower() in ("", "off", "0", "none"):
-            return None
-        return os.path.expanduser(override)
-    base = os.environ.get("XDG_CACHE_HOME",
-                          os.path.join(os.path.expanduser("~"), ".cache"))
-    return os.path.join(base, "repro", "plans.json")
 
 
 def _jax_version_str() -> str:
     return ".".join(map(str, compat.JAX_VERSION))
 
 
-def _read_versioned_json(path: str, fmt: int) -> Optional[dict]:
-    """Parse a versioned JSON store; ``None`` when the file is missing,
-    corrupt, or stale (other format or JAX version) — the shared
-    invalidation rule of every persisted-plan store (registry cache and
-    the serving plan store alike)."""
-    try:
-        with open(path) as f:
-            payload = json.load(f)
-    except (OSError, ValueError):
-        return None
-    if not isinstance(payload, dict) or payload.get("format") != fmt \
-            or payload.get("jax") != _jax_version_str():
-        return None
-    return payload
-
-
-def _atomic_write_json(path: str, payload: dict,
-                       prefix: str) -> Optional[str]:
-    """tmp+rename atomic JSON write; ``None`` (never raises) on I/O
-    errors so a read-only cache dir degrades gracefully."""
-    try:
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        fd, tmp = tempfile.mkstemp(dir=os.path.dirname(path),
-                                   prefix=prefix, suffix=".tmp")
-        try:
-            with os.fdopen(fd, "w") as f:
-                json.dump(payload, f, indent=1)
-            os.replace(tmp, path)  # atomic on POSIX
-        except BaseException:
-            os.unlink(tmp)
-            raise
-    except OSError:
-        return None
-    return path
-
-
-def save_plan_cache(path: Optional[str] = None) -> Optional[str]:
-    """Atomically write all measured/persisted plans to disk.
-
-    Entries already on disk (same format + JAX version) that this
-    process does not hold in memory are merged in before writing — a
-    *best-effort* courtesy to concurrent processes autotuning other
-    shapes (the unlocked read-merge-replace still has a lost-update
-    window; a plan lost to the race is merely re-measured, never
-    corrupted, so no file lock is taken).  Returns the
-    path written, or ``None`` when persistence is disabled or there is
-    nothing durable to save.  Never raises for I/O problems — a
-    read-only cache dir degrades to in-memory planning.
-    """
-    path = path or plan_cache_path()
-    if path is None:
-        return None
-    merged: Dict[tuple, dict] = {}
-    on_disk = _read_versioned_json(path, _PLAN_CACHE_FORMAT)
-    if on_disk is not None:  # missing/corrupt/stale file: start fresh
-        for entry in on_disk.get("plans", []):
-            try:
-                merged[tuple(entry["key"])] = entry
-            except (KeyError, TypeError):
-                continue
-    for key, plan in _PLAN_CACHE.items():
-        if plan.source in _PERSISTED_SOURCES:
-            merged[key] = {"key": list(key), "method": plan.method,
-                           "n_b": plan.n_b, "k_b": plan.k_b,
-                           "m_blk": plan.m_blk,
-                           "est_seconds": plan.est_seconds}
-    if not merged:
-        return None
-    payload = {"format": _PLAN_CACHE_FORMAT, "jax": _jax_version_str(),
-               "plans": list(merged.values())}
-    return _atomic_write_json(path, payload, prefix=".plans.")
-
-
-def load_plan_cache(path: Optional[str] = None) -> int:
-    """Merge persisted plans into the in-memory cache; returns count loaded.
-
-    Entries from a different JAX version (or an unreadable/corrupt file)
-    are ignored wholesale — measured timings do not transfer across
-    compiler versions.  An in-memory *measured* entry wins over disk.
-    """
-    path = path or plan_cache_path()
-    if path is None:
-        return 0
-    payload = _read_versioned_json(path, _PLAN_CACHE_FORMAT)
-    if payload is None:
-        return 0
-    loaded = 0
-    for entry in payload.get("plans", []):
-        try:
-            key = tuple(entry["key"])
-            plan = Plan(method=str(entry["method"]), n_b=entry.get("n_b"),
-                        k_b=entry.get("k_b"), m_blk=entry.get("m_blk"),
-                        est_seconds=float(entry.get("est_seconds", 0.0)),
-                        source="persisted")
-        except (KeyError, TypeError, ValueError):
-            continue
-        if plan.method not in _REGISTRY:
-            continue  # stale entry for an unregistered backend
-        cached = _PLAN_CACHE.get(key)
-        if cached is not None and cached.source == "measured":
-            continue
-        _PLAN_CACHE[key] = plan
-        loaded += 1
-    return loaded
-
-
-# Maximum summed |log(m/m')| + |log(n/n')| + |log(k/k')| (+ batch term)
-# at which a measured plan still transfers: ~4x per dimension on
-# average.  Beyond this the regime can differ qualitatively
-# (cache-resident vs streaming, VPU- vs MXU-bound) and the cost model is
-# the better guess.
-_INTERP_MAX_LOGDIST = 3 * math.log(4.0)
-
-
 def _plan_key(problem: Problem) -> tuple:
-    """Cache key for a problem.
+    """Memo key for a problem.
 
-    ``batch=1`` keys keep the legacy 7-tuple layout so plan caches
-    persisted before the batch field existed stay valid; batched
-    problems append the batch count, per-request batches
+    ``(m, n, k, dtype, platform, signs, sharded)`` for a single target;
+    batched problems append the batch count, per-request batches
     (``shared_sequence=False``, which price setup × b and can plan
     differently) append a ``"per_req"`` marker after it, and problems
     with a static live-plane count (padded/staircase sequences, which
-    plane-skipping backends price differently) append
-    ``("live", count)`` last.
-
-    Sharded problems put ``("sharded", devices)`` in the legacy
-    ``sharded`` slot: the mesh size is part of the eligibility *class*
-    (``_split_key``), so plans never transfer between device counts —
-    or to/from single-device keys, whose slot stays the legacy
-    ``False``.  Sharded plans are never persisted (``select_plan``'s
-    ``can_measure`` excludes them), so the tuple-valued slot never
-    reaches the JSON store.
+    plane-skipping backends price differently) append ``("live",
+    count)`` last.  Sharded problems put ``("sharded", devices)`` in
+    the ``sharded`` slot, so each mesh size has its own entries.
+    Problems the model prices differently never share an entry.
     """
     shard = ("sharded", max(1, problem.devices)) if problem.sharded \
         else False
@@ -888,80 +728,6 @@ def _plan_key(problem: Problem) -> tuple:
     if problem.live_planes is not None:
         base = base + ("live", problem.live_planes)
     return base
-
-
-def _split_key(key: tuple):
-    """``key -> ((m, n, k, batch), class, live_fraction)``.
-
-    ``class`` is the eligibility tuple ``(dtype, platform, signs,
-    sharded, shared_sequence)``.  Shared-sequence and per-request keys
-    are distinct classes — a measured plan for one sequence amortized
-    over a batch must not transfer at distance 0 to the same shape
-    paying setup per request (the backends differ, exactly like dense
-    vs live-annotated).  ``live_fraction`` decodes the optional
-    trailing ``("live", count)`` marker as ``count / ((n-1) * k)``
-    (``None`` when absent): liveness changes which backend wins, so
-    dense and live-annotated keys are distinct classes too, with the
-    live-fraction ratio added to the distance within the latter.
-    """
-    m, n, k = key[:3]
-    batch = key[7] if len(key) > 7 else 1
-    shared = True
-    idx = 8
-    if len(key) > idx and key[idx] == "per_req":
-        shared = False
-        idx += 1
-    frac = None
-    if len(key) > idx + 1 and key[idx] == "live":
-        planes = max(1, (n - 1) * k)
-        frac = max(1, int(key[idx + 1])) / planes
-    return (m, n, k, batch), tuple(key[3:7]) + (shared,), frac
-
-
-def _interpolated_plan(problem: Problem, key: tuple) -> Optional[Plan]:
-    """Borrow the nearest *measured* plan for an unmeasured shape.
-
-    Autotuned timings are expensive; rather than re-running the cost
-    model for a shape we have never measured, reuse the closest measured
-    (or disk-persisted) plan of the same eligibility class — identical
-    ``(dtype, platform, signs, sharded)`` and a backend this problem is
-    itself eligible for — ranked by log-distance in ``(m, n, k)`` and
-    only within :data:`_INTERP_MAX_LOGDIST` (a far-away measurement
-    must not override the cost model).  Borrowed plans are cached under
-    the new key with ``source="interpolated"`` (never persisted, and
-    upgraded in place by a later ``autotune=True`` call).
-    """
-    eligible = {spec.name for spec in eligible_backends(problem)}
-    best: Optional[Plan] = None
-    best_dist = _INTERP_MAX_LOGDIST
-    (m1, n1, k1, b1), cls1, frac1 = _split_key(key)
-    for cached_key, plan in _PLAN_CACHE.items():
-        if plan.source not in _PERSISTED_SOURCES:
-            continue
-        (m2, n2, k2, b2), cls2, frac2 = _split_key(cached_key)
-        if cls2 != cls1:  # (dtype, platform, signs, sharded, shared_seq)
-            continue
-        if (frac2 is None) != (frac1 is None):
-            continue  # dense vs live-annotated: different regimes
-        if plan.method not in eligible:
-            continue
-        if min(m2, n2, k2, b2) < 1:
-            continue
-        dist = (abs(math.log(m1 / m2))
-                + abs(math.log(n1 / n2))
-                + abs(math.log(k1 / k2))
-                + abs(math.log(b1 / b2)))
-        if frac1 is not None:
-            dist += abs(math.log(frac1 / frac2))
-        if dist < best_dist:
-            best, best_dist = plan, dist
-    if best is None:
-        return None
-    # the donor's measured wall-time belongs to the donor's shape; carry
-    # the cost model's estimate for *this* problem instead
-    borrowed = dataclasses.replace(best, source="interpolated")
-    est = get_backend(best.method).cost(problem, borrowed)
-    return dataclasses.replace(borrowed, est_seconds=est)
 
 
 def _modeled_plans(problem: Problem) -> List[Plan]:
@@ -990,137 +756,25 @@ def _modeled_plans(problem: Problem) -> List[Plan]:
     return plans
 
 
-def _synthetic_waves(problem: Problem, rng):
-    """One ``(C, S, G)`` wave draw matching the problem record.
-
-    A per-entry sign array is included when ``problem.signs`` so
-    sign-carrying plans are timed on the code path they will actually
-    serve, and a ``live_planes`` bound identity-pads the trailing waves
-    so plane-skipping backends are timed on (approximately) the live
-    grid they will execute, not a dense one ~grid/live times costlier.
-    """
-    import numpy as np
-
-    th = rng.standard_normal((problem.n - 1, problem.k))
-    Cn, Sn = np.cos(th), np.sin(th)
-    if problem.live_planes is not None \
-            and problem.live_planes < problem.planes_total:
-        live_waves = math.ceil(problem.live_planes
-                               / max(1, problem.n - 1))
-        Cn[:, live_waves:] = 1.0
-        Sn[:, live_waves:] = 0.0
-    Gn = None
-    if problem.signs:
-        Gn = np.where(rng.random((problem.n - 1, problem.k)) < 0.5,
-                      1.0, -1.0)
-        # identity padding must stay a rotation (a padded reflector is
-        # live), or the live_planes-shaped workload above is undone
-        Gn[(Cn == 1.0) & (Sn == 0.0)] = -1.0
-    return Cn, Sn, Gn
-
-
-def _time_median(fn: Callable, reps: int) -> float:
-    import jax
-
-    jax.block_until_ready(fn())  # compile
-    ts = []
-    for _ in range(reps):
-        t0 = _timing.now()
-        jax.block_until_ready(fn())
-        ts.append(_timing.now() - t0)
-    return sorted(ts)[len(ts) // 2]
-
-
-def _measure_plan(problem: Problem, plan: Plan, reps: int = 2) -> float:
-    """Median wall-time of one real application at ``plan``'s tiles.
-
-    The synthetic workload matches the problem record (signs, live
-    planes — see :func:`_synthetic_waves`).  Shared-sequence batches
-    execute flattened (rotations are row-wise), so they are timed as
-    the ``(batch*m, n)`` problem the dispatch path will actually run;
-    per-request batches are timed through
-    ``SequencePlan.apply_batched(A, sequences=...)`` with ``batch``
-    *distinct* sequences, so the fused / vmap / loop execution strategy
-    — and the per-sequence setup this problem re-pays ``b`` times — is
-    measured, not a single broadcast sequence that would hide it.
-    """
-    import jax.numpy as jnp
-    import numpy as np
-
-    if problem.batch > 1 and not problem.shared_sequence:
-        return _measure_plan_per_request(problem, plan, reps)
-    rng = np.random.default_rng(0)
-    dt = jnp.dtype(problem.dtype)
-    A = jnp.asarray(rng.standard_normal((problem.m_total, problem.n)), dt)
-    Cn, Sn, Gn = _synthetic_waves(problem, rng)
-    C, S = jnp.asarray(Cn, dt), jnp.asarray(Sn, dt)
-    G = None if Gn is None else jnp.asarray(Gn, dt)
-    spec = get_backend(plan.method)
-    fn = lambda: spec.fn(A, C, S, reflect=False, G=G, **plan.kwargs())
-    return _time_median(fn, reps)
-
-
-def _measure_plan_per_request(problem: Problem, plan: Plan,
-                              reps: int) -> float:
-    """Per-request-batch measurement: ``batch`` distinct sequences.
-
-    Routed through the same ``apply_batched`` strategy dispatch the
-    serving path uses (fused kernel, ``jax.vmap``, or per-element
-    loop), because that execution shape — not the flattened broadcast —
-    is what a per-request plan will actually run.
-    """
-    import jax.numpy as jnp
-    import numpy as np
-
-    # plan-layer import, deferred: sequence.py imports this module
-    from repro.core import sequence as _sequence
-
-    rng = np.random.default_rng(0)
-    dt = jnp.dtype(problem.dtype)
-    A = jnp.asarray(
-        rng.standard_normal((problem.batch, problem.m, problem.n)), dt)
-    seqs = []
-    for _ in range(problem.batch):
-        Cn, Sn, Gn = _synthetic_waves(problem, rng)
-        seq = _sequence.RotationSequence(
-            jnp.asarray(Cn, dt), jnp.asarray(Sn, dt),
-            None if Gn is None else jnp.asarray(Gn, dt))
-        if problem.live_planes is not None:
-            seq = dataclasses.replace(
-                seq, k_live=min(problem.live_planes, problem.planes_total))
-        seqs.append(seq)
-    sp = _sequence.SequencePlan(seqs[0], plan.method,
-                                tuple(sorted(plan.kwargs().items())), plan)
-    fn = lambda: sp.apply_batched(A, sequences=seqs, direct=True)
-    return _time_median(fn, reps)
-
-
 def select_plan(m: int, n: int, k: int, *, dtype="float32",
                 platform: Optional[str] = None, signs: bool = False,
                 sharded: bool = False, devices: int = 1, batch: int = 1,
                 shared_sequence: bool = True,
-                live_planes: Optional[int] = None,
-                autotune: bool = False, autotune_top: int = 3) -> Plan:
-    """Pick ``(method, n_b, k_b, m_blk)`` for a problem, with caching.
+                live_planes: Optional[int] = None) -> Plan:
+    """Pick ``(method, n_b, k_b, m_blk)`` for a problem, memoized.
 
-    Cost-model ranking by default; with ``autotune=True`` the top
-    ``autotune_top`` modeled plans are measured end-to-end and the
-    fastest wins.  Winning plans are cached per
-    ``(m, n, k, dtype, platform, signs, sharded[, batch])`` — an
-    autotuned (measured) entry overwrites a model-ranked one for the
-    same key and is then reused by plain ``method="auto"`` calls too.
+    The cheapest eligible plan by the cost model wins, and is memoized
+    in this process per problem key (:func:`_plan_key`): the same
+    problem always gets the same plan, whatever ran before it.
 
     ``batch`` is the number of independent ``(m, n)`` targets served per
     application (see :class:`Problem`): the amortization terms differ,
     so batch 64 can legitimately pick a different backend than batch 1.
     ``shared_sequence=False`` marks a *per-request* batch (one distinct
     sequence per target, the serving path): per-sequence setup terms
-    multiply by ``b`` instead of amortizing, the cache key carries a
-    ``"per_req"`` marker, and autotune measures ``b`` distinct
-    sequences through the real batched dispatch — additionally timing
-    the best candidate of *every* eligible backend, because the
-    traffic model cannot see fused/vmap/loop execution constants
-    (docs/cost-model.md, "the per-request correction").
+    multiply by ``b`` instead of amortizing, and the key carries a
+    ``"per_req"`` marker (docs/cost-model.md, "the per-request
+    correction").
     ``live_planes`` is the statically-known count of non-identity
     planes (``RotationSequence.k_live``): plane-skipping backends price
     padded/staircase grids by their live fraction, so a ``seq.T``
@@ -1129,18 +783,7 @@ def select_plan(m: int, n: int, k: int, *, dtype="float32",
     implies ``sharded=True``): stream terms divide across shards and
     the wave-panel broadcast is priced at link bandwidth, so
     ``method="auto"`` with a mesh genuinely arbitrates sharded-fused vs
-    replicated.  Sharded keys form their own cache class per device
-    count and are never persisted or interpolated across mesh sizes.
-
-    Unmeasured shapes first try **cross-shape interpolation**: the
-    nearest measured/persisted plan of the same eligibility class
-    (identical dtype/platform/signs/sharded, eligible backend) by
-    ``(m, n, k, batch)`` log-distance is borrowed
-    (``source="interpolated"``) before the cost model is re-run, so
-    autotune work transfers to neighbouring problem sizes.  A later
-    ``autotune=True`` call upgrades a borrowed entry in place — the
-    borrowed plan's tiles join the measured candidate set, and the
-    winning measurement is persisted (exactly once) like any other.
+    replicated.
     """
     import jax.numpy as jnp
 
@@ -1149,24 +792,16 @@ def select_plan(m: int, n: int, k: int, *, dtype="float32",
     batch = max(1, int(batch))
     devices = max(1, int(devices))
     sharded = bool(sharded) or devices > 1
-    # a batch of one is its own sequence either way: normalize so the
-    # legacy cache key (and plan) is shared by both spellings
+    # a batch of one is its own sequence either way: normalize so both
+    # spellings share one key (and plan)
     shared_sequence = bool(shared_sequence) or batch <= 1
-    # Measurements time THIS host's default backend; for any other
-    # platform (or a shard_map sub-problem, which can't be reproduced
-    # standalone) fall back to model ranking rather than cache bogus
-    # numbers — and then accept a cached model-ranked entry, since a
-    # measured one can never exist for this key.
-    can_measure = platform == compat.default_platform() and not sharded
-    autotune = autotune and can_measure
     problem = Problem(m=m, n=n, k=k, dtype=dtype, platform=platform,
                       signs=signs, sharded=sharded, batch=batch,
                       shared_sequence=shared_sequence,
                       live_planes=live_planes, devices=devices)
     key = _plan_key(problem)
     cached = _PLAN_CACHE.get(key)
-    if cached is not None and (not autotune
-                               or cached.source in _PERSISTED_SOURCES):
+    if cached is not None:
         _CACHE_STATS["hits"] += 1
         obs.inc("registry.plan_cache.hits")
         return cached
@@ -1182,57 +817,13 @@ def select_plan(m: int, n: int, k: int, *, dtype="float32",
         return best
 
     with obs.span("resolve", m=m, n=n, k=k, batch=batch, dtype=dtype,
-                  platform=platform, autotune=autotune) as sp:
-        if not autotune:
-            borrowed = _interpolated_plan(problem, key)
-            if borrowed is not None:
-                _PLAN_CACHE[key] = borrowed
-                obs.inc("registry.plan_cache.interpolated")
-                sp.set(method=borrowed.method, source="interpolated")
-                return borrowed
+                  platform=platform) as sp:
         plans = _modeled_plans(problem)
         if not plans:
             raise ValueError(
                 f"no registered backend is eligible for {problem}"
             )
         best = plans[0]
-        if autotune:
-            candidates = plans[:max(1, autotune_top)]
-            if batch > 1 and not shared_sequence:
-                # Per-request batches execute through fused / vmap /
-                # per-element-loop strategies whose constants the §6
-                # traffic model cannot see (interpret-mode kernels
-                # included), so widen the measured set to the best
-                # modeled candidate of every eligible backend and let
-                # measurement arbitrate — the model still prunes tiles
-                # within each backend.
-                seen = {pl.method for pl in candidates}
-                for pl in plans:
-                    if pl.method not in seen:
-                        seen.add(pl.method)
-                        candidates.append(pl)
-            # an interpolated entry being upgraded is a real hint:
-            # measure its tiles too, even when the model does not rank
-            # them top-N
-            if cached is not None and cached.source == "interpolated" \
-                    and not any(
-                        (pl.method, pl.n_b, pl.k_b, pl.m_blk)
-                        == (cached.method, cached.n_b, cached.k_b,
-                            cached.m_blk)
-                        for pl in candidates):
-                candidates = candidates + [cached]
-            # every candidate compiles (the tile generators offer no
-            # other), so a measurement that raises is a real failure
-            timed = [dataclasses.replace(
-                plan, est_seconds=_measure_plan(problem, plan),
-                source="measured") for plan in candidates]
-            best = min(timed, key=lambda pl: pl.est_seconds)
-            if cached is not None:
-                # a cached (model/interpolated) entry was replaced by a
-                # fresh measurement for the same key
-                obs.inc("registry.plan_cache.autotune_upgrade")
         _PLAN_CACHE[key] = best
-        sp.set(method=best.method, source=best.source)
-    if best.source == "measured":
-        save_plan_cache()  # write-through; no-op when persistence is off
+        sp.set(method=best.method)
     return best

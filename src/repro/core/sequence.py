@@ -23,7 +23,7 @@ module makes that object a real type:
 
 * **Two-phase execution** — ``plan = seq.plan(like=A)`` resolves the
   backend registry *once* (capability filter + SS6 cost model + plan
-  cache, or measured autotune) into a frozen :class:`SequencePlan`;
+  memo) into a frozen :class:`SequencePlan`;
   ``plan.apply(A)`` then calls the chosen backend directly, with no
   registry lookup on the hot path.  ``seq.apply(A)`` is the one-shot
   convenience composing both.
@@ -349,8 +349,8 @@ class RotationSequence:
     def to_dict(self) -> dict:
         """JSON-serializable dict (wave arrays as nested lists).
 
-        Intended for small recorded sequences (warm-start state,
-        request replay); large waves belong in array checkpoints.
+        Intended for small recorded sequences (request replay); large
+        waves belong in array checkpoints.
         """
         import numpy as np
 
@@ -385,7 +385,7 @@ class RotationSequence:
 
     # -- execution ---------------------------------------------------------
     def plan(self, like=None, *, m: Optional[int] = None,
-             method: str = "auto", autotune: bool = False,
+             method: str = "auto",
              platform: Optional[str] = None, sharded: bool = False,
              batch: Optional[int] = None, shared_sequence: bool = True,
              n_b: Optional[int] = None, k_b: Optional[int] = None,
@@ -402,8 +402,8 @@ class RotationSequence:
         setup × b and can plan onto a different backend than the same
         batch sharing one sequence (docs/cost-model.md).
         ``method="auto"`` runs capability filtering + the
-        SS6 cost model (or measured ``autotune``) through the per-shape
-        plan cache — batch-aware, so a batch-64 bucket can plan onto a
+        SS6 cost model through the per-problem plan memo — batch-aware,
+        so a batch-64 bucket can plan onto a
         different backend than a single request; a named method keeps
         the seed defaults (``n_b=64, k_b=16`` for tiled backends; on the
         chip a Pallas kernel takes its cheapest compilable tile, see
@@ -439,8 +439,8 @@ class RotationSequence:
                     m, n, k, dtype=dtype, platform=platform,
                     signs=self.sign is not None, sharded=sharded,
                     batch=batch, shared_sequence=shared_sequence,
-                    live_planes=self.k_live, autotune=autotune)
-                sp.set(method=plan.method, source=plan.source)
+                    live_planes=self.k_live)
+                sp.set(method=plan.method)
             planned = plan.kwargs()
             if n_b is not None:
                 planned["n_b"] = n_b
@@ -690,13 +690,12 @@ class SequencePlan:
     def to_dict(self) -> dict:
         """Serialize the *dispatch decision* (not the waves) to JSON.
 
-        The dict captures everything a warm process needs to skip
+        The dict captures everything another process needs to skip
         planning: backend method, resolved kwargs, the registry
         :class:`~repro.core.registry.Plan` record, and the wave
         shape/dtype/sign signature the decision was made for.  It is
-        keyed to the running JAX version (mirroring the persisted plan
-        cache — measured decisions do not transfer across compilers);
-        :meth:`from_dict` rejects stale or mismatched entries.
+        keyed to the running JAX version; :meth:`from_dict` rejects
+        stale or mismatched entries.
         """
         seq = self.sequence
         d = {
@@ -723,8 +722,7 @@ class SequencePlan:
         Raises ``ValueError`` when the entry is unusable: unknown
         format, different JAX version, wave-shape/dtype/sign mismatch
         with ``sequence``, or a backend that is no longer registered.
-        Callers holding persisted plans should treat the error as a
-        cache miss and re-plan.
+        Callers should treat the error as a miss and re-plan.
         """
         _ensure_backends()
         if d.get("format") != PLAN_DICT_FORMAT:
@@ -734,7 +732,7 @@ class SequencePlan:
         if d.get("jax") != jax_now:
             raise ValueError(
                 f"plan serialized under JAX {d.get('jax')!r}; running "
-                f"{jax_now} — re-plan (measured decisions do not transfer)")
+                f"{jax_now} — re-plan")
         if tuple(d.get("shape", ())) != tuple(sequence.shape):
             raise ValueError(
                 f"plan serialized for wave shape {d.get('shape')}; "
@@ -762,7 +760,7 @@ class SequencePlan:
                 method=str(pd.get("method", method)), n_b=pd.get("n_b"),
                 k_b=pd.get("k_b"), m_blk=pd.get("m_blk"),
                 est_seconds=float(pd.get("est_seconds", 0.0)),
-                source="persisted")
+                source=str(pd.get("source", "model")))
         return cls(sequence, method, kwargs, plan)
 
 

@@ -81,7 +81,7 @@ def _mesh_devices(mesh, axes) -> int:
 def plan_sharded(seq: RotationSequence, like=None, *, mesh,
                  row_axes=("data",), m: Optional[int] = None,
                  batch: Optional[int] = None, method: str = "auto",
-                 autotune: bool = False, platform: Optional[str] = None,
+                 platform: Optional[str] = None,
                  shared_sequence: bool = True,
                  partition: str = "row", col_axis: str = "model",
                  n_b: Optional[int] = None, k_b: Optional[int] = None,
@@ -94,8 +94,8 @@ def plan_sharded(seq: RotationSequence, like=None, *, mesh,
     dimension shards over (``devices`` = their extent product).
 
     ``method="auto"`` resolves **two** problems through the registry —
-    the sharded one (``devices=D``, keyed into its own ``"sharded"``
-    plan-cache class that never transfers to single-device keys) and
+    the sharded one (``devices=D``, under its own ``"sharded"`` plan
+    key, apart from single-device keys) and
     the replicated one — and freezes whichever the comm-extended cost
     model prices cheaper (:attr:`ShardedSequencePlan.execute_sharded`).
     A named ``method`` must be shard_map-capable
@@ -174,12 +174,11 @@ def plan_sharded(seq: RotationSequence, like=None, *, mesh,
         sh_plan = registry.select_plan(
             m, n, k, dtype=dtype, platform=platform, signs=signs,
             sharded=True, devices=devices, batch=batch,
-            shared_sequence=shared_sequence, live_planes=seq.k_live,
-            autotune=autotune)
+            shared_sequence=shared_sequence, live_planes=seq.k_live)
         rep_plan = registry.select_plan(
             m, n, k, dtype=dtype, platform=platform, signs=signs,
             batch=batch, shared_sequence=shared_sequence,
-            live_planes=seq.k_live, autotune=autotune)
+            live_planes=seq.k_live)
         sh_s, rep_s = modeled_crossover(
             m, n, k, devices=devices, dtype=dtype, platform=platform,
             signs=signs, batch=batch, shared_sequence=shared_sequence,
@@ -611,7 +610,7 @@ class ShardedSequencePlan:
         format/JAX version, wave signature, unregistered backend, or a
         mesh whose extent over the stored axes is not the stored device
         count — a sharded decision never transfers across mesh sizes,
-        exactly like its plan-cache class.
+        exactly like its plan key.
         """
         if d.get("format") != SHARDED_PLAN_DICT_FORMAT:
             raise ValueError(
@@ -659,7 +658,7 @@ class ShardedSequencePlan:
                 method=str(pd.get("method", method)), n_b=pd.get("n_b"),
                 k_b=pd.get("k_b"), m_blk=pd.get("m_blk"),
                 est_seconds=float(pd.get("est_seconds", 0.0)),
-                source="persisted")
+                source=str(pd.get("source", "model")))
         return cls(sequence=sequence, mesh=mesh, row_axes=row_axes,
                    method=method, kwargs=kwargs, plan=plan,
                    devices=devices,

@@ -58,7 +58,7 @@ def _canonical_dtype(A):
 
 
 def eigh_givens(A, *, method: str = "qr", k_delay: int = 32,
-                apply_method: str = "auto", autotune: bool = False,
+                apply_method: str = "auto",
                 cycles: int = 8, tol: Optional[float] = None,
                 max_sweeps: Optional[int] = None) -> EighResult:
     """Symmetric eigendecomposition via recorded rotation sequences.
@@ -70,7 +70,6 @@ def eigh_givens(A, *, method: str = "qr", k_delay: int = 32,
       k_delay: delayed-application batch depth (waves per flush).
       apply_method: dispatch method for basis accumulation (``"auto"``
         routes through the registry cost model + plan cache).
-      autotune: measure candidate plans on the first flush.
       cycles: Jacobi cycles (``method="jacobi"`` only).
       tol / max_sweeps: QR deflation threshold and sweep budget.
 
@@ -91,7 +90,7 @@ def eigh_givens(A, *, method: str = "qr", k_delay: int = 32,
         from repro.core.jacobi import jacobi_apply_basis, jacobi_eigh
 
         res = jacobi_eigh(jnp.asarray(A, dtype), cycles=cycles)
-        V = jacobi_apply_basis(res, method=apply_method, autotune=autotune)
+        V = jacobi_apply_basis(res, method=apply_method)
         w = res.eigenvalues
         order = jnp.argsort(w)
         return EighResult(w[order].astype(dtype), V[:, order].astype(dtype))
@@ -103,7 +102,7 @@ def eigh_givens(A, *, method: str = "qr", k_delay: int = 32,
     qr = tridiag_qr(tri.diag, tri.offdiag, tol=tol, max_sweeps=max_sweeps)
     _warn_unconverged("eigh_givens", qr.converged, qr.sweeps)
     buf = DelayedRotationBuffer(jnp.eye(n, dtype=dtype), k_delay=k_delay,
-                                method=apply_method, autotune=autotune)
+                                method=apply_method)
     # V = Q_tri @ U_qr: both recordings share the (n-1, .) plane layout,
     # so they stream through the buffer as one composed sequence
     buf.push_sequence(RotationSequence(tri.cos, tri.sin))
@@ -115,7 +114,7 @@ def eigh_givens(A, *, method: str = "qr", k_delay: int = 32,
 
 
 def svd_givens(A, *, k_delay: int = 32, apply_method: str = "auto",
-               autotune: bool = False, tol: Optional[float] = None,
+               tol: Optional[float] = None,
                max_sweeps: Optional[int] = None,
                full_matrices: bool = False) -> SvdResult:
     """Golub-Kahan SVD via recorded rotation sequences.
@@ -132,8 +131,7 @@ def svd_givens(A, *, k_delay: int = 32, apply_method: str = "auto",
     dtype = _canonical_dtype(A)
     if m < n:
         r = svd_givens(jnp.asarray(A).T, k_delay=k_delay,
-                       apply_method=apply_method, autotune=autotune,
-                       tol=tol, max_sweeps=max_sweeps,
+                       apply_method=apply_method, tol=tol, max_sweeps=max_sweeps,
                        full_matrices=full_matrices)
         return SvdResult(r.Vt.T, r.s, r.U.T)
     if n == 0:
@@ -147,14 +145,14 @@ def svd_givens(A, *, k_delay: int = 32, apply_method: str = "auto",
     # left factor: bidiag waves live on (m-1) planes, QR waves on (n-1);
     # embed the latter with identity padding below plane n-2
     buf_u = DelayedRotationBuffer(jnp.eye(m, dtype=dtype), k_delay=k_delay,
-                                  method=apply_method, autotune=autotune)
+                                  method=apply_method)
     buf_u.push_sequence(RotationSequence(bd.cos_left, bd.sin_left))
     buf_u.push_sequence(RotationSequence(
         _embed_planes(qr.cos_left, m - 1, 1.0),
         _embed_planes(qr.sin_left, m - 1, 0.0)))
     U = buf_u.value
     buf_v = DelayedRotationBuffer(jnp.eye(n, dtype=dtype), k_delay=k_delay,
-                                  method=apply_method, autotune=autotune)
+                                  method=apply_method)
     buf_v.push_sequence(RotationSequence(bd.cos_right, bd.sin_right))
     buf_v.push_sequence(RotationSequence(qr.cos_right, qr.sin_right))
     V = buf_v.value

@@ -7,8 +7,8 @@ eigen/singular-vector accumulators in bulk: a
 recorded waves until ``k_delay`` of them are pending, then flushes the
 whole batch as one :class:`~repro.core.sequence.RotationSequence`
 through a **cached** :class:`~repro.core.sequence.SequencePlan` — the
-registry (capability filter + cost model + plan cache, or measured
-autotune) is consulted on the *first* flush only; every later flush
+registry (capability filter + cost model + plan memo) is consulted on
+the *first* flush only; every later flush
 rebinds the frozen plan to the fresh waves and calls the chosen backend
 directly.  This converts the accumulation flops from ``K`` rank-2
 column updates into ``K / k_delay`` blocked/accumulated (or Pallas)
@@ -43,8 +43,6 @@ class DelayedRotationBuffer:
       k_delay: waves buffered per flush (the SS5.1 delay depth).
       method: dispatch method for the flush; ``"auto"`` consults the
         registry cost model + plan cache (once — see ``plan``).
-      autotune: measure candidate plans when first resolving the flush
-        plan (``auto`` only).
       mesh: optional ``jax.sharding.Mesh`` — flushes resolve a
         row-sharded :class:`~repro.dist.ShardedSequencePlan` via
         :func:`repro.dist.plan_sharded` instead of a replicated
@@ -58,7 +56,7 @@ class DelayedRotationBuffer:
     """
 
     def __init__(self, M, *, k_delay: int = 32, method: str = "auto",
-                 autotune: bool = False, pad_flush: bool = True,
+                 pad_flush: bool = True,
                  mesh=None, row_axes=("data",), **apply_kw):
         import jax.numpy as jnp
 
@@ -71,7 +69,6 @@ class DelayedRotationBuffer:
                 f"got {self._M.shape}")
         self.k_delay = int(k_delay)
         self.method = method
-        self.autotune = autotune
         self.mesh = mesh
         self.row_axes = tuple(row_axes)
         self.pad_flush = bool(pad_flush)
@@ -180,11 +177,9 @@ class DelayedRotationBuffer:
                         plan = dist.plan_sharded(
                             seq, like=self._M, mesh=self.mesh,
                             row_axes=self.row_axes, method=self.method,
-                            autotune=self.autotune, shared_sequence=True,
-                            **self.apply_kw)
+                            shared_sequence=True, **self.apply_kw)
                     else:
                         plan = seq.plan(like=self._M, method=self.method,
-                                        autotune=self.autotune,
                                         shared_sequence=True,
                                         **self.apply_kw)
                     self._plans[plan_key] = plan

@@ -73,7 +73,7 @@ def _run_rotations(args) -> None:
     # canonical mixed-shape stream: >= 3 shape buckets by construction
     requests = synthetic_stream(args.requests, seed=args.seed)
 
-    svc = RotationService(slots=args.slots, autotune=args.autotune)
+    svc = RotationService(slots=args.slots)
     misses0 = plan_cache_stats()["misses"]
     t0 = obs.timing.now()
     outs = svc.apply_many(requests)
@@ -98,8 +98,7 @@ def _run_rotations(args) -> None:
           f"{s['slots_executed']} executed)")
     print(f"buckets={len(svc._plans)} batches={s['batches']} "
           f"plans_resolved={s['plans_resolved']} (registry misses "
-          f"{resolved}) warm_plans={s['warm_plans']} "
-          f"padded_slots={s['padded_slots']}")
+          f"{resolved}) padded_slots={s['padded_slots']}")
 
     if args.metrics_json:
         snap = obs.write_metrics_json(
@@ -121,7 +120,7 @@ def _run_stream(args) -> None:
     from repro.serve import StreamEngine
 
     requests = synthetic_stream_for(args)
-    with StreamEngine(slots=args.slots, autotune=args.autotune) as eng:
+    with StreamEngine(slots=args.slots) as eng:
         t0 = obs.timing.now()
         tickets = [eng.submit(seq, A) for seq, A in requests]
         outs = [t.result(timeout=600.0) for t in tickets]
@@ -180,8 +179,6 @@ def main():
                     help="rotation mode: number of requests to stream")
     ap.add_argument("--slots", type=int, default=8,
                     help="rotation mode: per-bucket batch capacity")
-    ap.add_argument("--autotune", action="store_true",
-                    help="rotation mode: measure bucket plans")
     ap.add_argument("--check", action="store_true",
                     help="rotation mode: verify against per-request apply")
     ap.add_argument("--metrics-json", default=None,

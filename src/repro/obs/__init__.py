@@ -25,8 +25,7 @@ programmatically via :func:`set_enabled` / :func:`override`.
 This package is also the **single sanctioned home for timing**
 (:mod:`repro.obs.timing`); analyzer rule RA502 lint-errors ad-hoc
 ``time.perf_counter`` / ``time.time`` / ``timeit`` references
-anywhere else in ``repro.*`` / ``benchmarks.*`` / ``examples.*``
-(``benchmarks/common.py`` is the one exempt shim).
+anywhere else in ``repro.*`` / ``examples.*``.
 """
 from __future__ import annotations
 

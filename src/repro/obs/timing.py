@@ -1,11 +1,11 @@
 """The single sanctioned wall-clock in the tree.
 
-Every host-side timing measurement — benchmark loops, span timestamps,
-launcher throughput prints — goes through this module.  Analyzer rule
-RA502 bans direct ``time.perf_counter`` / ``time.time`` / ``timeit``
-references everywhere else (only this module and
-``benchmarks/common.py`` are exempt), so "who is allowed to look at
-the clock" is a one-line grep instead of an audit.
+Every host-side timing measurement — span timestamps, launcher
+throughput prints — goes through this module.  Analyzer rule RA502
+bans direct ``time.perf_counter`` / ``time.time`` / ``timeit``
+references everywhere else in the library and the examples (only
+``repro.obs`` is exempt), so "who is allowed to look at the clock" is
+a one-line grep instead of an audit.
 
 Keeping the clock behind one function also keeps rule family RA5
 honest: cost-model and plan-key code imports :mod:`repro.obs` freely

@@ -1,8 +1,7 @@
 from .lm import ServeEngine
-from .rotations import BucketKey, RotationService, serve_plan_store_path
+from .rotations import BucketKey, RotationService
 from .stream import (Backpressure, DeadlineExceeded, EngineClosed,
                      StreamEngine, StreamTicket)
 
-__all__ = ["RotationService", "BucketKey", "serve_plan_store_path",
-           "StreamEngine", "StreamTicket", "Backpressure",
+__all__ = ["RotationService", "BucketKey", "StreamEngine", "StreamTicket", "Backpressure",
            "DeadlineExceeded", "EngineClosed", "ServeEngine"]

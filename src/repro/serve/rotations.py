@@ -22,8 +22,9 @@ buckets):
 * **one frozen plan per bucket** — the first drain of a bucket resolves
   the registry exactly once (``seq.plan(like=..., batch=slots)``, so the
   cost model prices the *batched* problem: a batch-64 bucket can
-  legitimately land on a different backend than a single request);
-  every later drain rebinds the frozen
+  legitimately land on a different backend than a single request; a
+  second service in the same process finds the plan in the registry's
+  memo); every later drain rebinds the frozen
   :class:`~repro.core.sequence.SequencePlan` and calls the backend
   directly via :meth:`~repro.core.sequence.SequencePlan.apply_batched`.
 * **slot padding + per-request unpadding** — partial drains are padded
@@ -41,12 +42,6 @@ buckets):
   ``valid_planes`` windows skipping the ``pad_to`` identity waves
   instead of multiplying them through; per-request vmap/loop execution
   stays as the fallback capability on every other backend.
-* **serialized warm starts** — resolved bucket plans write through to a
-  JSON store next to the registry's persisted plan cache
-  (``~/.cache/repro/serve_plans.json``; same ``REPRO_PLAN_CACHE``
-  override semantics, keyed by JAX version).  A warm service restores
-  them via :meth:`~repro.core.sequence.SequencePlan.from_dict` and
-  performs **zero** new registry resolutions for known buckets.
 
 Bitwise contract: per-request and bucketed execution are bit-identical
 for plain-rotation sequences on every rotation-family backend
@@ -67,21 +62,17 @@ multiplied-through ``0*x`` would poison or sign-normalize them.
 from __future__ import annotations
 
 import dataclasses
-import os
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 import numpy as np
 
 from repro import obs
 
-__all__ = ["RotationService", "BucketKey", "serve_plan_store_path",
-           "synthetic_stream"]
-
-_STORE_FORMAT = 1
+__all__ = ["RotationService", "BucketKey", "synthetic_stream"]
 
 # canonical mixed-shape demo workload (>= 3 buckets by construction),
-# shared by `repro.launch.serve --rotations` and benchmarks/bench_serve
-# so the CI bucket-count invariants track one definition
+# shared by `repro.launch.serve --rotations` and the serving tests so
+# their bucket counts track one definition
 DEMO_SHAPES = ((16, 32, 8), (32, 32, 8), (16, 64, 12))
 
 
@@ -100,21 +91,6 @@ def synthetic_stream(n_requests: int, *, shapes=DEMO_SHAPES, seed: int = 0):
         A = jnp.asarray(rng.standard_normal((m, n)), jnp.float32)
         out.append((random_sequence(jax.random.key(seed + i), n, k), A))
     return out
-
-
-def serve_plan_store_path() -> Optional[str]:
-    """Default on-disk store for serialized bucket plans.
-
-    Lives next to the registry's persisted plan cache and follows the
-    same ``REPRO_PLAN_CACHE`` override: when plan persistence is off,
-    serving still works — it just re-plans each bucket once per process.
-    """
-    from repro.core import registry
-
-    base = registry.plan_cache_path()
-    if base is None:
-        return None
-    return os.path.join(os.path.dirname(base), "serve_plans.json")
 
 
 def _next_pow2(x: int) -> int:
@@ -148,16 +124,6 @@ class BucketKey:
     signed: bool
     wave_dtype: str
 
-    def as_list(self) -> list:
-        return [self.m, self.n, self.dtype, self.k_pad, self.signed,
-                self.wave_dtype]
-
-    @classmethod
-    def from_list(cls, parts) -> "BucketKey":
-        m, n, dtype, k_pad, signed, wave_dtype = parts
-        return cls(int(m), int(n), str(dtype), int(k_pad), bool(signed),
-                   str(wave_dtype))
-
 
 @dataclasses.dataclass
 class _Pending:
@@ -180,21 +146,14 @@ class RotationService:
         batched computation keeps one stable shape.
       method: dispatch method for bucket plans (``"auto"`` prices the
         *batched* problem through the registry cost model).
-      autotune: measure candidate plans when first resolving a bucket.
       pad_waves: normalize each request's wave count to the bucket's
         next-power-of-two ``k_pad`` (exact identity padding).  With
         ``False``, the raw wave count becomes part of the bucket key.
       min_k_pad: floor for ``k_pad`` (avoids one bucket per tiny k).
-      store: path for the serialized-plan store; ``None`` uses
-        :func:`serve_plan_store_path` (which respects
-        ``REPRO_PLAN_CACHE=off``), ``False`` disables persistence.
-      warm_start: load serialized plans from ``store`` at construction.
       mesh: optional ``jax.sharding.Mesh`` — bucket plans resolve
         through :func:`repro.dist.plan_sharded` (row-sharded batched
         drains; ``method="auto"`` arbitrates sharded vs replicated via
-        the comm-extended cost model).  Sharded bucket plans are
-        process-local: the serialized warm store is bypassed, since a
-        mesh cannot round-trip through JSON.
+        the comm-extended cost model).
       row_axes: mesh axes bucket targets' rows shard over (with
         ``mesh``; default ``("data",)``).
       plan_kw: extra kwargs forwarded to ``RotationSequence.plan`` when
@@ -202,37 +161,27 @@ class RotationService:
     """
 
     def __init__(self, *, slots: int = 8, method: str = "auto",
-                 autotune: bool = False, pad_waves: bool = True,
-                 min_k_pad: int = 4, store=None, warm_start: bool = True,
+                 pad_waves: bool = True, min_k_pad: int = 4,
                  mesh=None, row_axes=("data",), **plan_kw):
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
         self.slots = int(slots)
         self.method = method
-        self.autotune = autotune
         self.pad_waves = bool(pad_waves)
         self.min_k_pad = int(min_k_pad)
         self.mesh = mesh
         self.row_axes = tuple(row_axes)
         self.plan_kw = dict(plan_kw)
-        if store is False:
-            self._store_path = None
-        else:
-            self._store_path = store if store is not None \
-                else serve_plan_store_path()
         self._queues: Dict[BucketKey, List[_Pending]] = {}
         self._plans: Dict[BucketKey, "object"] = {}   # frozen SequencePlan
-        self._warm: Dict[BucketKey, dict] = {}        # serialized, unbound
         self._results: Dict[int, "object"] = {}
         self._next_ticket = 0
         # "requests" counts *real* admissions only; "slots_executed" is
         # total batch slots run (real + identity pad) — keeping the two
         # separate is what stops pad slots inflating req/s accounting
         self.stats = {"requests": 0, "batches": 0, "plans_resolved": 0,
-                      "warm_plans": 0, "padded_slots": 0, "padded_waves": 0,
+                      "padded_slots": 0, "padded_waves": 0,
                       "slots_executed": 0}
-        if warm_start:
-            self._load_store()
 
     def __repr__(self) -> str:  # pragma: no cover - debug aid
         pending = sum(len(q) for q in self._queues.values())
@@ -352,49 +301,28 @@ class RotationService:
         return self._results.pop(ticket)
 
     def _bucket_plan(self, key: BucketKey, rep_seq, like):
-        """The bucket's frozen plan: warm store first, registry once."""
-        from repro.core.sequence import SequencePlan
-
+        """The bucket's frozen plan, resolved exactly once."""
         plan = self._plans.get(key)
         if plan is not None:
             return plan
         if self.mesh is not None:
-            # sharded bucket plans resolve per process (no warm store:
-            # a live mesh has no JSON form) — still exactly once per
-            # bucket, rebound on every later drain like the rest
             from repro import dist
 
             plan = dist.plan_sharded(rep_seq, like=like, mesh=self.mesh,
                                      row_axes=self.row_axes,
                                      method=self.method,
-                                     autotune=self.autotune,
                                      shared_sequence=False,
                                      **self.plan_kw)
-            self.stats["plans_resolved"] += 1
-            obs.inc("serve.plans_resolved")
-            self._plans[key] = plan
-            return plan
-        warm = self._warm.get(key)
-        if warm is not None:
-            try:
-                plan = SequencePlan.from_dict(warm, rep_seq)
-                self.stats["warm_plans"] += 1
-            except ValueError:
-                plan = None  # stale entry: fall through to the registry
-        if plan is not None:
-            obs.inc("serve.warm_plans")
         else:
             # shared_sequence=False: a bucket batch carries one distinct
             # sequence per slot, so the registry prices per-sequence
             # setup × slots — the correction that lets method="auto"
             # avoid setup-heavy backends on serving traffic
             plan = rep_seq.plan(like=like, method=self.method,
-                                autotune=self.autotune, batch=self.slots,
-                                shared_sequence=False, **self.plan_kw)
-            self.stats["plans_resolved"] += 1
-            obs.inc("serve.plans_resolved")
-            self._warm[key] = plan.to_dict()
-            self._save_store()
+                                batch=self.slots, shared_sequence=False,
+                                **self.plan_kw)
+        self.stats["plans_resolved"] += 1
+        obs.inc("serve.plans_resolved")
         self._plans[key] = plan
         return plan
 
@@ -435,8 +363,8 @@ class RotationService:
         else:
             A = np.stack([np.asarray(t) for t in targets])
         # the planning representative carries the bucket's signature: a
-        # signed bucket plans (and warm-binds) on a sign-carrying
-        # sequence even when the first queued request is implicit
+        # signed bucket plans on a sign-carrying sequence even when the
+        # first queued request is implicit
         rep = seqs[0].with_signs() if key.signed else seqs[0]
         return seqs, A, rep, pad
 
@@ -500,61 +428,3 @@ class RotationService:
                       sum(len(q) for q in self._queues.values()))
         if self._queues[key]:
             self._drain_bucket(key)
-
-    # -- serialized plan store ---------------------------------------------
-    # (shares the registry cache's invalidation + atomic-write plumbing:
-    # _read_versioned_json / _atomic_write_json live in core.registry)
-
-    def _load_store(self) -> int:
-        """Merge serialized bucket plans from disk; returns count loaded.
-
-        Mirrors the registry's persisted-cache invalidation: a missing/
-        corrupt file, a different format, or a different JAX version is
-        ignored wholesale (individual entries are additionally validated
-        by ``SequencePlan.from_dict`` when first bound).
-        """
-        from repro.core import registry
-
-        path = self._store_path
-        if path is None:
-            return 0
-        payload = registry._read_versioned_json(path, _STORE_FORMAT)
-        if payload is None:
-            return 0
-        loaded = 0
-        for entry in payload.get("plans", []):
-            try:
-                key = BucketKey.from_list(entry["bucket"])
-                plan_dict = dict(entry["plan"])
-            except (KeyError, TypeError, ValueError):
-                continue
-            self._warm.setdefault(key, plan_dict)
-            loaded += 1
-        return loaded
-
-    def _save_store(self) -> Optional[str]:
-        """Atomically write-through all known bucket plans (best-effort
-        read-merge-replace, same courtesy as ``save_plan_cache``)."""
-        from repro.core import registry
-
-        path = self._store_path
-        if path is None:
-            return None
-        merged: Dict[Tuple, dict] = {}
-        on_disk = registry._read_versioned_json(path, _STORE_FORMAT)
-        if on_disk is not None:
-            for entry in on_disk.get("plans", []):
-                try:
-                    merged[tuple(entry["bucket"])] = entry
-                except (KeyError, TypeError):
-                    continue
-        for key, plan_dict in self._warm.items():
-            merged[tuple(key.as_list())] = {"bucket": key.as_list(),
-                                            "plan": plan_dict}
-        if not merged:
-            return None
-        payload = {"format": _STORE_FORMAT,
-                   "jax": registry._jax_version_str(),
-                   "plans": list(merged.values())}
-        return registry._atomic_write_json(path, payload,
-                                           prefix=".serve_plans.")

@@ -15,7 +15,7 @@ batches (one sequence per slot), so their plans are priced with
 explicit; analyzer rule RA204 confines thread/queue primitives to this
 module.
 
-The full design — bucket lifecycle, warm plans, backpressure and
+The full design — bucket lifecycle, plan reuse, backpressure and
 deadline semantics, close policy — is documented in
 ``docs/serving.md``; ``docs/architecture.md`` places this module in the
 registry → sequence → serve → stream layer diagram, and
@@ -156,9 +156,8 @@ class StreamEngine:
       start: spawn the scheduler/dispatcher threads immediately
         (``False`` lets tests exercise admission policies inertly).
       service_kw: forwarded to the private ``RotationService`` (e.g.
-        ``store=False``, ``method=...``, ``autotune=True``, or
-        ``mesh=``/``row_axes=`` for row-sharded bucket execution via
-        :mod:`repro.dist`).
+        ``method=...``, ``pad_waves=False``, or ``mesh=``/``row_axes=``
+        for row-sharded bucket execution via :mod:`repro.dist`).
     """
 
     def __init__(self, service: Optional[RotationService] = None, *,

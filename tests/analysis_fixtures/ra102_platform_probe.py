@@ -1,4 +1,4 @@
-# repro-lint: fixture-as=benchmarks/bad_probe.py
+# repro-lint: fixture-as=examples/bad_probe.py
 """RA102 fixture: platform probed outside compat.py."""
 import jax
 

@@ -2,7 +2,7 @@
 """RA501 fixture: wall-clock/RNG in cache-key and cost-model paths.
 
 A timestamped plan key makes identical problems hash to different
-plans, silently defeating the on-disk plan store.
+plans, silently defeating the in-process plan memo.
 """
 import random
 import time
@@ -22,10 +22,10 @@ def _bucket_key(seq) -> tuple:
     return (seq.n, np.random.default_rng().integers(10))  # expect: RA501
 
 
-def _measure_plan(fn):
-    # measurement helpers escape RA501 (name is outside the key/cost
-    # pattern) but still trip RA502: even measurement code must source
-    # its clock from repro.obs.timing
+def _time_call(fn):
+    # helpers outside the key/cost name pattern escape RA501 but still
+    # trip RA502: any code that times must source its clock from
+    # repro.obs.timing
     t0 = time.perf_counter()  # expect: RA502
     fn()
     return time.perf_counter() - t0  # expect: RA502

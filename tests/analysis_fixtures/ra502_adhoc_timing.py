@@ -1,10 +1,10 @@
-# repro-lint: fixture-as=benchmarks/bench_adhoc.py
+# repro-lint: fixture-as=examples/bench_adhoc.py
 """RA502 fixture: ad-hoc stopwatch code outside ``repro.obs``.
 
-Every hand-rolled ``time.perf_counter()`` pair is a number the roofline
-attribution never sees; ``repro.obs.timing`` (re-exported by
-``benchmarks.common``) is the single sanctioned clock.  A bare
-``import time`` stays legal — ``time.sleep`` is not a clock.
+Every hand-rolled ``time.perf_counter()`` pair is a number no one can
+compare with another; ``repro.obs.timing`` is the single sanctioned
+clock.  A bare ``import time`` stays legal — ``time.sleep`` is not a
+clock.
 """
 import time
 import timeit  # expect: RA502

@@ -1,5 +1,6 @@
 """Backend-registry dispatch: capability records, cost-model plans,
-plan caching, autotune, and oracle agreement of every registered method."""
+the in-process plan memo, and oracle agreement of every registered
+method."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -138,6 +139,36 @@ def test_plan_cache_hits_on_second_call():
     assert after["misses"] == before["misses"]
 
 
+# problems of every memo-key class: dense, live-annotated, shared and
+# per-request batches, sharded over two and four devices
+_KEY_CLASSES = [
+    dict(m=64, n=256, k=12),
+    dict(m=64, n=256, k=12, live_planes=255 * 3),
+    dict(m=16, n=32, k=8, batch=64),
+    dict(m=16, n=32, k=8, batch=64, shared_sequence=False),
+    dict(m=4096, n=512, k=64, sharded=True, devices=2),
+    dict(m=4096, n=512, k=64, sharded=True, devices=4),
+]
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_auto_plan_is_order_independent(platform):
+    """Which problems were planned before, and in what order, never
+    changes a plan: each is the model's for its own problem."""
+    clear_plan_cache()
+    forward = [select_plan(platform=platform, **p) for p in _KEY_CLASSES]
+    clear_plan_cache()
+    backward = [select_plan(platform=platform, **p)
+                for p in reversed(_KEY_CLASSES)][::-1]
+    clear_plan_cache()
+    alone = []
+    for p in _KEY_CLASSES:
+        alone.append(select_plan(platform=platform, **p))
+        clear_plan_cache()
+    assert forward == backward == alone
+    assert all(pl.source == "model" for pl in forward)
+
+
 def test_auto_matches_oracle():
     A, seq = _problem(10, 24, 5, seed=7)
     ref = rot_sequence_numpy(A, seq.cos, seq.sin)
@@ -176,66 +207,3 @@ def test_explicit_tiles_override_auto_plan():
                                   method="auto", n_b=8, k_b=2)
     np.testing.assert_allclose(np.asarray(out, np.float64), ref,
                                atol=5e-5, rtol=1e-4)
-
-
-def test_cross_shape_plan_interpolation():
-    """An unmeasured shape borrows the nearest measured plan of its
-    eligibility class before the cost model is re-run."""
-    clear_plan_cache()
-    donor = select_plan(16, 48, 6, platform="cpu", autotune=True,
-                        autotune_top=2)
-    assert donor.source == "measured"
-    # nearby unmeasured shape: borrowed, not model-ranked
-    borrowed = select_plan(20, 64, 8, platform="cpu")
-    assert borrowed.source == "interpolated"
-    assert borrowed.method == donor.method
-    assert (borrowed.n_b, borrowed.k_b) == (donor.n_b, donor.k_b)
-    # cached under its own key afterwards
-    stats0 = plan_cache_stats()["hits"]
-    assert select_plan(20, 64, 8, platform="cpu") == borrowed
-    assert plan_cache_stats()["hits"] == stats0 + 1
-    # a different eligibility class (signs) must NOT borrow it
-    other = select_plan(20, 64, 8, platform="cpu", signs=True)
-    assert other.source == "model"
-    # nearest-donor selection: seed a second, farther measured plan and
-    # check log-distance picks the close one
-    clear_plan_cache()
-    import dataclasses as _dc
-    near_key = (16, 48, 6, "float32", "cpu", False, False)
-    far_key = (1024, 4096, 128, "float32", "cpu", False, False)
-    registry._PLAN_CACHE[near_key] = _dc.replace(donor, source="measured")
-    registry._PLAN_CACHE[far_key] = _dc.replace(
-        donor, method="accumulated", n_b=96, k_b=96, source="measured")
-    pick = select_plan(20, 64, 8, platform="cpu")
-    assert pick.source == "interpolated"
-    assert pick.method == donor.method and pick.n_b == donor.n_b
-    # ... but a shape beyond the log-distance cap must NOT borrow: the
-    # cost model is the better guess across regime changes
-    far_pick = select_plan(16384, 16384, 2048, platform="cpu")
-    assert far_pick.source == "model"
-    # autotune=True ignores the borrowed entry and measures for real
-    measured = select_plan(20, 64, 8, platform="cpu", autotune=True,
-                           autotune_top=1)
-    assert measured.source == "measured"
-    clear_plan_cache()
-
-
-def test_autotune_measures_and_caches():
-    clear_plan_cache()
-    plan = select_plan(16, 48, 6, platform="cpu", autotune=True,
-                       autotune_top=2)
-    assert plan.source == "measured"
-    assert plan.est_seconds > 0
-    again = select_plan(16, 48, 6, platform="cpu", autotune=True,
-                        autotune_top=2)
-    assert again == plan
-    assert plan_cache_stats()["hits"] >= 1
-    # a measured plan is reused by plain (non-autotune) auto calls ...
-    assert select_plan(16, 48, 6, platform="cpu") == plan
-    # ... and autotune=True upgrades an existing model-ranked entry
-    clear_plan_cache()
-    modeled = select_plan(16, 48, 6, platform="cpu")
-    assert modeled.source == "model"
-    measured = select_plan(16, 48, 6, platform="cpu", autotune=True,
-                           autotune_top=2)
-    assert measured.source == "measured"

@@ -254,10 +254,10 @@ def test_comm_term_monotone_in_devices():
 
 
 def test_sharded_plan_cache_key_isolation():
-    """Sharded plan keys carry ``("sharded", devices)`` in the legacy
-    slot, so plans never transfer between device counts or to
-    single-device keys (distinct ``_split_key`` classes)."""
-    from repro.core.registry import Problem, _plan_key, _split_key
+    """Sharded plan keys carry ``("sharded", devices)`` in the
+    ``sharded`` slot, so each device count, and the single-device
+    problem, has its own memo entry."""
+    from repro.core.registry import Problem, _plan_key
 
     k1 = _plan_key(Problem(64, 32, 8))
     k8 = _plan_key(Problem(64, 32, 8, sharded=True, devices=8))
@@ -265,11 +265,8 @@ def test_sharded_plan_cache_key_isolation():
     assert k1[6] is False
     assert k8[6] == ("sharded", 8)
     assert k4[6] == ("sharded", 4)
-
-    (_, cls1, _), (_, cls8, _), (_, cls4, _) = map(
-        _split_key, (k1, k8, k4))
-    assert len({cls1, cls8, cls4}) == 3, (cls1, cls8, cls4)
-    # round-trip through the key: same problem -> identical key/class
+    assert len({k1, k8, k4}) == 3, (k1, k8, k4)
+    # round-trip through the key: same problem -> identical key
     assert _plan_key(Problem(64, 32, 8, sharded=True, devices=8)) == k8
     # batch/per-request markers survive alongside the sharded slot
     kb = _plan_key(Problem(64, 32, 8, sharded=True, devices=8, batch=16,

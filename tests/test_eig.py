@@ -2,18 +2,15 @@
 
 Oracle tests against `{np,jnp}.linalg`, staircase-packing correctness of
 the tridiagonal/bidiagonal recordings, delayed-buffer flush equivalence
-(bit-for-bit per backend), persisted plan cache round-trip, and the
-SOAP-Givens `solver="qr"` consumer.
+(bit-for-bit per backend), the rotation count of a seeded QR recording,
+and the SOAP-Givens `solver="qr"` consumer.
 """
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import compat
-from repro.core import registry
 from repro.core.api import apply_rotation_sequence
 from repro.core.ref import rot_sequence_numpy
 from repro.core.rotations import random_sequence
@@ -65,6 +62,25 @@ def test_tridiag_qr_eigenvalues_and_sequence():
 
 
 # --------------------------------------------------------------- eigh ----
+
+def test_eigh_qr_recording_rotation_count():
+    """The rotations a seeded n=64 QR recording carries, pinned: the
+    first 512 waves of the tridiagonalization and implicit-shift QR
+    recordings, concatenated, hold 6746 nonzero sines in float32.  A
+    change in either recording's wave layout or deflation moves it."""
+    from repro.core.sequence import RotationSequence
+
+    rng = np.random.default_rng(64)
+    X = rng.standard_normal((64, 64))
+    tri = tridiagonalize((X + X.T) / 2)
+    qr = tridiag_qr(tri.diag, tri.offdiag)
+    seq = RotationSequence(
+        jnp.asarray(np.concatenate([tri.cos, qr.cos], axis=1), jnp.float32),
+        jnp.asarray(np.concatenate([tri.sin, qr.sin], axis=1), jnp.float32))
+    window = seq[:512]
+    assert (window.n, window.k) == (64, 262)
+    assert int(np.count_nonzero(np.asarray(window.sin))) == 6746
+
 
 @pytest.mark.parametrize("n", [4, 33, 64])
 def test_eigh_qr_oracle_f32(n):
@@ -272,87 +288,6 @@ def test_delayed_buffer_validates_wave_shape():
     buf = DelayedRotationBuffer(jnp.eye(5), k_delay=4)
     with pytest.raises(ValueError, match="planes"):
         buf.push(np.ones(7), np.zeros(7))
-
-
-# ------------------------------------------------- persisted plan cache ----
-
-def test_plan_cache_persistence_roundtrip(tmp_path, monkeypatch):
-    path = tmp_path / "plans.json"
-    monkeypatch.setenv("REPRO_PLAN_CACHE", str(path))
-    registry.clear_plan_cache()
-    try:
-        plan = registry.select_plan(16, 48, 6, platform="cpu",
-                                    autotune=True, autotune_top=2)
-        assert plan.source == "measured"
-        assert path.exists()  # write-through on measure
-        registry.clear_plan_cache()
-        assert registry.load_plan_cache() == 1
-        again = registry.select_plan(16, 48, 6, platform="cpu",
-                                     autotune=True)  # no re-measure
-        assert again.source == "persisted"
-        assert (again.method, again.n_b, again.k_b) == \
-            (plan.method, plan.n_b, plan.k_b)
-    finally:
-        registry.clear_plan_cache()
-
-
-def test_plan_cache_persistence_disabled(monkeypatch):
-    monkeypatch.setenv("REPRO_PLAN_CACHE", "off")
-    assert registry.plan_cache_path() is None
-    assert registry.save_plan_cache() is None
-    assert registry.load_plan_cache() == 0
-
-
-def test_plan_cache_ignores_corrupt_file(tmp_path, monkeypatch):
-    path = tmp_path / "plans.json"
-    path.write_text("{not json")
-    monkeypatch.setenv("REPRO_PLAN_CACHE", str(path))
-    assert registry.load_plan_cache() == 0
-
-
-def test_plan_cache_save_merges_foreign_entries(tmp_path, monkeypatch):
-    """A writer must not clobber plans another process persisted."""
-    path = tmp_path / "plans.json"
-    monkeypatch.setenv("REPRO_PLAN_CACHE", str(path))
-    registry.clear_plan_cache()
-    try:
-        key_a = (8, 8, 4, "float32", "cpu", False, False)
-        registry._PLAN_CACHE[key_a] = registry.Plan(
-            method="blocked", n_b=8, k_b=4, est_seconds=1e-6,
-            source="measured")
-        registry.save_plan_cache()
-        # "another process": different key, same file
-        registry.clear_plan_cache()
-        key_b = (16, 16, 8, "float32", "cpu", False, False)
-        registry._PLAN_CACHE[key_b] = registry.Plan(
-            method="accumulated", n_b=16, k_b=16, est_seconds=2e-6,
-            source="measured")
-        registry.save_plan_cache()
-        registry.clear_plan_cache()
-        assert registry.load_plan_cache() == 2  # both survive
-        assert {k for k in registry._PLAN_CACHE} == {key_a, key_b}
-    finally:
-        registry.clear_plan_cache()
-
-
-def test_plan_cache_rejects_other_jax_version(tmp_path, monkeypatch):
-    path = tmp_path / "plans.json"
-    monkeypatch.setenv("REPRO_PLAN_CACHE", str(path))
-    registry.clear_plan_cache()
-    try:
-        key = (8, 8, 4, "float32", "cpu", False, False)
-        registry._PLAN_CACHE[key] = registry.Plan(
-            method="blocked", n_b=8, k_b=4, est_seconds=1e-6,
-            source="measured")
-        assert registry.save_plan_cache() == str(path)
-        import json
-        payload = json.loads(path.read_text())
-        payload["jax"] = "0.0.1"
-        path.write_text(json.dumps(payload))
-        registry.clear_plan_cache()
-        assert registry.load_plan_cache() == 0
-    finally:
-        registry.clear_plan_cache()
 
 
 # ----------------------------------------------------------- consumers ----

@@ -2,7 +2,7 @@
 on the profiler's clock, and the disabled-path bit-identity contract.
 
 The exact-count tests pin the plan-cache counter semantics across the
-cold -> warm -> interpolated -> autotune-upgrade lifecycle; the
+cold -> warm lifecycle of the in-process memo; the
 determinism tests pin the acceptance contract that (a) two identical
 runs produce bit-identical snapshots once timing-derived fields are
 zeroed, and (b) disabling obs changes neither outputs nor plan-cache
@@ -231,42 +231,34 @@ def test_sharded_plan_and_in_place_apply_record_memory_and_donation(
 # ------------------------------------- plan-cache counters, exactly ----
 
 def test_plan_cache_counters_cold_warm_interpolated_upgrade():
+    """Cold -> warm in the in-process memo, counted exactly: a first
+    resolution is one miss, its repeat one hit, and a nearby shape is a
+    miss of its own that the model ranks (nothing is borrowed)."""
     with obs.override(True):
-        # cold: one miss, zero hits (autotuned so the entry can donate)
-        donor = select_plan(16, 48, 6, platform="cpu", autotune=True,
-                            autotune_top=1)
-        assert donor.source == "measured"
+        # cold: one miss, zero hits
+        first = select_plan(16, 48, 6, platform="cpu")
+        assert first.source == "model"
         c = obs.snapshot()["counters"]
         assert c.get("registry.plan_cache.hits", 0) == 0
         assert c["registry.plan_cache.misses"] == 1
 
-        # warm: exact repeat is a pure hit
-        assert select_plan(16, 48, 6, platform="cpu") == donor
+        # warm: exact repeat is a pure hit, the same record
+        assert select_plan(16, 48, 6, platform="cpu") is first
         c = obs.snapshot()["counters"]
         assert c["registry.plan_cache.hits"] == 1
         assert c["registry.plan_cache.misses"] == 1
 
-        # nearby unmeasured shape: counted as miss + interpolated borrow
-        borrowed = select_plan(20, 64, 8, platform="cpu")
-        assert borrowed.source == "interpolated"
+        # a nearby shape is cold on its own and priced by the model
+        near = select_plan(20, 64, 8, platform="cpu")
+        assert near.source == "model"
         c = obs.snapshot()["counters"]
         assert c["registry.plan_cache.misses"] == 2
-        assert c["registry.plan_cache.interpolated"] == 1
-
-        # the borrowed entry is itself warm on repeat
-        assert select_plan(20, 64, 8, platform="cpu") == borrowed
+        assert select_plan(20, 64, 8, platform="cpu") is near
         c = obs.snapshot()["counters"]
         assert c["registry.plan_cache.hits"] == 2
-
-        # autotune over a borrowed entry: miss + upgrade, never a hit
-        upgraded = select_plan(20, 64, 8, platform="cpu", autotune=True,
-                               autotune_top=1)
-        assert upgraded.source == "measured"
-        c = obs.snapshot()["counters"]
-        assert c["registry.plan_cache.hits"] == 2
-        assert c["registry.plan_cache.misses"] == 3
-        assert c["registry.plan_cache.autotune_upgrade"] == 1
-        assert c["registry.plan_cache.interpolated"] == 1
+        assert set(k for k in c if k.startswith("registry.")) == {
+            "registry.plan_cache.hits", "registry.plan_cache.misses"}
+        assert plan_cache_stats()["size"] == 2
 
 
 # ------------------------------------------------ dispatch + spans ----
@@ -338,7 +330,7 @@ def test_instrumented_apply_stays_differentiable(tmp_path):
 def test_service_metrics_account_pad_slots_and_latency():
     requests = synthetic_stream(8, seed=3)
     with obs.override(True):
-        svc = RotationService(slots=4, store=False)
+        svc = RotationService(slots=4)
         outs = svc.apply_many(requests)
         jax.block_until_ready(outs[-1])
         snap = obs.snapshot()
@@ -358,7 +350,7 @@ def test_service_snapshot_bit_identical_across_runs():
     def run() -> str:
         clear_plan_cache()
         obs.reset()
-        svc = RotationService(slots=4, store=False)
+        svc = RotationService(slots=4)
         outs = svc.apply_many(synthetic_stream(8, seed=3))
         jax.block_until_ready(outs[-1])
         return json.dumps(obs.zeroed_timings(obs.snapshot()),

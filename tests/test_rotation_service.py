@@ -1,20 +1,17 @@
 """Batched plan-once/apply-many serving: SequencePlan.apply_batched,
-plan serialization, the shape-bucketed RotationService, the batch-aware
-cost model, and the persisted-plan merge path."""
-import dataclasses
+plan serialization, the shape-bucketed RotationService, and the
+batch-aware cost model."""
 import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import registry
 from repro.core.registry import clear_plan_cache, plan_cache_stats, select_plan
 from repro.core.rotations import random_sequence
 from repro.core.sequence import RotationSequence, SequencePlan
-from repro.serve import RotationService, serve_plan_store_path
+from repro.serve import RotationService
 
 
 def _stream(n_requests=24, seed=0, shapes=None):
@@ -131,6 +128,19 @@ def test_sequence_plan_dict_roundtrip_bitwise():
                                   np.asarray(plan.apply(A)))
 
 
+@pytest.mark.parametrize("method", ["auto", "blocked"])
+def test_plan_dict_round_trip_keeps_the_plan_record(method):
+    """A serialized plan comes back with the registry record it was
+    frozen with (``None`` for a pinned method), not a relabelled one."""
+    seq = random_sequence(jax.random.key(1), 24, 6)
+    plan = seq.plan(m=16, method=method)
+    assert (plan.plan is None) == (method != "auto")
+    d = json.loads(json.dumps(plan.to_dict()))
+    back = SequencePlan.from_dict(d, seq)
+    assert back.plan == plan.plan
+    assert (back.method, back.kwargs) == (plan.method, plan.kwargs)
+
+
 def test_sequence_plan_from_dict_rejects_stale_and_mismatched():
     seq = random_sequence(jax.random.key(0), 16, 8)
     plan = seq.plan(m=8)
@@ -169,7 +179,7 @@ def test_service_bitwise_and_one_plan_per_bucket():
     refs = [seq.plan(like=A).apply(A) for seq, A in requests]
 
     misses0 = plan_cache_stats()["misses"]
-    svc = RotationService(slots=8, store=False)
+    svc = RotationService(slots=8)
     outs = svc.apply_many(requests)
     assert plan_cache_stats()["misses"] - misses0 == 3  # one per bucket
     assert svc.stats["plans_resolved"] == 3
@@ -192,7 +202,7 @@ def test_service_partial_batch_pads_slots():
     clear_plan_cache()
     requests = _stream(5, shapes=((16, 32, 8),))  # one bucket, 5 < slots
     refs = [seq.plan(like=A).apply(A) for seq, A in requests]
-    svc = RotationService(slots=8, store=False)
+    svc = RotationService(slots=8)
     outs = svc.apply_many(requests)
     assert svc.stats["padded_slots"] == 3
     for out, ref in zip(outs, refs):
@@ -223,7 +233,7 @@ def test_service_signed_and_reflect_requests():
             seq = RotationSequence(seq.cos, seq.sin, None, True)
         requests.append((seq, A))
     refs = [seq.plan(like=A).apply(A) for seq, A in requests]
-    svc = RotationService(slots=4, store=False)
+    svc = RotationService(slots=4)
     outs = svc.apply_many(requests)
     # plain bucket + signed bucket (sign-carrying and reflect share the
     # signed bucket; their structures stay implicit until stacking)
@@ -240,7 +250,7 @@ def test_service_admission_keeps_signs_implicit():
     ``pad_to``, reflector requests only materialize at genuine-reflector
     padding, and identity slot-pads stay implicit."""
     clear_plan_cache()
-    svc = RotationService(slots=8, store=False)
+    svc = RotationService(slots=8)
     rng = np.random.default_rng(9)
     A = jnp.asarray(rng.standard_normal((8, 16)), jnp.float32)
     plain = random_sequence(jax.random.key(0), 16, 5)
@@ -273,7 +283,7 @@ def test_service_fused_bucket_execution_bitwise():
     clear_plan_cache()
     requests = _stream(10)  # 3 buckets, partial drains
     refs = [seq.plan(like=A).apply(A) for seq, A in requests]
-    svc = RotationService(slots=4, store=False, method="rotseq_batched")
+    svc = RotationService(slots=4, method="rotseq_batched")
     outs = svc.apply_many(requests)
     assert svc.stats["padded_slots"] > 0  # partial buckets exercised
     for out, ref in zip(outs, refs):
@@ -283,7 +293,7 @@ def test_service_fused_bucket_execution_bitwise():
 
 def test_service_wave_padding_buckets_by_pow2():
     clear_plan_cache()
-    svc = RotationService(slots=8, store=False)
+    svc = RotationService(slots=8)
     rng = np.random.default_rng(8)
     A = jnp.asarray(rng.standard_normal((8, 16)), jnp.float32)
     t1 = svc.submit(random_sequence(jax.random.key(0), 16, 5), A)
@@ -298,81 +308,41 @@ def test_service_wave_padding_buckets_by_pow2():
     clear_plan_cache()
 
 
-def test_service_warm_restart_zero_resolutions(tmp_path):
-    """Acceptance: a warm restart from serialized plans performs zero new
-    registry plan resolutions and reproduces results exactly."""
-    clear_plan_cache()
-    store = str(tmp_path / "serve_plans.json")
-    requests = _stream(24)
-    svc = RotationService(slots=8, store=store)
-    outs = svc.apply_many(requests)
-    assert svc.stats["plans_resolved"] == 3
-    assert os.path.exists(store)
-
-    # "new process": plan cache cold, service warm from the store.
-    # Resolution counts are asserted through the obs metrics — the same
-    # counters the OBS_metrics.json artifact exports — not by poking
-    # service internals.
+def test_service_warm_restart_zero_resolutions():
+    """A second service in the same process resolves no new plan for
+    buckets the registry has already planned: it finds them in the
+    in-process memo, and reproduces the first service's bits."""
     from repro import obs
 
     clear_plan_cache()
+    requests = _stream(24)
+    svc = RotationService(slots=8)
+    outs = svc.apply_many(requests)
+    assert svc.stats["plans_resolved"] == 3
     misses0 = plan_cache_stats()["misses"]
-    warm = RotationService(slots=8, store=store)
+    assert misses0 == 3
+
+    # Counted through the obs metrics, not by poking service internals.
+    warm = RotationService(slots=8)
     with obs.override(True):
         obs.reset()
         outs2 = warm.apply_many(requests)
         counters = obs.snapshot()["counters"]
-    assert counters.get("serve.plans_resolved", 0) == 0
-    assert counters.get("serve.warm_plans", 0) == 3
+    # each bucket is still frozen once per service, but through the memo
+    assert counters.get("serve.plans_resolved", 0) == 3
+    assert counters.get("registry.plan_cache.hits", 0) == 3
     assert counters.get("registry.plan_cache.misses", 0) == 0
-    assert warm.stats["plans_resolved"] == 0
-    assert warm.stats["warm_plans"] == 3
     assert plan_cache_stats()["misses"] == misses0
+    for key, plan in warm._plans.items():
+        assert plan.plan is svc._plans[key].plan
     obs.reset()
     for a, b in zip(outs, outs2):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     clear_plan_cache()
 
 
-def test_service_warm_store_rejects_stale_jax(tmp_path):
-    clear_plan_cache()
-    store = str(tmp_path / "serve_plans.json")
-    requests = _stream(8, shapes=((16, 32, 8),))
-    RotationService(slots=8, store=store).apply_many(requests)
-    payload = json.loads(open(store).read())
-    payload["jax"] = "0.0.1"
-    open(store, "w").write(json.dumps(payload))
-    svc = RotationService(slots=8, store=store)
-    svc.apply_many(requests)
-    assert svc.stats["warm_plans"] == 0  # stale file ignored wholesale
-    assert svc.stats["plans_resolved"] == 1
-    clear_plan_cache()
-
-
-def test_service_warm_store_ignores_corrupt_file(tmp_path):
-    store = tmp_path / "serve_plans.json"
-    store.write_text("{not json")
-    svc = RotationService(slots=4, store=str(store))
-    outs = svc.apply_many(_stream(4, shapes=((8, 16, 4),)))
-    assert len(outs) == 4
-
-
-def test_service_functional_with_persistence_off(monkeypatch):
-    """REPRO_PLAN_CACHE=off disables the store but not serving."""
-    monkeypatch.setenv("REPRO_PLAN_CACHE", "off")
-    assert serve_plan_store_path() is None
-    clear_plan_cache()
-    requests = _stream(12)
-    refs = [seq.plan(like=A).apply(A) for seq, A in requests]
-    svc = RotationService(slots=4)  # default store resolves to None
-    outs = svc.apply_many(requests)
-    for out, ref in zip(outs, refs):
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-    clear_plan_cache()
-
-
 def test_service_rejects_bad_requests():
-    svc = RotationService(slots=2, store=False)
+    svc = RotationService(slots=2)
     seq = random_sequence(jax.random.key(0), 16, 4)
     with pytest.raises(ValueError, match="columns"):
         svc.submit(seq, jnp.zeros((4, 8)))
@@ -409,139 +379,3 @@ def test_delayed_buffer_rejects_wrong_rank():
 
     with pytest.raises(ValueError, match="accumulator"):
         DelayedRotationBuffer(jnp.zeros((2, 3, 4, 5)))
-
-
-# ------------------------------- persisted plan cache: merge small fix ----
-
-def test_autotune_upgrades_interpolated_and_persists_once(tmp_path,
-                                                          monkeypatch):
-    """An interpolated entry upgraded by autotune is measured (its tiles
-    join the candidate set) and persisted exactly once — no duplicate
-    keys on merge, across repeated saves."""
-    path = tmp_path / "plans.json"
-    monkeypatch.setenv("REPRO_PLAN_CACHE", str(path))
-    clear_plan_cache()
-    try:
-        donor = select_plan(16, 48, 6, platform="cpu", autotune=True,
-                            autotune_top=2)
-        assert donor.source == "measured"
-        borrowed = select_plan(20, 64, 8, platform="cpu")
-        assert borrowed.source == "interpolated"
-        upgraded = select_plan(20, 64, 8, platform="cpu", autotune=True,
-                               autotune_top=1)
-        assert upgraded.source == "measured"
-        registry.save_plan_cache()
-        registry.save_plan_cache()  # idempotent: still one entry per key
-        payload = json.loads(path.read_text())
-        keys = [tuple(e["key"]) for e in payload["plans"]]
-        assert len(keys) == len(set(keys))  # no duplicate keys
-        assert (20, 64, 8, "float32", "cpu", False, False) in keys
-        # interpolated entries themselves are never persisted
-        clear_plan_cache()
-        loaded = registry.load_plan_cache()
-        assert loaded == 2
-        assert all(p.source == "persisted"
-                   for p in registry._PLAN_CACHE.values())
-    finally:
-        clear_plan_cache()
-
-
-def test_save_merge_concurrent_writers_same_key(tmp_path, monkeypatch):
-    """Two writers sharing a key: merge keeps one entry, last writer's
-    measurement wins, foreign keys survive."""
-    path = tmp_path / "plans.json"
-    monkeypatch.setenv("REPRO_PLAN_CACHE", str(path))
-    clear_plan_cache()
-    try:
-        key = (8, 8, 4, "float32", "cpu", False, False)
-        other = (16, 16, 8, "float32", "cpu", False, False)
-        registry._PLAN_CACHE[key] = registry.Plan(
-            method="blocked", n_b=8, k_b=4, est_seconds=1e-6,
-            source="measured")
-        registry._PLAN_CACHE[other] = registry.Plan(
-            method="accumulated", n_b=16, k_b=16, est_seconds=2e-6,
-            source="measured")
-        registry.save_plan_cache()
-        # "writer B": same key, fresh measurement, no knowledge of
-        # `other`
-        clear_plan_cache()
-        registry._PLAN_CACHE[key] = registry.Plan(
-            method="blocked", n_b=16, k_b=8, est_seconds=5e-7,
-            source="measured")
-        registry.save_plan_cache()
-        payload = json.loads(path.read_text())
-        keys = [tuple(e["key"]) for e in payload["plans"]]
-        assert len(keys) == len(set(keys)) == 2  # exactly once per key
-        clear_plan_cache()
-        assert registry.load_plan_cache() == 2
-        assert registry._PLAN_CACHE[key].n_b == 16  # B's write won
-        assert registry._PLAN_CACHE[other].method == "accumulated"
-    finally:
-        clear_plan_cache()
-
-
-# --------------------------------------------- regression-compare gate ----
-
-def test_compare_baseline_check_semantics():
-    import importlib.util
-    import pathlib
-
-    spec = importlib.util.spec_from_file_location(
-        "compare_baseline",
-        pathlib.Path(__file__).parent.parent / "benchmarks"
-        / "compare_baseline.py")
-    cb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(cb)
-
-    count = dict(higher_is_better=False, rel_tol=0.0, count=True)
-    assert cb._check("c", count, 3, 3)[0]
-    assert not cb._check("c", count, 3, 4)[0]
-    rate_hi = dict(higher_is_better=True, rel_tol=0.30)
-    assert cb._check("r", rate_hi, 100.0, 71.0)[0]
-    assert not cb._check("r", rate_hi, 100.0, 69.0)[0]
-    assert cb._check("r", rate_hi, 100.0, 250.0)[0]  # improvement
-    rate_lo = dict(higher_is_better=False, rel_tol=0.30, abs_floor=500.0)
-    assert cb._check("o", rate_lo, 100.0, 129.0)[0]
-    assert cb._check("o", rate_lo, 100.0, 400.0)[0]  # under abs floor
-    assert not cb._check("o", rate_lo, 100.0, 600.0)[0]
-
-
-def test_compare_baseline_liveness_floor():
-    """Warn-only serving rates absorb noise but hard-fail when the rate
-    collapses below the absolute liveness floor (hung-kernel detector),
-    and the fused-vs-vmap speedup row gates at the 1.5x acceptance."""
-    import importlib.util
-    import pathlib
-
-    spec = importlib.util.spec_from_file_location(
-        "compare_baseline",
-        pathlib.Path(__file__).parent.parent / "benchmarks"
-        / "compare_baseline.py")
-    cb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(cb)
-
-    warn = dict(higher_is_better=True, rel_tol=0.30, warn_only=True,
-                live_floor=1.0)
-    ok, msg = cb._evaluate("w", warn, 100.0, 50.0)   # noisy but alive
-    assert ok and "warn-only" in msg
-    ok, msg = cb._evaluate("w", warn, 100.0, 0.0)    # collapsed
-    assert not ok and "liveness" in msg
-    ok, msg = cb._evaluate("w", warn, 100.0, float("nan"))
-    assert not ok and "liveness" in msg
-    assert cb._evaluate("w", warn, 100.0, 120.0)[0]  # healthy
-    # the floor is unconditional: even against a baseline that itself
-    # drifted near the floor (relative band satisfied), a collapsed
-    # rate fails
-    ok, msg = cb._evaluate("w", warn, 1.2, 0.95)
-    assert not ok and "liveness" in msg
-
-    # the SPEC rows the satellite is about actually carry the floor
-    assert cb.SPEC["serve/bucketed:req_s"]["live_floor"] > 0
-    assert cb.SPEC["serve/shared_batch:speedup"]["live_floor"] > 0
-    fused = cb.SPEC["serve/fused_vs_vmap:speedup"]
-    assert not fused.get("warn_only")          # gating, not warn-only
-    assert fused["abs_floor"] == 1.5           # the acceptance bar
-    # >=1.5x passes even against a drifted-high baseline; below both
-    # the band and the floor fails
-    assert cb._evaluate("f", fused, 8.0, 1.6)[0]
-    assert not cb._evaluate("f", fused, 8.0, 1.2)[0]

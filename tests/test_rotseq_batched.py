@@ -208,22 +208,25 @@ def test_registry_routes_staircase_to_fused_on_tpu():
 
 
 def test_interpolation_respects_liveness_class():
-    """A measured plane-skipping plan keyed with a live-plane count must
-    not transfer at distance 0 to the dense grid of the same shape (and
-    vice versa) — liveness is part of the interpolation class; nearby
-    live-annotated problems may still borrow it."""
+    """A live-annotated problem and the dense grid of the same shape
+    never share a memo entry: a plan planted under the live key leaves
+    the dense plan the model's, and the reverse."""
     clear_plan_cache()
-    p_live = registry.Problem(m=4096, n=96, k=102, platform="tpu",
-                              live_planes=95 * 8)
-    key = registry._plan_key(p_live)
-    registry._PLAN_CACHE[key] = registry.Plan(
-        "rotseq_batched", m_blk=256, est_seconds=1e-5, source="measured")
-    dense = select_plan(4096, 96, 102, platform="tpu")
-    assert dense.method != "rotseq_batched"
-    near = select_plan(4096, 96, 102, platform="tpu",
-                       live_planes=95 * 10)
-    assert near.method == "rotseq_batched"
-    assert near.source == "interpolated"
+    dense_model = select_plan(4096, 96, 102, platform="tpu")
+    live_model = select_plan(4096, 96, 102, platform="tpu",
+                             live_planes=95 * 8)
+    assert dense_model.method != live_model.method
+    planted = registry.Plan("unoptimized", est_seconds=1e-5)
+    for live in (95 * 8, None):
+        clear_plan_cache()
+        p = registry.Problem(m=4096, n=96, k=102, platform="tpu",
+                             live_planes=live)
+        registry._PLAN_CACHE[registry._plan_key(p)] = planted
+        assert select_plan(4096, 96, 102, platform="tpu") == (
+            planted if live is None else dense_model)
+        assert select_plan(4096, 96, 102, platform="tpu",
+                           live_planes=95 * 8) == (
+            planted if live is not None else live_model)
     clear_plan_cache()
 
 

@@ -3,8 +3,9 @@
 The acceptance contract: streamed results are **bit-identical** to
 synchronous ``RotationService`` drains (plain/signed/reflector, mixed
 shapes) because both run the same ``assemble_batch``/``execute_batch``
-code path; each bucket is planned exactly once (warm-startable from the
-serialized store); the close policy fires on size *or* age; the
+code path; each bucket is planned exactly once (and a second engine in
+the process finds its plans in the registry's memo); the close policy
+fires on size *or* age; the
 backpressure policies block / fail / shed as selected; weighted
 round-robin keeps a cold bucket from starving behind a hot one; and a
 graceful shutdown drains every queued request.
@@ -47,8 +48,8 @@ def test_stream_bitwise_equals_sync_mixed_shapes():
     """Streamed == synchronous RotationService, bit for bit, across the
     canonical mixed-shape stream (odd count: partial buckets drain)."""
     requests = synthetic_stream(14, seed=5)
-    refs = RotationService(slots=4, store=False).apply_many(requests)
-    eng = StreamEngine(slots=4, store=False)
+    refs = RotationService(slots=4).apply_many(requests)
+    eng = StreamEngine(slots=4)
     outs = _run_stream(eng, requests)
     for ref, out in zip(refs, outs):
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
@@ -73,8 +74,8 @@ def test_stream_bitwise_signed_and_reflector():
             seq = RotationSequence(seq.cos, seq.sin, None, True)
         requests.append((seq, A))
     refs = [seq.plan(like=A).apply(A) for seq, A in requests]
-    sync = RotationService(slots=4, store=False).apply_many(requests)
-    outs = _run_stream(StreamEngine(slots=4, store=False), requests)
+    sync = RotationService(slots=4).apply_many(requests)
+    outs = _run_stream(StreamEngine(slots=4), requests)
     for ref, s, out in zip(refs, sync, outs):
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
         np.testing.assert_array_equal(np.asarray(s), np.asarray(out))
@@ -86,7 +87,7 @@ def test_age_close_fires_on_partial_bucket():
     """A partial bucket must not wait for slots to fill: the age policy
     closes it once the oldest request exceeds the bucket target."""
     requests = synthetic_stream(3, shapes=((16, 32, 8),), seed=1)
-    eng = StreamEngine(slots=8, store=False, min_age_s=0.001)
+    eng = StreamEngine(slots=8, min_age_s=0.001)
     tickets = [eng.submit(seq, A) for seq, A in requests]
     # no close(): the age policy alone must complete the requests
     for t in tickets:
@@ -101,7 +102,7 @@ def test_age_target_scales_with_cost_model():
     """The per-bucket age target derives from the §6-modeled batch
     seconds once the bucket is planned, clamped to [min, max]."""
     requests = synthetic_stream(8, shapes=((16, 32, 8),), seed=2)
-    eng = StreamEngine(slots=8, store=False, start=False,
+    eng = StreamEngine(slots=8, start=False,
                        min_age_s=0.004, max_age_s=0.2, age_factor=8.0)
     key = eng.service._bucket_key(*requests[0])
     assert eng._age_target(key) == eng.min_age_s  # unplanned: floor
@@ -118,7 +119,7 @@ def test_weighted_round_robin_serves_cold_bucket():
     """Deterministic WRR check on the scheduler policy itself: with a
     hot bucket (3 batches queued) and a cold full bucket, the cold
     bucket is served within ``max_burst`` consecutive hot closes."""
-    eng = StreamEngine(slots=4, store=False, start=False, max_burst=2)
+    eng = StreamEngine(slots=4, start=False, max_burst=2)
     hot = synthetic_stream(12, shapes=((16, 32, 8),), seed=3)
     cold = synthetic_stream(4, shapes=((16, 64, 12),), seed=4)
     for seq, A in hot + cold:
@@ -137,7 +138,7 @@ def test_weighted_round_robin_serves_cold_bucket():
 def test_fairness_hot_and_cold_end_to_end():
     """A single cold request completes (age close + WRR) while a hot
     bucket keeps the engine saturated — no starvation, no shedding."""
-    eng = StreamEngine(slots=4, store=False, min_age_s=0.001)
+    eng = StreamEngine(slots=4, min_age_s=0.001)
     hot = synthetic_stream(32, shapes=((16, 32, 8),), seed=6)
     (cold_seq, cold_A), = synthetic_stream(1, shapes=((16, 64, 12),),
                                            seed=7)
@@ -156,7 +157,7 @@ def test_fairness_hot_and_cold_end_to_end():
 # ------------------------------------------------ backpressure policies ----
 
 def test_backpressure_fail_policy_rejects():
-    eng = StreamEngine(slots=4, store=False, start=False, max_pending=2,
+    eng = StreamEngine(slots=4, start=False, max_pending=2,
                        backpressure="fail")
     requests = synthetic_stream(3, shapes=((8, 16, 4),), seed=8)
     eng.submit(*requests[0])
@@ -172,7 +173,7 @@ def test_backpressure_fail_policy_rejects():
 def test_backpressure_shed_policy_drops_expired():
     """Under pressure the shed policy fails queued past-deadline tickets
     (DeadlineExceeded) to admit new work; unexpired requests survive."""
-    eng = StreamEngine(slots=4, store=False, start=False, max_pending=3,
+    eng = StreamEngine(slots=4, start=False, max_pending=3,
                        backpressure="shed")
     requests = synthetic_stream(5, shapes=((8, 16, 4),), seed=9)
     doomed = [eng.submit(*requests[i], deadline_s=0.0) for i in range(2)]
@@ -196,7 +197,7 @@ def test_backpressure_shed_policy_drops_expired():
 def test_backpressure_block_policy_waits_for_room():
     """submit() under the block policy stalls until the scheduler frees
     budget — every request is eventually admitted and served."""
-    eng = StreamEngine(slots=2, store=False, max_pending=2,
+    eng = StreamEngine(slots=2, max_pending=2,
                        backpressure="block", min_age_s=0.001)
     requests = synthetic_stream(7, shapes=((8, 16, 4),), seed=10)
     outs = _run_stream(eng, requests)
@@ -212,12 +213,12 @@ def test_graceful_shutdown_drains_everything():
     """close(drain=True) flushes every queued request — including
     partial buckets — through the normal batch path."""
     requests = synthetic_stream(11, seed=11)  # 3 buckets, none full
-    eng = StreamEngine(slots=8, store=False, min_age_s=5.0,
+    eng = StreamEngine(slots=8, min_age_s=5.0,
                        max_age_s=10.0)  # age close effectively off
     tickets = [eng.submit(seq, A) for seq, A in requests]
     eng.close(drain=True)
     assert all(t.done() for t in tickets)
-    refs = RotationService(slots=8, store=False).apply_many(requests)
+    refs = RotationService(slots=8).apply_many(requests)
     for ref, t in zip(refs, tickets):
         np.testing.assert_array_equal(np.asarray(ref),
                                       np.asarray(t.result()))
@@ -225,7 +226,7 @@ def test_graceful_shutdown_drains_everything():
 
 
 def test_close_without_drain_fails_pending_tickets():
-    eng = StreamEngine(slots=8, store=False, start=False)
+    eng = StreamEngine(slots=8, start=False)
     tickets = [eng.submit(seq, A)
                for seq, A in synthetic_stream(3, shapes=((8, 16, 4),))]
     eng.close(drain=False)
@@ -238,7 +239,7 @@ def test_close_without_drain_fails_pending_tickets():
 
 def test_context_manager_drains_on_exit():
     requests = synthetic_stream(5, shapes=((16, 32, 8),), seed=12)
-    with StreamEngine(slots=4, store=False) as eng:
+    with StreamEngine(slots=4) as eng:
         tickets = [eng.submit(seq, A) for seq, A in requests]
     assert all(t.done() for t in tickets)
 
@@ -252,7 +253,7 @@ def test_plans_resolved_exactly_once_per_bucket():
     misses0 = plan_cache_stats()["misses"]
     with obs.override(True):
         obs.reset()
-        eng = StreamEngine(slots=4, store=False)
+        eng = StreamEngine(slots=4)
         outs = _run_stream(eng, requests)
         snap = obs.snapshot()
     c = snap["counters"]
@@ -267,36 +268,36 @@ def test_plans_resolved_exactly_once_per_bucket():
     assert lat["p99"] >= lat["p50"] > 0
 
 
-def test_stream_warm_start_zero_resolutions(tmp_path):
-    """A restarted engine warm-binds every bucket plan from the
-    serialized store: zero registry resolutions, identical bits."""
-    store = str(tmp_path / "serve_plans.json")
+def test_stream_warm_start_zero_resolutions():
+    """A second engine in the same process finds every bucket plan in
+    the registry's memo: zero registry resolutions, identical bits."""
+    clear_plan_cache()
     requests = synthetic_stream(12, seed=14)
-    cold = StreamEngine(slots=4, store=store)
+    cold = StreamEngine(slots=4)
     outs = _run_stream(cold, requests)
     assert cold.service.stats["plans_resolved"] == 3
 
-    clear_plan_cache()
     with obs.override(True):
         obs.reset()
-        warm = StreamEngine(slots=4, store=store)
+        warm = StreamEngine(slots=4)
         outs2 = _run_stream(warm, requests)
         counters = obs.snapshot()["counters"]
-    assert counters.get("serve.plans_resolved", 0) == 0
-    assert counters.get("serve.warm_plans", 0) == 3
     assert counters.get("registry.plan_cache.misses", 0) == 0
+    assert counters.get("registry.plan_cache.hits", 0) == 3
     for a, b in zip(outs, outs2):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    obs.reset()
+    clear_plan_cache()
 
 
 def test_engine_rejects_bad_arguments():
     with pytest.raises(ValueError, match="backpressure"):
-        StreamEngine(store=False, backpressure="drop", start=False)
+        StreamEngine(backpressure="drop", start=False)
     with pytest.raises(ValueError, match="max_pending"):
-        StreamEngine(store=False, max_pending=0, start=False)
-    svc = RotationService(slots=2, store=False)
+        StreamEngine(max_pending=0, start=False)
+    svc = RotationService(slots=2)
     with pytest.raises(ValueError, match="service_kw"):
-        StreamEngine(svc, store=False, start=False)
+        StreamEngine(svc, pad_waves=False, start=False)
     eng = StreamEngine(svc, start=False)
     with pytest.raises(ValueError, match="2D"):
         eng.submit(random_sequence(jax.random.key(0), 16, 4),

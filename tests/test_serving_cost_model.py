@@ -4,11 +4,9 @@ The acceptance bucket throughout is the serving benchmark's
 (m=16, n=32) shape with requests recorded at k_req=5 and padded to
 k_pad=8, so ``live_planes = 31*5 = 155`` of ``planes_total = 31*8 =
 248``.  The expected backend flips are the ones docs/cost-model.md
-derives; they are pure cost-model arithmetic (no autotune, no JAX
-dispatch), so the assertions are exact, not statistical.
+derives; they are pure cost-model arithmetic (no JAX dispatch), so the
+assertions are exact, not statistical.
 """
-import dataclasses as _dc
-
 import pytest
 
 from repro.core import registry
@@ -101,7 +99,7 @@ def test_modeled_prediction_cliff_is_at_least_5x():
 
 
 # ------------------------------------------------------------- cache keys
-def test_per_request_key_is_distinct_and_round_trips(tmp_path):
+def test_per_request_key_is_distinct_and_round_trips():
     clear_plan_cache()
     shared = _bucket_plan(64, True, "tpu")
     per_req = _bucket_plan(64, False, "tpu")
@@ -111,19 +109,14 @@ def test_per_request_key_is_distinct_and_round_trips(tmp_path):
     (pkey,) = keys
     assert registry._PLAN_CACHE[pkey] == per_req
     assert per_req != shared
-
-    # round-trip through the persisted store: the marker must survive
-    # JSON (lists -> tuples) and come back as the same class
-    registry._PLAN_CACHE[pkey] = _dc.replace(per_req, source="measured")
-    path = str(tmp_path / "plans.json")
-    assert registry.save_plan_cache(path) == path
-    clear_plan_cache()
-    assert registry.load_plan_cache(path) >= 1
-    restored = registry._PLAN_CACHE[pkey]
-    assert restored.method == per_req.method
-    assert restored.source == "persisted"
-    # and select_plan finds it as a hit, not a re-resolution
-    assert _bucket_plan(64, False, "tpu") == restored
+    # the same problem maps to the same key, and select_plan finds it
+    # as a hit, not a re-resolution
+    prob = Problem(m=M, n=N, k=K_PAD, dtype="float32", platform="tpu",
+                   batch=64, shared_sequence=False, live_planes=LIVE)
+    assert registry._plan_key(prob) == pkey
+    hits0 = registry.plan_cache_stats()["hits"]
+    assert _bucket_plan(64, False, "tpu") is per_req
+    assert registry.plan_cache_stats()["hits"] == hits0 + 1
 
 
 def test_batch_one_shares_the_legacy_key():
@@ -132,35 +125,27 @@ def test_batch_one_shares_the_legacy_key():
     assert all("per_req" not in k for k in registry._PLAN_CACHE)
 
 
-# ---------------------------------------------------------- interpolation
-def _seed_measured(batch, shared, method):
-    """Plant a measured plan for the acceptance bucket in the cache."""
-    prob = Problem(m=M, n=N, k=K_PAD, dtype="float32", platform="tpu",
-                   batch=batch, shared_sequence=shared, live_planes=LIVE)
-    key = registry._plan_key(prob)
-    registry._PLAN_CACHE[key] = Plan(method=method, est_seconds=1e-6,
-                                     source="measured")
-
-
+# ------------------------------------------------------------ memo keys
 def test_interpolation_never_crosses_the_ownership_class():
-    # a measured per-request plan at distance 0 must NOT be borrowed by
-    # the shared twin (and vice versa): the classes differ like dense
-    # vs live-annotated
-    clear_plan_cache()
-    _seed_measured(64, False, "unoptimized")
-    shared = _bucket_plan(64, True, "tpu")
-    assert shared.source == "model"
+    """A per-request bucket and its shared-sequence twin never share a
+    memo entry: planting another backend under one key leaves the
+    other's plan the model's."""
+    def _plant(batch, shared, method):
+        prob = Problem(m=M, n=N, k=K_PAD, dtype="float32", platform="tpu",
+                       batch=batch, shared_sequence=shared,
+                       live_planes=LIVE)
+        registry._PLAN_CACHE[registry._plan_key(prob)] = Plan(
+            method=method, est_seconds=1e-6)
 
     clear_plan_cache()
-    _seed_measured(64, True, "accumulated")
-    per_req = _bucket_plan(64, False, "tpu")
-    assert per_req.source == "model"
+    model_shared = _bucket_plan(64, True, "tpu")
+    model_per_req = _bucket_plan(64, False, "tpu")
 
-
-def test_interpolation_transfers_within_the_per_request_class():
     clear_plan_cache()
-    _seed_measured(64, False, "rotseq_batched")
-    near = select_plan(M, N, K_PAD, dtype="float32", platform="tpu",
-                       batch=32, shared_sequence=False, live_planes=LIVE)
-    assert near.source == "interpolated"
-    assert near.method == "rotseq_batched"
+    _plant(64, False, "unoptimized")
+    assert _bucket_plan(64, True, "tpu") == model_shared
+
+    clear_plan_cache()
+    _plant(64, True, "unoptimized")
+    assert _bucket_plan(64, False, "tpu") == model_per_req
+    clear_plan_cache()
