@@ -14,8 +14,8 @@ the tile, not a gather.  On a TPU a Pallas kernel keeps each tile's
 factor in VMEM for all its steps (float32); elsewhere an XLA loop runs
 the same schedule over all tiles at once.  The loop's step is a few XLA
 ops over the whole ``T x 2k_b x w`` window, which costs ~73 µs on a TPU
-v5e at 96/96 tiles against ~0.2 µs per tile and anti-diagonal in the
-kernel (PERF.md, section 5).
+v5e at 96/96 tiles, against ~148 ns per tile and anti-diagonal in the
+kernel when it builds one tile per grid step (PERF.md, section 5).
 
 On CPU (the paper) this trades ~4/3 more flops for MKL GEMM throughput and
 only wins for large matrices.  On TPU it is the *natural* formulation: the
@@ -45,13 +45,14 @@ share these helpers (``accumulated``, ``pallas_mxu``) read alike.
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro import compat
+from repro import compat, obs
 from repro.kernels.factors import tile_factors_pallas
-from repro.kernels.limits import LANES, SUBLANES, round_up
+from repro.kernels.limits import factor_tile_dims, factor_tiles_per_step
 
 from .blocked import (ACCUMULATE, PACK, SWEEP, _band_inputs, _shear,
                       num_tiles, pack_sheared)
@@ -60,8 +61,12 @@ from .rotations import plane_update
 __all__ = [
     "accumulate_tile_factors",
     "apply_band_accumulated",
+    "record_factor_schedule",
     "rot_sequence_accumulated",
 ]
+
+# The backends whose tile factors come from accumulate_tile_factors.
+FACTOR_BACKENDS = ("accumulated", "pallas_mxu")
 
 
 def _diagonals(X, fill, steps: int, lanes: int):
@@ -124,19 +129,52 @@ def _factors_loop(Ct, St, Gt, dtype):
     return jnp.swapaxes(Qt[:, k_b - 1:k_b - 1 + w], 1, 2)
 
 
-def _factors_kernel(Ct, St, Gt, *, interpret: bool = False):
+def _factors_kernel(Ct, St, Gt, *, interpret: bool = False,
+                    tiles: Optional[int] = None):
     """The schedule as a Pallas kernel over float32 factors: the waves
     sheared by anti-diagonal, each tile's columns built split by parity
-    (``kernels/factors.py``), then interleaved."""
+    (``kernels/factors.py``), then interleaved.
+
+    The kernel builds ``tiles`` tiles per grid step, by default
+    :func:`~repro.kernels.limits.factor_tiles_per_step`'s choice; no-op
+    tiles pad ``T`` to a multiple of it, and their factors are dropped.
+    """
     T, n_b, k_b = Ct.shape
-    steps = round_up(n_b + k_b - 1, SUBLANES)
-    lanes = round_up(k_b, LANES)
-    C, S, G = (_diagonals(X.astype(jnp.float32), fill, steps, lanes)
+    B = tiles or factor_tiles_per_step(T, n_b, k_b)
+    pad = ((0, -T % B), (0, 0), (0, 0))
+    steps, lanes, _, _ = factor_tile_dims(n_b, k_b)
+    C, S, G = (_diagonals(jnp.pad(X.astype(jnp.float32), pad,
+                                  constant_values=fill),
+                          fill, steps, lanes)
                for X, fill in ((Ct, 1), (St, 0), (Gt, -1)))
-    E, O = tile_factors_pallas(C, S, G, n_b=n_b, k_b=k_b,
+    E, O = tile_factors_pallas(C, S, G, n_b=n_b, k_b=k_b, tiles=B,
                                interpret=interpret)
-    Q = jnp.stack([E, O], axis=3).reshape(T, n_b + k_b, -1)
+    Q = jnp.stack([E[:T], O[:T]], axis=3).reshape(T, n_b + k_b, -1)
     return Q[:, :, k_b - 1:k_b - 1 + n_b + k_b]
+
+
+def _takes_kernel(dtype) -> bool:
+    return compat.is_tpu() and jnp.dtype(dtype) == jnp.float32
+
+
+def record_factor_schedule(method: str, kwargs: dict, n: int, k: int,
+                           dtype) -> None:
+    """At plan time, with obs on: the factor kernel's tiles per grid
+    step as the gauge ``kernels.rotseq_factors.tiles_per_step``, and
+    the no-op tiles that pad one application's bands to whole steps in
+    the counter ``kernels.rotseq_factors.padded_tiles``.  Nothing where
+    the plan builds no factor with the kernel."""
+    if not (obs.enabled() and method in FACTOR_BACKENDS
+            and _takes_kernel(dtype)):
+        return
+    # the backends' defaults and clip (rot_sequence_accumulated's)
+    n_b = min(kwargs.get("n_b", 128), max(8, n))
+    k_b = kwargs.get("k_b", 128)
+    T = num_tiles(n, n_b, k_b)
+    B = factor_tiles_per_step(T, n_b, k_b)
+    bands = -(-k // k_b)
+    obs.gauge("kernels.rotseq_factors.tiles_per_step", B)
+    obs.inc("kernels.rotseq_factors.padded_tiles", (-T % B) * bands)
 
 
 def accumulate_tile_factors(Ct, St, Gt, *, dtype=jnp.float32):
@@ -157,7 +195,7 @@ def accumulate_tile_factors(Ct, St, Gt, *, dtype=jnp.float32):
     dtypes, from an XLA loop of the same schedule (two anti-diagonals per
     step over all tiles at once).
     """
-    if compat.is_tpu() and jnp.dtype(dtype) == jnp.float32:
+    if _takes_kernel(dtype):
         return _factors_kernel(Ct, St, Gt)
     return _factors_loop(Ct, St, Gt, dtype)
 

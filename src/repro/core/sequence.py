@@ -411,6 +411,9 @@ class RotationSequence:
         Explicit ``n_b``/``k_b`` always override the planned tiles.
         """
         _ensure_backends()
+        # imported here: accumulate's kernel imports this module
+        from repro.core.accumulate import record_factor_schedule
+
         like_shape = getattr(like, "shape", None)
         if like_shape is not None and len(like_shape) == 3:
             if batch is None:
@@ -447,6 +450,7 @@ class RotationSequence:
             if k_b is not None:
                 planned["k_b"] = k_b
             planned.update(kw)
+            record_factor_schedule(plan.method, planned, n, k, dtype)
             return SequencePlan(self, plan.method,
                                 tuple(sorted(planned.items())), plan)
 
@@ -460,6 +464,7 @@ class RotationSequence:
         if k_b is not None:
             tiles["k_b"] = k_b
         planned = {**tiles, **kw}
+        record_factor_schedule(method, planned, n, k, dtype)
         return SequencePlan(self, method, tuple(sorted(planned.items())),
                             None)
 

@@ -49,6 +49,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import compat, obs
 from repro.core import registry
+from repro.core.accumulate import record_factor_schedule
 from repro.core.sequence import (RotationSequence, SequencePlan,
                                  planned_apply, planned_apply_batched,
                                  planned_run, stack_request_waves)
@@ -164,6 +165,7 @@ def plan_sharded(seq: RotationSequence, like=None, *, mesh,
         if k_b is not None:
             planned["k_b"] = k_b
         planned.update(kw)
+        record_factor_schedule(method, planned, n, k, dtype)
         return ShardedSequencePlan(
             sequence=seq, mesh=mesh, row_axes=_as_tuple(row_axes),
             method=method, kwargs=tuple(sorted(planned.items())),
@@ -196,6 +198,7 @@ def plan_sharded(seq: RotationSequence, like=None, *, mesh,
     if k_b is not None:
         planned["k_b"] = k_b
     planned.update(kw)
+    record_factor_schedule(chosen.method, planned, n, k, dtype)
     return ShardedSequencePlan(
         sequence=seq, mesh=mesh, row_axes=_as_tuple(row_axes),
         method=chosen.method, kwargs=tuple(sorted(planned.items())),

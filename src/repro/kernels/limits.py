@@ -18,7 +18,9 @@ from __future__ import annotations
 
 __all__ = [
     "SUBLANES", "LANES", "SMEM_BYTES", "SMEM_PANEL_BUDGET",
-    "round_up", "clamp_m_blk", "smem_panel_bytes",
+    "FACTOR_VMEM_BUDGET", "FACTOR_TILES_PER_STEP", "FACTOR_PAD_SHARE",
+    "round_up", "clamp_m_blk", "smem_panel_bytes", "factor_tile_dims",
+    "factor_tile_bytes", "factor_tiles_per_step",
 ]
 
 # TPU sublane count: block shapes keep the second-minor dimension a
@@ -39,6 +41,20 @@ SMEM_BYTES = 2**20
 # exactly this bound and refuses n=344 by 12 KB.  It also bounds the
 # kernel's VMEM: n <= 337 keeps an (n, 256) float32 slab under 345 KB.
 SMEM_PANEL_BUDGET = SMEM_BYTES - 16 * 2**10
+
+# VMEM the rotseq_factors kernel's double-buffered blocks may take: 12
+# MiB of the 16 MiB that Mosaic scopes for a kernel on a v5e by default,
+# the rest left to the loop's temporaries.
+FACTOR_VMEM_BUDGET = 12 * 2**20
+
+# Tiles the rotseq_factors kernel may build per grid step, largest first.
+FACTOR_TILES_PER_STEP = (8, 4, 2, 1)
+
+# The no-op tiles that pad a band of T tiles to whole grid steps stay
+# within T / FACTOR_PAD_SHARE: past two tiles a step, doubling the tiles
+# saved at most ~11% of a tile's issue slots in the loop body as Mosaic
+# schedules it for a v5e (64/32, 4 -> 8 tiles; PERF.md, section 6).
+FACTOR_PAD_SHARE = 8
 
 
 def round_up(x: int, mult: int) -> int:
@@ -67,3 +83,35 @@ def smem_panel_bytes(J: int, K: int) -> int:
     """
     return (3 * 2 * round_up(max(1, J), SUBLANES)
             * round_up(max(1, K), LANES) * 4)
+
+
+def factor_tile_dims(n_b: int, k_b: int):
+    """``(steps, lanes, w, P)`` of one tile in the factor kernel: its
+    waves are ``(steps, lanes)`` (anti-diagonals rounded up to a sublane
+    tile, waves to a lane tile), each parity of its factor ``(w, P)``."""
+    half = -(-(n_b + k_b - 1) // 2)
+    return (round_up(n_b + k_b - 1, SUBLANES), round_up(k_b, LANES),
+            n_b + k_b, round_up(half + k_b, LANES))
+
+
+def factor_tile_bytes(n_b: int, k_b: int) -> int:
+    """VMEM one tile's blocks take in the factor kernel: three float32
+    wave blocks in, two factor blocks out, each double-buffered."""
+    steps, lanes, w, P = factor_tile_dims(n_b, k_b)
+    return 2 * 4 * (3 * steps * lanes + 2 * w * P)
+
+
+def factor_tiles_per_step(T: int, n_b: int, k_b: int) -> int:
+    """Tiles per grid step of the factor kernel, for ``T`` tiles.
+
+    One tile's anti-diagonals form a chain of dependent steps; the tiles
+    of one grid step run their chains side by side.  The most tiles of
+    :data:`FACTOR_TILES_PER_STEP` win whose blocks fit
+    :data:`FACTOR_VMEM_BUDGET` and whose last step pads at most ``T /
+    FACTOR_PAD_SHARE`` tiles, never more than ``T``: 8 for 16 or 1025
+    tiles, 4 for 41, where 8 would pad 7.
+    """
+    fit = [b for b in FACTOR_TILES_PER_STEP
+           if b <= max(1, T) and -T % b * FACTOR_PAD_SHARE <= T
+           and b * factor_tile_bytes(n_b, k_b) <= FACTOR_VMEM_BUDGET]
+    return fit[0] if fit else 1

@@ -1,9 +1,10 @@
 """Tile factors accumulated one anti-diagonal per step equal the tile
 applied plane by plane to an identity, both as the XLA loop and as the
-Pallas kernel (interpreted here), and each runs one loop of
-``ceil((n_b + k_b - 1) / 2)`` steps, two anti-diagonals each.  The
-sheared tiles they start from are packed without a gather, and equal
-the gathered ones bit for bit."""
+Pallas kernel (interpreted here) at every number of tiles per grid
+step, and each runs one loop of ``ceil((n_b + k_b - 1) / 2)`` steps,
+two anti-diagonals each.  The rule that picks the tiles per step stays
+within its VMEM budget.  The sheared tiles the schedules start from are
+packed without a gather, and equal the gathered ones bit for bit."""
 from functools import partial
 
 import jax
@@ -15,6 +16,9 @@ from repro import compat
 from repro.core import accumulate
 from repro.core.accumulate import accumulate_tile_factors
 from repro.core.blocked import apply_tile, num_tiles, pack_sheared
+from repro.kernels.limits import (FACTOR_PAD_SHARE, FACTOR_TILES_PER_STEP,
+                                  FACTOR_VMEM_BUDGET, factor_tile_bytes,
+                                  factor_tile_dims, factor_tiles_per_step)
 
 SHAPES = [(3, 16, 16), (2, 8, 4), (4, 24, 8), (2, 8, 1), (3, 5, 9),
           (2, 64, 64)]
@@ -58,6 +62,55 @@ def test_factors_equal_plane_by_plane(T, n_b, k_b, signs, schedule):
         want = _plane_by_plane(Ct, St, Gt)
         assert got.shape == (T, n_b + k_b, n_b + k_b)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# small-T copies of the benchmark's tiles (n3840's 96/96, n960's 64/64,
+# a four-chip shard's 64/32), and tile counts that no block size divides
+BLOCK_SHAPES = [(3, 96, 96), (3, 64, 64), (5, 64, 32), (5, 8, 4),
+                (3, 5, 9), (7, 16, 16)]
+
+
+@pytest.mark.parametrize("tiles", [1, 2, 4, 8])
+@pytest.mark.parametrize("signs", ["rotations", "reflectors", "mixed"])
+@pytest.mark.parametrize("T,n_b,k_b", BLOCK_SHAPES)
+def test_tiles_per_step_keep_the_factors(T, n_b, k_b, signs, tiles):
+    """However many tiles a grid step builds, whether or not they divide
+    ``T``, the kernel's factors are the plane-by-plane ones bit for bit:
+    the no-op tiles that pad the last step never reach ``Q``."""
+    run = jax.jit(partial(accumulate._factors_kernel, interpret=True,
+                          tiles=tiles))
+    for Ct, St, Gt in _bands(T, n_b, k_b, signs):
+        got = run(Ct, St, Gt)
+        assert got.shape == (T, n_b + k_b, n_b + k_b)
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(_plane_by_plane(Ct, St, Gt)))
+
+
+@pytest.mark.parametrize("n_b,k_b", [(128, 128), (96, 96), (64, 64),
+                                     (32, 32), (16, 16), (8, 8), (64, 16),
+                                     (64, 32), (5, 9), (256, 256)])
+def test_tiles_per_step_rule_is_bounded(n_b, k_b):
+    """The rule offers a block size of the set, never more than the
+    tiles there are, one whose last step pads at most an eighth of them,
+    and one whose double-buffered blocks fit the VMEM budget unless even
+    one tile's do not."""
+    one = factor_tile_bytes(n_b, k_b)
+    steps, lanes, w, P = factor_tile_dims(n_b, k_b)
+    assert one == 2 * 4 * (3 * steps * lanes + 2 * w * P)
+    for T in [1, 2, 3, 5, 7, 8, 9, 16, 41, 1025]:
+        B = factor_tiles_per_step(T, n_b, k_b)
+        assert B in FACTOR_TILES_PER_STEP and B <= T
+        assert -T % B * FACTOR_PAD_SHARE <= T
+        assert B * one <= FACTOR_VMEM_BUDGET or B == 1
+
+
+def test_tiles_per_step_rule_at_the_cells_tiles():
+    """n3840's band of 41 tiles of 96/96 (8 a step would pad 7), n960's
+    16 of 64/64, and a four-chip shard's 1025 of 64/32."""
+    assert factor_tile_bytes(96, 96) == 1376256
+    assert factor_tiles_per_step(41, 96, 96) == 4
+    assert factor_tiles_per_step(16, 64, 64) == 8
+    assert factor_tiles_per_step(1025, 64, 32) == 8
 
 
 def _eqns(jaxpr, depth=0):

@@ -25,7 +25,8 @@ from repro.core.accumulate import _factors_kernel, rot_sequence_accumulated
 from repro.core.blocked import num_tiles
 from repro.core.sequence import RotationSequence
 from repro.dist import rot_sequence_column_sharded_padded
-from repro.kernels.limits import SMEM_PANEL_BUDGET, smem_panel_bytes
+from repro.kernels.limits import (SMEM_PANEL_BUDGET, factor_tiles_per_step,
+                                  smem_panel_bytes)
 from repro.kernels.rotseq.ops import rot_sequence_wave
 from repro.kernels.rotseq_batched.ops import rot_sequence_batched
 from repro.kernels.rotseq_mxu.ops import rot_sequence_mxu
@@ -110,6 +111,30 @@ def test_factor_kernel_compiles(one_chip, on_tpu, m, n, k):
                             A, C, S, n_b=b, k_b=b),
                         one_chip, (m, n), (n - 1, k), (n - 1, k)).as_text()
         assert text.count("rotseq_factors") >= -(-k // b)
+
+
+@pytest.mark.parametrize("T,n_b,k_b", [(41, 96, 96), (16, 64, 64),
+                                       (1025, 64, 32)])
+def test_factor_kernel_compiles_at_the_cells_tiles(one_chip, on_tpu, T,
+                                                   n_b, k_b):
+    """The factor kernel compiles with the rule's tiles per grid step at
+    the benchmark's tiles: n3840's band of 41 tiles, n960's of 16, and
+    one four-chip shard's band of 1025; its grid is that many steps
+    fewer."""
+    B = factor_tiles_per_step(T, n_b, k_b)
+    tile = jax.ShapeDtypeStruct((T, n_b, k_b), jnp.float32)
+
+    def grids(jaxpr):  # the kernel's call sits inside a jit
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e.params["grid_mapping"].grid
+            for v in e.params.values():
+                if isinstance(v, jax.extend.core.ClosedJaxpr):
+                    yield from grids(v.jaxpr)
+
+    jaxpr = jax.make_jaxpr(_factors_kernel)(tile, tile, tile).jaxpr
+    assert list(grids(jaxpr)) == [(-(-T // B),)]
+    _compile(_factors_kernel, one_chip, *[(T, n_b, k_b)] * 3)
 
 
 def test_column_sharded_factor_kernel_compiles(topo, on_tpu):

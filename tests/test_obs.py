@@ -228,6 +228,38 @@ def test_sharded_plan_and_in_place_apply_record_memory_and_donation(
     assert snap["gauges"]["dist.shard_bytes"] == 256 * 128 * 4
 
 
+@pytest.mark.parametrize("on_tpu", [True, False])
+def test_plans_record_the_factor_kernels_tiles_per_step(monkeypatch,
+                                                        on_tpu):
+    """A plan that builds its tile factors with the kernel records, at
+    plan time, the tiles per grid step it will run and the no-op tiles
+    that pad each band: 11 tiles of 64/32 at n=640 run 4 a step, so 1
+    pads each of 2 bands.  Single-device and sharded plans alike; a plan
+    off the chip, which builds its factors with the XLA loop, records
+    nothing."""
+    from repro import compat, dist
+    from repro.kernels.limits import factor_tiles_per_step
+
+    monkeypatch.setattr(compat, "is_tpu", lambda: on_tpu)
+    n, k = 640, 40
+    assert factor_tiles_per_step(11, 64, 32) == 4
+    A = jax.ShapeDtypeStruct((64, n), jnp.float32)
+    seq = random_sequence(jax.random.key(4), n, k)
+    mesh = compat.make_mesh((1,), ("data",))
+    for plan in (
+            lambda: seq.plan(like=A, method="accumulated", n_b=64, k_b=32),
+            lambda: dist.plan_sharded(seq, like=A, mesh=mesh,
+                                      method="accumulated", n_b=64,
+                                      k_b=32)):
+        obs.reset()
+        with obs.override(True):
+            plan()
+        snap = obs.snapshot()
+        gauge = snap["gauges"].get("kernels.rotseq_factors.tiles_per_step")
+        padded = snap["counters"].get("kernels.rotseq_factors.padded_tiles")
+        assert (gauge, padded) == ((4, 2) if on_tpu else (None, None))
+
+
 # ------------------------------------- plan-cache counters, exactly ----
 
 def test_plan_cache_counters_cold_warm_interpolated_upgrade():
