@@ -5,9 +5,10 @@
 
 One chip: the paper's experiment (k=180 waves on a 3840 x 3840 float32
 target) through ``method="auto"`` and the two Pallas kernels pinned, a
-``StreamEngine`` serving a few hundred requests over four buckets, and
-an n=1024 symmetric eigendecomposition whose eigenvectors accumulate
-through planned device applications.  ``--four-chips`` runs only the
+``StreamEngine`` serving a few hundred requests over four buckets, an
+n=1024 symmetric eigendecomposition whose eigenvectors accumulate
+through planned device applications, and one flush of real waves on a
+complex64 target.  ``--four-chips`` runs only the
 ``repro.dist`` paths on a 4-device mesh: a row-sharded batched bucket
 and the column-panel pipeline, each against its replicated result.
 
@@ -53,6 +54,9 @@ SERVE_SHAPES = ((256, 128, 32), (512, 128, 16), (1024, 256, 32),
                 (256, 128, 4))
 SERVE_REQUESTS = 320
 EIG_N = 1024
+# (m, n, k) of real waves applied to a complex64 target (ZSTEQR's
+# eigenvector update, one flush of k_delay=32 sweeps)
+COMPLEX_SHAPE = (1024, 1024, 32)
 # --four-chips: (b, m, n, k) of a shared-sequence bucket planned with
 # method="auto" and of a per-request serving bucket on the fused kernel,
 # and (m, n, k) of the column-panel pipeline
@@ -66,8 +70,11 @@ def log(phase: str, msg: str) -> None:
 
 
 def rel_err(x, ref) -> float:
-    x = np.asarray(x, np.float64)
-    ref = np.asarray(ref, np.float64)
+    """Largest modulus of ``x - ref`` over the largest of ``ref``; a
+    complex pair is compared in complex128."""
+    dt = np.complex128 if np.iscomplexobj(ref) else np.float64
+    x = np.asarray(x, dt)
+    ref = np.asarray(ref, dt)
     return float(np.abs(x - ref).max() / np.abs(ref).max())
 
 
@@ -309,6 +316,54 @@ def eig_phase(seed: int) -> None:
           EIG_TOL)
 
 
+def complex_phase(seed: int) -> None:
+    """Real waves on a complex64 target through ``method="auto"``: the
+    eigenvector update of a complex Hermitian eigensolver (LAPACK
+    ``ZSTEQR``), checked against the complex128 oracle.  The plan is
+    ``accumulated``, swept on the target's real view, with its factors
+    from the kernel and no complex dot in the program."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro import obs
+    from repro.core.ref import rot_sequence_numpy
+    from repro.core.rotations import random_sequence
+
+    m, n, k = COMPLEX_SHAPE
+    phase = f"complex m={m} n={n} k={k}"
+    key = jax.random.key(seed * 7919 + 5)
+    kr, ki, kw = jax.random.split(key, 3)
+    Z = jax.lax.complex(jax.random.normal(kr, (m, n), jnp.float32),
+                        jax.random.normal(ki, (m, n), jnp.float32))
+    seq = random_sequence(kw, n, k)
+    plan = seq.plan(like=Z, method="auto")
+    log(phase, f"plan auto -> {plan.method} tiles={dict(plan.kwargs)}")
+    if plan.method != "accumulated":
+        raise SystemExit(f"[{phase}] auto planned {plan.method!r} for a "
+                         f"complex64 target, not 'accumulated'")
+    obs.set_enabled(True)
+    obs.reset()
+    out, compiled, c_s, s_s = compile_and_time(plan.apply, Z)
+    plan.apply(Z)
+    views = obs.snapshot()["counters"].get("accumulate.complex_views", 0)
+    obs.set_enabled(False)
+    text = compiled.as_text()
+    require_kernel(phase, "auto", compiled)
+    if "rotseq_factors" not in text:
+        raise SystemExit(f"[{phase}] the factors are not the kernel's")
+    if [ln for ln in text.splitlines()
+            if (" dot(" in ln or " convolution(" in ln) and "c64" in ln]:
+        raise SystemExit(f"[{phase}] the program holds a complex dot")
+    if views != 1:
+        raise SystemExit(f"[{phase}] accumulate.complex_views read "
+                         f"{views} for one application")
+    log(phase, f"auto: compile {c_s:.2f} s; steady {s_s * 1e3:.2f} ms/call")
+    oracle = rot_sequence_numpy(np.asarray(Z), np.asarray(seq.cos),
+                                np.asarray(seq.sin))
+    check(phase, "auto vs complex128 oracle, max rel err",
+          rel_err(out, oracle), apply_tol(k))
+
+
 def four_chip_phase(seed: int) -> None:
     import jax
     import jax.numpy as jnp
@@ -409,6 +464,7 @@ def main() -> None:
         paper_phase(args.seed)
         serving_phase(args.seed)
         eig_phase(args.seed)
+        complex_phase(args.seed)
     log("done", f"all phases passed in {time.perf_counter() - t0:.1f} s")
     print(json.dumps({"ok": True, "device": device}))
 

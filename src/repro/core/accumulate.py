@@ -61,6 +61,7 @@ from .rotations import plane_update
 __all__ = [
     "accumulate_tile_factors",
     "apply_band_accumulated",
+    "factor_dtype",
     "record_factor_schedule",
     "rot_sequence_accumulated",
 ]
@@ -153,6 +154,18 @@ def _factors_kernel(Ct, St, Gt, *, interpret: bool = False,
     return Q[:, :, k_b - 1:k_b - 1 + n_b + k_b]
 
 
+def factor_dtype(dtype):
+    """The dtype of the tile factors for a target of ``dtype``.
+
+    The rotations are real, so the factors are too: a complex target
+    takes factors of its real part's dtype (float32 for complex64), and
+    a real target its own dtype."""
+    dtype = jnp.dtype(dtype)
+    if jnp.issubdtype(dtype, jnp.complexfloating):
+        return jnp.finfo(dtype).dtype
+    return dtype
+
+
 def _takes_kernel(dtype) -> bool:
     return compat.is_tpu() and jnp.dtype(dtype) == jnp.float32
 
@@ -163,9 +176,10 @@ def record_factor_schedule(method: str, kwargs: dict, n: int, k: int,
     step as the gauge ``kernels.rotseq_factors.tiles_per_step``, and
     the no-op tiles that pad one application's bands to whole steps in
     the counter ``kernels.rotseq_factors.padded_tiles``.  Nothing where
-    the plan builds no factor with the kernel."""
+    the plan builds no factor with the kernel.  ``dtype`` is the
+    target's."""
     if not (obs.enabled() and method in FACTOR_BACKENDS
-            and _takes_kernel(dtype)):
+            and _takes_kernel(factor_dtype(dtype))):
         return
     # the backends' defaults and clip (rot_sequence_accumulated's)
     n_b = min(kwargs.get("n_b", 128), max(8, n))
@@ -200,18 +214,33 @@ def accumulate_tile_factors(Ct, St, Gt, *, dtype=jnp.float32):
     return _factors_loop(Ct, St, Gt, dtype)
 
 
+def _real_view(X):
+    """A complex ``X`` ``(r, c)`` as the real ``(2r, c)``: its real part
+    above its imaginary part.  A real ``X`` as it is."""
+    if jnp.iscomplexobj(X):
+        return jnp.concatenate([X.real, X.imag], axis=0)
+    return X
+
+
 def apply_band_accumulated(A, Q, *, k_b: int):
     """Sweep one band using precomputed tile factors ``Q`` (T, w, w).
 
     The GEMMs run at ``Precision.HIGHEST``: at the default precision a
     TPU multiplies float32 operands in one bfloat16 pass, which falls
     far short of the float32 accuracy this backend promises.
+
+    A complex ``A`` is swept as its real view: ``A Q = Re(A) Q + i
+    Im(A) Q`` for the real ``Q``, so the tile-major copy stacks the
+    real rows above the imaginary ones, every GEMM is a real ``(2m, w)
+    @ (w, w)``, and the copy back rebuilds the complex result.  No dot
+    sees a complex operand, and no pass over ``A`` is added.
     """
     T, w, _ = Q.shape
     n_b = w - k_b
     m, n = A.shape
     carry0, fresh = _band_inputs(A, k_b, n_b, T)
-    fresh_tiles = fresh.reshape(m, T, n_b).transpose(1, 0, 2)
+    carry0, fresh = _real_view(carry0), _real_view(fresh)
+    fresh_tiles = fresh.reshape(-1, T, n_b).transpose(1, 0, 2)
 
     def step(carry, xs):
         q, ft = xs
@@ -221,7 +250,9 @@ def apply_band_accumulated(A, Q, *, k_b: int):
         return X[:, n_b:], X[:, :n_b]
 
     _, out = jax.lax.scan(step, carry0, (Q, fresh_tiles))
-    O = out.transpose(1, 0, 2).reshape(m, T * n_b)
+    O = out.transpose(1, 0, 2).reshape(-1, T * n_b)
+    if jnp.iscomplexobj(A):
+        O = jax.lax.complex(O[:m], O[m:])
     return jax.lax.slice_in_dim(O, k_b - 1, k_b - 1 + n, axis=1)
 
 
@@ -239,7 +270,8 @@ def rot_sequence_accumulated(A, C, S, *, n_b: int = 128, k_b: int = 128,
             Ct, St, Gt = pack_sheared(C, S, p0, k_b, n_b, T,
                                       reflect=reflect, G=G)
         with jax.named_scope(ACCUMULATE):
-            Q = accumulate_tile_factors(Ct, St, Gt, dtype=A.dtype)
+            Q = accumulate_tile_factors(Ct, St, Gt,
+                                        dtype=factor_dtype(A.dtype))
         with jax.named_scope(SWEEP):
             A = apply_band_accumulated(A, Q, k_b=k_b)
     return A

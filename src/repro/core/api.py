@@ -135,10 +135,12 @@ registry.register(BackendSpec(
 # Pallas kernels pad m to m_blk internally, so a shared-sequence batch
 # still flattens to (b*m, n); per-request wave batches fall back to a
 # per-element loop (supports_vmap=False) rather than vmapping pallas_call.
+# Mosaic has no complex type: the kernels take real targets only.
 registry.register(BackendSpec(
     name="pallas_wave",
     fn=_run_pallas_wave,
-    capability=Capability(platforms=("tpu",), tile_min=(2, 1),
+    capability=Capability(dtypes=registry.REAL_DTYPES, platforms=("tpu",),
+                          tile_min=(2, 1),
                           needs_pallas=True, supports_vmap=False),
     cost=registry.cost_pallas_wave,
     candidates=registry.pallas_wave_tiles,
@@ -148,7 +150,8 @@ registry.register(BackendSpec(
 registry.register(BackendSpec(
     name="pallas_mxu",
     fn=_run_pallas_mxu,
-    capability=Capability(platforms=("tpu",), tile_min=(2, 1),
+    capability=Capability(dtypes=registry.REAL_DTYPES, platforms=("tpu",),
+                          tile_min=(2, 1),
                           needs_pallas=True, supports_vmap=False),
     cost=registry.cost_pallas_mxu,
     candidates=registry.pallas_mxu_tiles,
@@ -166,9 +169,10 @@ registry.register(BackendSpec(
 registry.register(BackendSpec(
     name="rotseq_batched",
     fn=_run_rotseq_batched,
-    capability=Capability(platforms=("tpu",), tile_min=(2, 1),
-                          needs_pallas=True, supports_vmap=False,
-                          supports_sharding=True, batch_via="fused"),
+    capability=Capability(dtypes=registry.REAL_DTYPES, platforms=("tpu",),
+                          tile_min=(2, 1), needs_pallas=True,
+                          supports_vmap=False, supports_sharding=True,
+                          batch_via="fused"),
     cost=registry.cost_rotseq_batched,
     candidates=registry.rotseq_batched_tiles,
     doc="Fused multi-request Pallas kernel (one launch per bucket, "

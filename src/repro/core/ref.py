@@ -1,6 +1,7 @@
 """Reference implementations of rotation-sequence application.
 
-``rot_sequence_numpy``        — Algorithm 1.2, pure numpy, float64: the oracle.
+``rot_sequence_numpy``        — Algorithm 1.2, pure numpy, float64 (complex128
+                                for a complex target): the oracle.
 ``rot_sequence_unoptimized``  — Algorithm 1.2 in JAX (fori_loop), jit-able.
 ``rot_sequence_wavefront``    — Algorithm 1.3 (wavefront order) in JAX.
 
@@ -42,9 +43,12 @@ def rot_sequence_numpy(A, C, S, reflect: bool = False,
     Evaluates the canonical :func:`~repro.core.rotations.plane_update`
     order with the sign materialized per entry, exactly like the jnp
     and Pallas paths (numpy has no constant folding, so the unified
-    order is bit-identical to the seed's branched formulas).
+    order is bit-identical to the seed's branched formulas).  A complex
+    target stays complex (complex128): the real rotations update its
+    real and imaginary parts alike.
     """
-    A = np.array(A, dtype=np.float64, copy=True)
+    A = np.array(A, dtype=np.complex128 if np.iscomplexobj(A)
+                 else np.float64, copy=True)
     C = np.asarray(C, dtype=np.float64)
     S = np.asarray(S, dtype=np.float64)
     n = A.shape[1]

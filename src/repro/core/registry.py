@@ -24,6 +24,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 from repro import compat, obs
 
 __all__ = [
+    "REAL_DTYPES", "COMPLEX_DTYPES",
     "Hardware", "Problem", "Plan", "Capability", "BackendSpec",
     "register", "get_backend", "registered_methods", "eligible_backends",
     "no_tiles", "blocked_tiles", "accumulated_tiles",
@@ -97,13 +98,20 @@ class Problem:
 
     @property
     def itemsize(self) -> int:
+        """Bytes of one real item: a complex target is priced as its
+        real view, so ``complex64`` counts 4."""
         return {"float64": 8, "float32": 4, "bfloat16": 2,
-                "float16": 2}.get(self.dtype, 4)
+                "float16": 2, "complex64": 4}.get(self.dtype, 4)
 
     @property
     def m_total(self) -> int:
-        """Total rows streamed per application (``batch * m``)."""
-        return self.m * max(1, self.batch)
+        """Total real rows streamed per application (``batch * m``).
+
+        A complex target counts twice: real rotations update its real
+        and imaginary parts alike, so it is priced as its real view of
+        ``2m`` rows."""
+        views = 2 if self.dtype in COMPLEX_DTYPES else 1
+        return self.m * max(1, self.batch) * views
 
     @property
     def sequences(self) -> int:
@@ -155,10 +163,17 @@ class Plan:
         return kw
 
 
+# Target dtypes.  Real rotations apply to a complex target part by part,
+# so the jnp backends take complex64 too; Mosaic has no complex type,
+# so the Pallas backends list the real dtypes alone.
+REAL_DTYPES = ("float32", "bfloat16", "float64", "float16")
+COMPLEX_DTYPES = ("complex64",)
+
+
 @dataclasses.dataclass(frozen=True)
 class Capability:
     """What a backend can run; consulted before costing it."""
-    dtypes: Tuple[str, ...] = ("float32", "bfloat16", "float64", "float16")
+    dtypes: Tuple[str, ...] = REAL_DTYPES + COMPLEX_DTYPES
     platforms: Tuple[str, ...] = ("cpu", "gpu", "tpu")
     supports_signs: bool = True       # per-entry G (mixed rot/reflector)
     supports_sharding: bool = False   # callable inside shard_map

@@ -108,6 +108,17 @@ class RotationSequence:
     reflect: bool = False
     k_live: Optional[int] = None
 
+    def __post_init__(self):
+        # complex Givens rotations need a conjugate-aware plane update,
+        # which no applier has: refuse them rather than apply a
+        # non-unitary transform (real waves on a complex target are fine)
+        for x in (self.cos, self.sin):
+            if getattr(getattr(x, "dtype", None), "kind", None) == "c":
+                raise TypeError(
+                    f"complex Givens rotations are not supported: the "
+                    f"waves are {x.dtype}; cos and sin must be real "
+                    f"(they may be applied to a complex target)")
+
     # -- pytree protocol ---------------------------------------------------
     def tree_flatten(self):
         return (self.cos, self.sin, self.sign), (self.reflect, self.k_live)
@@ -424,6 +435,7 @@ class RotationSequence:
             m = like_shape[0] if like_shape is not None else max(self.n, 1)
         batch = 1 if batch is None else max(1, int(batch))
         dtype = getattr(like, "dtype", None) or self.dtype
+        target = str(jnp.dtype(dtype))
         n, k = self.n, self.k
         if method != "auto":
             # validate the method name + sign capability even when the
@@ -433,11 +445,17 @@ class RotationSequence:
                 raise ValueError(
                     f"method {method!r} does not support per-entry signs; "
                     f"use a blocked-family backend")
+            if target in registry.COMPLEX_DTYPES \
+                    and target not in spec.capability.dtypes:
+                raise ValueError(
+                    f"method {method!r} cannot apply to a {target} "
+                    f"target; one of {spec.capability.dtypes}")
         if n < 2 or k < 1 or m < 1:
             return SequencePlan(self, _IDENTITY, (), None)
 
         if method == "auto":
-            with obs.span("plan", m=m, n=n, k=k, batch=batch) as sp:
+            with obs.span("plan", m=m, n=n, k=k, batch=batch,
+                          dtype=target) as sp:
                 plan = registry.select_plan(
                     m, n, k, dtype=dtype, platform=platform,
                     signs=self.sign is not None, sharded=sharded,
@@ -524,10 +542,10 @@ class SequencePlan:
             return _apply_planned(self.method, self.kwargs, seq.reflect,
                                   A, seq.cos, seq.sin, seq.sign)
         with obs.span("apply", method=self.method, m=int(A.shape[0]),
-                      n=int(A.shape[1])):
+                      n=int(A.shape[1]), dtype=str(A.dtype)):
             out = _apply_planned(self.method, self.kwargs, seq.reflect,
                                  A, seq.cos, seq.sin, seq.sign)
-        self._count_apply()
+        self._count_apply(A)
         return out
 
     __call__ = apply
@@ -548,10 +566,11 @@ class SequencePlan:
         if compat.is_tracer(A):
             return _run_backend(self.method, self.kwargs, seq.reflect,
                                 A, seq.cos, seq.sin, seq.sign)
-        with obs.span("apply", method=self.method, direct=True):
+        with obs.span("apply", method=self.method, direct=True,
+                      dtype=str(A.dtype)):
             out = _run_backend(self.method, self.kwargs, seq.reflect,
                                A, seq.cos, seq.sin, seq.sign)
-        self._count_apply()
+        self._count_apply(A)
         return out
 
     def apply_batched(self, A, sequences=None, *, direct: bool = False):
@@ -600,9 +619,9 @@ class SequencePlan:
             return self._apply_batched_impl(A, sequences, direct)
         with obs.span("apply_batched", method=self.method,
                       batch=int(A.shape[0]), m=int(A.shape[1]),
-                      n=int(A.shape[2])):
+                      n=int(A.shape[2]), dtype=str(A.dtype)):
             out = self._apply_batched_impl(A, sequences, direct)
-        self._count_apply()
+        self._count_apply(A)
         return out
 
     def _apply_batched_impl(self, A, sequences, direct: bool):
@@ -668,11 +687,15 @@ class SequencePlan:
                 f"plan built for n={self.sequence.n} targets; "
                 f"got A.shape={A.shape}")
 
-    def _count_apply(self) -> None:
-        """Count one concrete application, in all and per backend."""
+    def _count_apply(self, A) -> None:
+        """Count one concrete application, in all and per backend, and
+        in ``accumulate.complex_views`` where the GEMM sweep ran on a
+        complex target's real view."""
         if obs.enabled():
             obs.inc("sequence.applies")
             obs.inc(f"sequence.applies.{self.method}")
+            if self.method == "accumulated" and jnp.iscomplexobj(A):
+                obs.inc("accumulate.complex_views")
 
     def rebind(self, sequence: RotationSequence) -> "SequencePlan":
         """Bind this (method, tiles) decision to a new same-shape sequence."""

@@ -267,3 +267,38 @@ def test_eigenvector_flush_fits_a_chip_in_place(topo, on_tpu):
     assert ma.alias_size_in_bytes == 0
     assert ma.argument_size_in_bytes + ma.output_size_in_bytes + \
         ma.temp_size_in_bytes > 16e9
+
+
+# the complex cell: real waves on a 16384² complex64 Z, k = 32 per flush
+ZSTEQR = (16384, 16384, 32)
+CHIP_BYTES = 16.91e9     # a v5e's memory as JAX reports its limit
+
+
+def test_complex_flush_fits_a_chip_with_no_complex_dot(one_chip, on_tpu):
+    """The complex cell's flush as ``auto`` plans it: ``accumulated``
+    64/32, its factors from the kernel, every dot real (the sweep runs
+    on the real view), and what the program holds (its argument, its
+    output and its temporaries) with one more Z, the chain's next
+    output in flight, inside 80% of the chip (PERF.md, section 4)."""
+    m, n, k = ZSTEQR
+    Z = jax.ShapeDtypeStruct((m, n), jnp.complex64, sharding=one_chip)
+    W = jax.ShapeDtypeStruct((n - 1, k), jnp.float32, sharding=one_chip)
+    plan = RotationSequence(W, W).plan(like=Z, method="auto",
+                                       platform="tpu")
+    assert (plan.method, dict(plan.kwargs)) == \
+        ("accumulated", {"n_b": 64, "k_b": 32})
+
+    def flush(Z, C, S):
+        return plan.rebind(RotationSequence(C, S)).apply(Z)
+
+    compiled = jax.jit(flush).lower(Z, W, W).compile()
+    text = compiled.as_text()
+    assert "rotseq_factors" in text
+    dots = [line for line in text.splitlines()
+            if " dot(" in line or " convolution(" in line]
+    assert dots and not [d for d in dots if "c64" in d], dots
+    ma = compiled.memory_analysis()
+    held = ma.argument_size_in_bytes + ma.output_size_in_bytes + \
+        ma.temp_size_in_bytes
+    z_bytes = m * n * 8
+    assert held + z_bytes < 0.8 * CHIP_BYTES, held

@@ -260,6 +260,51 @@ def test_plans_record_the_factor_kernels_tiles_per_step(monkeypatch,
         assert (gauge, padded) == ((4, 2) if on_tpu else (None, None))
 
 
+@pytest.mark.parametrize("dtype,views", [("float32", 0),
+                                         ("complex64", 1)])
+@pytest.mark.parametrize("method", ["accumulated", "blocked"])
+def test_complex_views_count_each_complex_gemm_sweep(tmp_path, method,
+                                                     dtype, views):
+    """``accumulate.complex_views`` counts one per application whose
+    GEMM sweep ran on a complex target's real view, and none for a real
+    target or a backend without the sweep; the plan and apply spans
+    carry the target's dtype."""
+    rng = np.random.default_rng(1)
+    A = rng.standard_normal((8, 24)) + (
+        1j * rng.standard_normal((8, 24)) if dtype == "complex64" else 0)
+    A = jnp.asarray(A, dtype)
+    seq = random_sequence(jax.random.key(2), 24, 6)
+    obs.reset()
+    with obs.override(True), _trace_path(str(tmp_path / "t.json")):
+        plan = seq.plan(like=A, method=method, n_b=8, k_b=4)
+        seq.plan(like=A)  # an auto plan opens the plan span
+        jax.block_until_ready(plan.apply(A))
+        jax.block_until_ready(plan.apply(A))
+        snap = obs.snapshot()
+    want = 2 * views if method == "accumulated" else 0
+    assert snap["counters"].get("accumulate.complex_views", 0) == want
+    spans = {ev["name"]: ev["args"] for ev in obs.trace.events()}
+    assert spans["apply"]["dtype"] == dtype
+    assert spans["plan"]["dtype"] == dtype
+
+
+def test_complex_plans_record_the_factor_kernels_tiles_per_step(
+        monkeypatch):
+    """A complex64 target's factors are float32, so on a TPU its plan
+    records the factor kernel's tiles per step as a real one does."""
+    from repro import compat
+
+    monkeypatch.setattr(compat, "is_tpu", lambda: True)
+    seq = random_sequence(jax.random.key(4), 640, 40)
+    obs.reset()
+    with obs.override(True):
+        seq.plan(like=jax.ShapeDtypeStruct((64, 640), jnp.complex64),
+                 method="accumulated", n_b=64, k_b=32)
+    snap = obs.snapshot()
+    assert snap["gauges"]["kernels.rotseq_factors.tiles_per_step"] == 4
+    assert snap["counters"]["kernels.rotseq_factors.padded_tiles"] == 2
+
+
 # ------------------------------------- plan-cache counters, exactly ----
 
 def test_plan_cache_counters_cold_warm_interpolated_upgrade():
@@ -314,7 +359,8 @@ def test_sequence_dispatch_records_roofline_and_counters(tmp_path):
     spans = [ev for ev in obs.trace.events()
              if ev["name"] in ("apply", "apply_batched")]
     assert [ev["name"] for ev in spans] == ["apply", "apply_batched"]
-    assert spans[0]["args"] == {"method": plan.method, "m": 12, "n": 24}
+    assert spans[0]["args"] == {"method": plan.method, "m": 12, "n": 24,
+                                "dtype": "float32"}
     assert spans[1]["args"]["batch"] == 3
     assert all(ev["dur"] >= 0 for ev in spans)
 
